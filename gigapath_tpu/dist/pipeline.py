@@ -124,18 +124,23 @@ def _default_streaming_forward():
     the dense forward emits (the parity/bit-exactness surface)."""
     import jax
 
-    from gigapath_tpu.dist.stagemesh import stage_mesh, stage_param_shardings
+    from gigapath_tpu.dist.stagemesh import (
+        stage_mesh,
+        stage_param_shardings,
+        stage_process_devices,
+    )
     from gigapath_tpu.models.classification_head import get_model
     from gigapath_tpu.models.streaming_encoder import StreamingEncoderSession
     from gigapath_tpu.serve.streaming import streaming_head_logits
     from gigapath_tpu.utils.registry import create_model_from_registry
 
     def build(dim_in: int):
+        devices = stage_process_devices()  # first JAX touch: fails by cause
         model, params = get_model(
             input_dim=dim_in, latent_dim=32, feat_layer="1", n_classes=2,
             model_arch="gigapath_slide_enc_tiny", dtype=None,
         )
-        mesh = stage_mesh("slide_encoder", devices=jax.devices()[:1])
+        mesh = stage_mesh("slide_encoder", devices=devices)
         params = jax.device_put(
             params, stage_param_shardings("slide_encoder", params, mesh)
         )
@@ -174,15 +179,20 @@ def _default_forward():
     path a sharded fleet does, without changing a single byte)."""
     import jax
 
-    from gigapath_tpu.dist.stagemesh import stage_mesh, stage_param_shardings
+    from gigapath_tpu.dist.stagemesh import (
+        stage_mesh,
+        stage_param_shardings,
+        stage_process_devices,
+    )
     from gigapath_tpu.models.classification_head import get_model
 
     def build(dim_in: int):
+        devices = stage_process_devices()  # first JAX touch: fails by cause
         model, params = get_model(
             input_dim=dim_in, latent_dim=32, feat_layer="1", n_classes=2,
             model_arch="gigapath_slide_enc_tiny", dtype=None,
         )
-        mesh = stage_mesh("slide_encoder", devices=jax.devices()[:1])
+        mesh = stage_mesh("slide_encoder", devices=devices)
         params = jax.device_put(
             params, stage_param_shardings("slide_encoder", params, mesh)
         )
@@ -619,6 +629,13 @@ def run_slide_consumer(root: str, *, runlog=None,
     }
 
 
+def _stage_stderr(root: str, stage: str):
+    """A stage process's stderr lands in ``<root>/<stage>.stderr`` (append):
+    a stage that dies at start-up — a second process asking for a chip
+    another holds (``stagemesh.stage_process_devices``) — says why there."""
+    return open(os.path.join(root, f"{stage}.stderr"), "ab")
+
+
 def spawn_worker(root: str, worker_id: str, *,
                  chaos: Optional[str] = None, run_id: Optional[str] = None,
                  deadline_s: float = 120.0) -> subprocess.Popen:
@@ -632,13 +649,14 @@ def spawn_worker(root: str, worker_id: str, *,
         env["GIGAPATH_CHAOS"] = chaos
     if run_id:
         env["GIGAPATH_OBS_RUN_ID"] = run_id
-    return subprocess.Popen(
-        [sys.executable, "-m", "gigapath_tpu.dist.worker",
-         "--root", root, "--worker", worker_id,
-         "--deadline-s", str(deadline_s)],
-        env=env, cwd=REPO_ROOT,
-        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
-    )
+    with _stage_stderr(root, worker_id) as err:  # the child keeps its own copy
+        return subprocess.Popen(
+            [sys.executable, "-m", "gigapath_tpu.dist.worker",
+             "--root", root, "--worker", worker_id,
+             "--deadline-s", str(deadline_s)],
+            env=env, cwd=REPO_ROOT,
+            stdout=subprocess.DEVNULL, stderr=err,
+        )
 
 
 def spawn_consumer(root: str, *, chaos: Optional[str] = None,
@@ -657,12 +675,13 @@ def spawn_consumer(root: str, *, chaos: Optional[str] = None,
     if run_id:
         env["GIGAPATH_OBS_RUN_ID"] = run_id
     env.setdefault("JAX_PLATFORMS", "cpu")
-    return subprocess.Popen(
-        [sys.executable, "-m", "gigapath_tpu.dist.pipeline",
-         "--root", root, "--deadline-s", str(deadline_s)],
-        env=env, cwd=REPO_ROOT,
-        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
-    )
+    with _stage_stderr(root, "consumer") as err:
+        return subprocess.Popen(
+            [sys.executable, "-m", "gigapath_tpu.dist.pipeline",
+             "--root", root, "--deadline-s", str(deadline_s)],
+            env=env, cwd=REPO_ROOT,
+            stdout=subprocess.DEVNULL, stderr=err,
+        )
 
 
 def load_result(root: str) -> dict:
@@ -747,6 +766,9 @@ def main(argv=None) -> int:
     acceptance). Publishes its result atomically to
     ``<root>/result.npz`` so the orchestrator reads it across the
     process boundary."""
+    from gigapath_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="dist slide-stage consumer (module docstring)"
     )

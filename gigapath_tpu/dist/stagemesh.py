@@ -135,6 +135,31 @@ register_stage(StageSpec(
 ))
 
 
+def stage_process_devices() -> list:
+    """The one device a dist stage process computes on.
+
+    Each stage (tile worker, slide consumer) is its own OS process, and an
+    accelerator belongs to one process at a time: on a host with a single
+    chip the second stage process to start cannot get it. That is reported
+    here, by cause, instead of as whatever the backend raises — the
+    two-process layout needs a device per process (or
+    ``JAX_PLATFORMS=cpu`` in the stages that are to stay off the chip)."""
+    import jax
+
+    try:
+        return jax.devices()[:1]
+    except RuntimeError as e:
+        raise RuntimeError(
+            "dist stage process could not initialise its JAX backend. The "
+            "two-process layout (gigapath_tpu.dist.worker / "
+            "gigapath_tpu.dist.pipeline) needs a device per process: an "
+            "accelerator belongs to ONE process at a time, so on a one-chip "
+            "host a second stage process cannot take it. Give each stage "
+            "process its own device, or set JAX_PLATFORMS=cpu for the "
+            f"stages that stay off the chip. Backend error: {e}"
+        ) from e
+
+
 def stage_mesh(name: str, n_devices: Optional[int] = None, *,
                devices=None,
                axis_sizes: Optional[Dict[str, int]] = None) -> Mesh:

@@ -156,11 +156,16 @@ def make_encoder(plan: dict):
     import jax
     import jax.numpy as jnp
 
-    from gigapath_tpu.dist.stagemesh import stage_mesh, stage_param_shardings
+    from gigapath_tpu.dist.stagemesh import (
+        stage_mesh,
+        stage_param_shardings,
+        stage_process_devices,
+    )
     from gigapath_tpu.models.tile_encoder import init_params
     from gigapath_tpu.quant.qtensor import bf16_round_trip, normalize_mode
     from gigapath_tpu.utils.registry import create_model_from_registry
 
+    devices = stage_process_devices()  # first JAX touch: fails by cause
     mode = normalize_mode(plan.get("quant", "int8"))
     model = create_model_from_registry(
         plan.get("tile_arch", "vit_tile_enc_test"),
@@ -171,7 +176,7 @@ def make_encoder(plan: dict):
     params = init_params(
         model, rng=jax.random.PRNGKey(int(plan["encoder_seed"]))
     )
-    mesh = stage_mesh("tile_encoder", devices=jax.devices()[:1])
+    mesh = stage_mesh("tile_encoder", devices=devices)
     params = jax.device_put(
         params, stage_param_shardings("tile_encoder", params, mesh)
     )
@@ -374,6 +379,9 @@ def run_tile_worker(root: str, worker_id: str, *,
 
 
 def main(argv=None) -> int:
+    from gigapath_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="dist dryrun tile worker (module docstring)"
     )

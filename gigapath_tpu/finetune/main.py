@@ -27,7 +27,9 @@ def main(argv: Optional[list] = None) -> dict:
     from gigapath_tpu.finetune.task_configs.utils import load_task_config
     from gigapath_tpu.finetune.training import train
     from gigapath_tpu.finetune.utils import get_exp_code, seed_everything
+    from gigapath_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     args = get_finetune_params(argv)
     console(str(args))
 

@@ -494,6 +494,9 @@ def run_inference(
 
 
 def main(argv=None):
+    from gigapath_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description="GigaPath model inference")
     parser.add_argument("--model_path", type=str, required=True)
     parser.add_argument("--feature_dir", type=str, required=True)

@@ -303,6 +303,9 @@ def _train_loop(
 
 
 def main(argv=None):
+    from gigapath_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     args = build_argparser().parse_args(argv)
     console(str(args))
     seed_everything(args.seed)

@@ -451,7 +451,9 @@ def init_params(
 ) -> Dict[str, Any]:
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     x = jnp.zeros((1, model.img_size, model.img_size, 3), jnp.float32)
-    return model.init(rng, x)["params"]
+    # under jit: eager flax init dispatches every initializer (and the
+    # batch-1 forward behind it) as its own device op and compile
+    return jax.jit(model.init)(rng, x)["params"]
 
 
 def create_tile_encoder(

@@ -426,7 +426,7 @@ def _branch_pallas_bwd(sl, r, is_causal, interpret, res, cots):
     # Backward blocks are chosen independently of the forward single block:
     # the bwd kernels hold ~2.5 live fp32 logits tiles (vs the forward's
     # ~2), so the forward's 1408 choice overflows scoped vmem in the
-    # backward (the BENCH_r03 crash). bwd_blocks keeps block_q = the
+    # backward (the round-3 driver crash). bwd_blocks keeps block_q = the
     # forward block (q side stays unpadded) and shrinks block_k to fit.
     bq, bk = pf.bwd_blocks(block)
     dq5, dk5, dv5 = pf._bwd_impl(
@@ -997,7 +997,7 @@ def dilated_attention(
     length and branches whose segment exceeds it gather K/V across the axis;
     fully-local branches route through the fused phase-major kernels on TPU.
     shard_map callers must pass ``check_vma=False`` when the Pallas tier is
-    active (jax 0.9's vma checking cannot see through ``pallas_call``).
+    active (vma checking cannot see through ``pallas_call``).
     ``dropout_rate`` is attention-probability dropout inside each branch
     (parity with the reference forwarding dropout to flash-attn).
 
@@ -1153,25 +1153,19 @@ def dilated_attention(
     # N=10241) instead of the head-major generic loop. Gathered branches
     # and every non-default case keep the generic path.
     def _vma_transparent() -> bool:
-        # jax 0.9's vma checking cannot see through pallas_call: under a
-        # shard_map with the default check_vma=True the traced avals carry
-        # a non-empty vma and the kernel call would fail at trace time.
-        # Auto-fall-back to the generic path there (warning once) instead
-        # of hard-breaking existing callers; check_vma=False unlocks the
-        # fused routing. jax 0.4.x has neither jax.typeof nor vma (its
-        # shard_map uses check_rep, which pallas already satisfies) — the
-        # fused routing is unconditionally available there.
-        typeof = getattr(jax, "typeof", None)
-        vma = (
-            getattr(typeof(q), "vma", frozenset()) if typeof else frozenset()
-        )
-        if vma:
+        # vma checking cannot see through pallas_call: under a shard_map
+        # with the default check_vma=True the traced avals carry a
+        # non-empty vma and the kernel call would fail at trace time.
+        # Fall back to the generic path there, warning once;
+        # check_vma=False unlocks the fused routing (chip_smoke.py's
+        # four-chip phase fails unless the sharded program holds the
+        # kernels, so the fallback cannot pass for the kernel path).
+        if jax.typeof(q).vma:
             _warn_once(
                 "sequence-parallel dilated attention inside a "
-                "check_vma=True shard_map: pallas kernels are vma-opaque "
-                "in jax 0.9, so local branches fall back to the generic "
-                "path — pass check_vma=False to shard_map to enable the "
-                "fused kernels"
+                "check_vma=True shard_map: pallas kernels are vma-opaque, "
+                "so local branches fall back to the generic path — pass "
+                "check_vma=False to shard_map to enable the fused kernels"
             )
             return False
         return True
@@ -1235,6 +1229,16 @@ def dilated_attention(
     outs, lses = [], []
     for i, (sl, r) in enumerate(zip(segment_lengths, dilated_ratios)):
         sl_i, r_i = int(sl), int(r)
+        if seq_active and sl_i < k.shape[1] and k.shape[1] % sl_i:
+            # each shard segments its own tokens from its own start: a
+            # local segment that does not divide the shard puts segment
+            # boundaries elsewhere than the unsharded op does
+            _warn_once(
+                "sequence-parallel dilated attention: segment length %d does "
+                "not divide the %d-token shard, so this branch's segments "
+                "restart at every shard boundary and the result differs from "
+                "the unsharded op" % (sl_i, k.shape[1])
+            )
         if (
             fused_local
             and sl_i <= k.shape[1]
@@ -1346,6 +1350,12 @@ def _dilated_branch(
     ring_counts = None
     if gather_kv:
         local_len = k.shape[1]
+        # a segment longer than the whole sharded sequence is ONE segment
+        # over all of it — the single-device path's g = min(sl, L), taken
+        # here over the global length. Without it the flagship schedule
+        # (185,363 / 1,048,576) neither divides into whole shards nor fits
+        # the seq axis.
+        sl = min(sl, seq_axis_size * local_len)
         use_ring = ring and not is_causal
         if ring and is_causal:
             # visible, once: silently taking the gather path would make

@@ -1126,7 +1126,7 @@ def _pack_bt(Mp: int, r: int, E: int, itemsize: int) -> int:
     legally shrink below 128 down to the 8-row fp32 tile — which is what
     enforces the budget when r*E*itemsize is large: at the flagship r=16
     branch in fp32, bt=128 would be ~6.3 MB in + 6.3 MB out (~25 MB
-    double-buffered, over the ~16 MB scoped-VMEM ceiling — the BENCH_r03
+    double-buffered, over the ~16 MB scoped-VMEM ceiling — the the round-3 driver run
     OOM class); bt=64 lands back inside the budget. A lane split is NOT
     available here: the per-phase window is W = E/r lanes (48 at the
     flagship), and Mosaic only allows lane blocks that are 128-multiples

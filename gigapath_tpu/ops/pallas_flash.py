@@ -68,7 +68,7 @@ DEFAULT_BLOCK_K = 1024
 # tiles fit under the 16 MB limit with headroom: 2.6 * 4 B * budget
 # <= 14 MB  =>  budget <= ~1.35M elements. 1024x1024 (1.05M, the default)
 # passes; 1408x1408 (1.98M, round 3's single-block choice) does not — that
-# exact overflow shipped a HEAD whose own benchmark crashed (BENCH_r03).
+# exact overflow shipped a HEAD whose own benchmark crashed (the round-3 driver run).
 _BWD_LOGITS_BUDGET = 1_350_000
 
 

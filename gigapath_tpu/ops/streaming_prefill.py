@@ -181,7 +181,6 @@ def pair_partial_attention(
             q_blk, k_blk, v_blk, q0, k0,
             segment_len=segment_len, ratio=ratio, valid_len=valid_len,
             block_q=bq, block_k=bk,
-            interpret=jax.default_backend() != "tpu",
         )
     B, cq, H, Dh = q_blk.shape
     ck = k_blk.shape[1]

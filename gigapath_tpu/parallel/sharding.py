@@ -61,30 +61,6 @@ _SEQ_COLLECTIVES: Dict[str, tuple] = {
 }
 
 
-def shard_map_compat():
-    """(shard_map, check_kwargs) across jax spellings: jax >= 0.9 exposes
-    ``jax.shard_map`` and checks vma (``check_vma`` — pallas-opaque, so
-    the kwarg disables it); 0.4.x has the experimental spelling and
-    ``check_rep``. The ONE compat shim — scripts and tests building
-    seq-parallel regions by hand unpack it instead of re-deriving the
-    signature dance per call site::
-
-        shard_map, check_kw = shard_map_compat()
-        fn = shard_map(body, mesh=mesh, in_specs=..., out_specs=..., **check_kw)
-    """
-    import inspect
-
-    try:  # jax >= 0.9 spells it jax.shard_map
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    sig = inspect.signature(shard_map).parameters
-    check_kw = (
-        {"check_vma": False} if "check_vma" in sig else {"check_rep": False}
-    )
-    return shard_map, check_kw
-
-
 def param_spec(
     path_names,
     leaf,

@@ -87,6 +87,9 @@ def load_tile_slide_encoder(
     local_tile_encoder_path: str = "",
     local_slide_encoder_path: str = "",
     global_pool: bool = False,
+    *,
+    tile_arch: str = "gigapath_tile_enc",
+    slide_arch: str = "gigapath_slide_enc12l768d",
 ) -> Tuple[tuple, tuple]:
     """Load both encoders; returns ``((tile_model, tile_params),
     (slide_model, slide_params))`` (reference ``pipeline.py:118-137``).
@@ -99,21 +102,45 @@ def load_tile_slide_encoder(
     quantized-Dense tier — a distinct traced program, so the jit cache
     can never serve the wrong tier."""
     tile_model, tile_params = tile_encoder_lib.create_tile_encoder(
-        pretrained=local_tile_encoder_path, dtype=jnp.bfloat16,
+        pretrained=local_tile_encoder_path, model_arch=tile_arch,
+        dtype=jnp.bfloat16,
     )
     n_tile = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(tile_params))
     console(f"Tile encoder param # {n_tile}")
 
     slide_model, slide_params = slide_encoder_lib.create_model(
         local_slide_encoder_path or "hf_hub:prov-gigapath/prov-gigapath",
-        "gigapath_slide_enc12l768d",
-        1536,
+        slide_arch,
+        tile_model.embed_dim,
         global_pool=global_pool,
         dtype=jnp.bfloat16,
     )
     n_slide = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(slide_params))
     console(f"Slide encoder param # {n_slide}")
     return (tile_model, tile_params), (slide_model, slide_params)
+
+
+def tile_encode_fn(tile_encoder):
+    """The jitted tile-batch forward ``encode(params, imgs [B, H, W, 3]) ->
+    [B, 1536]`` that :func:`run_inference_with_tile_encoder` runs (params
+    ride as an argument, never as 4 GB of inline constants)."""
+
+    @jax.jit
+    def encode(params, imgs):
+        return tile_encoder.apply({"params": params}, imgs)
+
+    return encode
+
+
+def slide_forward_fn(slide_encoder_model):
+    """The jitted all-layer slide forward ``(params, tile_embeds [B, N, D]
+    bf16, coords [B, N, 2]) -> per-layer embeddings`` that
+    :func:`run_inference_with_slide_encoder` runs."""
+    return jax.jit(
+        lambda p, x, c: slide_encoder_model.apply(
+            {"params": p}, x, c, all_layer_embed=True
+        )
+    )
 
 
 def run_inference_with_tile_encoder(
@@ -135,10 +162,7 @@ def run_inference_with_tile_encoder(
         transform=load_tile_encoder_transforms(crop_size=tile_encoder.img_size),
     )
 
-    @jax.jit
-    def encode(params, imgs):
-        return tile_encoder.apply({"params": params}, imgs)
-
+    encode = tile_encode_fn(tile_encoder)
     embeds, coords = [], []
     for start in range(0, len(dataset), batch_size):
         samples = [dataset[i] for i in range(start, min(start + batch_size, len(dataset)))]
@@ -220,11 +244,9 @@ def run_inference_with_slide_encoder(
         tile_embeds = tile_embeds[None]
         coords = coords[None]
 
-    slide_embeds = jax.jit(
-        lambda p, x, c: slide_encoder_model.apply(
-            {"params": p}, x, c, all_layer_embed=True
-        )
-    )(slide_params, tile_embeds.astype(jnp.bfloat16), coords)
+    slide_embeds = slide_forward_fn(slide_encoder_model)(
+        slide_params, tile_embeds.astype(jnp.bfloat16), coords
+    )
     outputs = {
         f"layer_{i}_embed": np.asarray(e, np.float32)
         for i, e in enumerate(slide_embeds)

@@ -145,6 +145,18 @@ class AotExecutableCache:
             sds((capacity, bucket_n), jnp.bool_),
         )
 
+    def _execution_devices(self) -> list:
+        """The devices every executable of this cache runs on: where the
+        params live (the jit compiles for its arguments' devices), in
+        mesh order when they are sharded over one."""
+        import jax
+
+        sharding = jax.tree_util.tree_leaves(self.params)[0].sharding
+        mesh = getattr(sharding, "mesh", None)
+        if mesh is not None:
+            return list(mesh.devices.flat)
+        return sorted(sharding.device_set, key=lambda d: d.id)
+
     # -- artifact persistence ---------------------------------------------
     def _code_signature(self) -> str:
         """Identity for the forward's CODE, not just its shapes: the
@@ -254,8 +266,13 @@ class AotExecutableCache:
                 or meta["fingerprint"] != self._fingerprint(capacity, bucket_n)
             ):
                 return None
+            # pin the reload to the devices the executable was compiled
+            # for: with none given, deserialize_and_load spans EVERY local
+            # device of the backend, and a one-device executable then
+            # refuses its arguments on any host that has more than one
             return serialize_executable.deserialize_and_load(
-                doc["payload"], doc["in_tree"], doc["out_tree"]
+                doc["payload"], doc["in_tree"], doc["out_tree"],
+                execution_devices=self._execution_devices(),
             )
         except Exception as e:
             self.runlog.echo(

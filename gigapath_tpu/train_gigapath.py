@@ -432,6 +432,9 @@ def main(
 ):
     """Full journey: rename -> tile -> extract -> (dummy) labels -> train
     (reference ``main:387``)."""
+    from gigapath_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     slide_files = rename_slide_files(data_dir)
     feature_dir = os.path.join(output_dir, "features")
     extract_features(

@@ -121,11 +121,9 @@ def main():
         # device. L trims to a shard multiple; gathered branches must
         # divide into whole shards (the shard_map path's contract), so
         # incompatible segments are dropped with a note.
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from gigapath_tpu.parallel.sharding import shard_map_compat
-
-        shard_map, check_kw = shard_map_compat()
         ndev = len(jax.devices())
         if ndev < 2:
             sys.exit("--variants gather/ring need >= 2 devices")
@@ -172,7 +170,7 @@ def main():
                     seq_axis_name="seq", seq_axis_size=ndev,
                 ),
                 mesh=mesh, in_specs=(P(None, "seq"),) * 3,
-                out_specs=P(None, "seq"), **check_kw,
+                out_specs=P(None, "seq"), check_vma=False,
             )(q, k, v)
 
     fused = lambda q, k, v: da.dilated_attention_fused(q, k, v, SEGS, RATIOS)

@@ -930,7 +930,12 @@ def main(argv: Optional[List[str]] = None) -> int:
     args = ap.parse_args(argv)
 
     if args.selftest:
-        return selftest()
+        # the selftest is a CPU check: the fold seam passes no `interpret`
+        # of its own, so interpret mode is asked for here, explicitly
+        from jax.experimental.pallas import tpu as pltpu
+
+        with pltpu.force_tpu_interpret_mode():
+            return selftest()
 
     payload = sweep(args)
     print(json.dumps(payload["decision"]))

@@ -1,7 +1,6 @@
 #!/usr/bin/env python
 """Fold perf snapshots into PERF_HISTORY.json and gate on the trend.
 
-    python scripts/perf_history.py seed                       # r01..r05 + golden ledger -> PERF_HISTORY.json
     python scripts/perf_history.py ingest --label r06 \
         --bench BENCH_r06.json --multichip MULTICHIP_r06.json \
         --ledger out/obs/run.ledger.json
@@ -27,7 +26,6 @@ input / usage errors.
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import sys
@@ -57,39 +55,6 @@ def _load_or_new(path: str) -> dict:
 # ---------------------------------------------------------------------------
 # commands
 # ---------------------------------------------------------------------------
-
-def cmd_seed(args) -> int:
-    """Build the day-one history from every BENCH_r*/MULTICHIP_r*
-    snapshot in the repo root (plus the golden flagship ledger under the
-    newest round's label), so the trend gate never starts blind."""
-    doc = history.new_history() if args.force else _load_or_new(args.history)
-    rounds: List[str] = []
-    try:
-        for path in sorted(glob.glob(os.path.join(args.root, "BENCH_r*.json"))):
-            label = os.path.basename(path).replace("BENCH_", "").replace(".json", "")
-            rounds.append(label)
-            history.fold_bench(doc, _load_json(path), label,
-                               source=os.path.basename(path), force=args.force)
-        for path in sorted(glob.glob(os.path.join(args.root, "MULTICHIP_r*.json"))):
-            label = os.path.basename(path).replace("MULTICHIP_", "").replace(".json", "")
-            history.fold_multichip(doc, _load_json(path), label,
-                                   source=os.path.basename(path), force=args.force)
-        if rounds and os.path.exists(GOLDEN_LEDGER):
-            history.fold_ledger(
-                doc, _load_json(GOLDEN_LEDGER), max(rounds),
-                source=os.path.relpath(GOLDEN_LEDGER, REPO_ROOT),
-                force=args.force,
-            )
-    except (OSError, ValueError, json.JSONDecodeError) as e:
-        print(f"error: {e} (already seeded? --force rebuilds)",
-              file=sys.stderr)
-        return 2
-    history.write_history(doc, args.history)
-    n_points = sum(len(e["points"]) for e in doc["entries"].values())
-    print(f"perf_history: seeded {len(doc['entries'])} entries "
-          f"({n_points} points) -> {args.history}")
-    return 0
-
 
 def cmd_ingest(args) -> int:
     try:
@@ -637,14 +602,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="verify the trend gate on a synthetic history")
     sub = ap.add_subparsers(dest="command")
 
-    p_seed = sub.add_parser("seed", help="build from BENCH_r*/MULTICHIP_r* "
-                            "snapshots (+ the golden ledger)")
-    p_seed.add_argument("--history", default=DEFAULT_HISTORY)
-    p_seed.add_argument("--root", default=REPO_ROOT,
-                        help="directory holding the round snapshots")
-    p_seed.add_argument("--force", action="store_true",
-                        help="rebuild from scratch, replacing the file")
-
     p_ing = sub.add_parser("ingest", help="append one labeled round")
     p_ing.add_argument("--history", default=DEFAULT_HISTORY)
     p_ing.add_argument("--label", required=True,
@@ -703,13 +660,11 @@ def main(argv: Optional[List[str]] = None) -> int:
     args = ap.parse_args(argv)
     if args.selftest:
         return selftest()
-    if args.command == "seed":
-        return cmd_seed(args)
     if args.command == "ingest":
         return cmd_ingest(args)
     if args.command == "check":
         return cmd_check(args)
-    ap.error("provide a command (seed | ingest | check) or --selftest")
+    ap.error("provide a command (ingest | check) or --selftest")
     return 2
 
 

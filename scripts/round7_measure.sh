@@ -7,7 +7,7 @@
 set -x
 cd "$(dirname "$0")/.."
 
-# 1. headline bench -> BENCH_LOCAL.json (the round's survivable record)
+# 1. headline bench (one JSON line naming the device; fails without a TPU)
 timeout 1800 python bench.py 2>/tmp/r7_bench.err | tee /tmp/r7_bench.log
 
 # 2. gate the kernels at the bench geometry (incl. flagged combos)

@@ -665,6 +665,9 @@ def run_drift(args) -> dict:
 
 
 def main(argv=None) -> int:
+    from gigapath_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python scripts/serve_smoke.py",
         description="Concurrent synthetic slides through the serving stack",

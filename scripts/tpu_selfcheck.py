@@ -2,273 +2,56 @@
 
 The pytest suite runs on a virtual CPU mesh (tests/conftest.py) where the
 Pallas kernels execute in interpret mode; this script validates the REAL
-compiled kernels on the local TPU against the jnp reference at bf16
-tolerances, plus gradients through the custom-vjp backward kernels.
+compiled kernels on the local TPU against the float32 jnp reference at bf16
+tolerances, plus gradients through the custom-vjp backward kernels. The
+checks themselves live in ``gigapath_tpu/utils/kernel_checks.py`` —
+``chip_smoke.py`` phase A runs the same function; this script adds the
+default-off env-flagged kernel variants.
 
-Run: python scripts/tpu_selfcheck.py   (exits nonzero on any failure)
+Run: python scripts/tpu_selfcheck.py   (exits nonzero on any failure, and
+when the default backend is not a TPU: there is nothing it can vouch for)
 """
 
 import os
 import sys
+import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import time
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+def main() -> int:
+    import jax
 
-FAILED = []
-_T0 = time.time()
+    from gigapath_tpu.utils.compile_cache import enable_compile_cache
+    from gigapath_tpu.utils.kernel_checks import flagship, run_kernel_checks
 
-
-def check(name, got, ref, atol):
-    err = float(jnp.abs(jnp.asarray(got, jnp.float32) - jnp.asarray(ref, jnp.float32)).max())
-    # NaN must fail: `err <= atol` is False for NaN, but so would `err >
-    # atol` be — gate on NOT-ok, or a NaN-producing kernel passes silently
-    ok = err <= atol
-    print(f"[{time.time() - _T0:6.1f}s] {name:55s} max_err={err:.4e} (atol {atol:g})  {'ok' if ok else 'FAIL'}")
-    if not ok:
-        FAILED.append(name)
-
-
-def main():
-    from gigapath_tpu.ops import dilated_attention as da
-    from gigapath_tpu.ops.flash_attention import _on_tpu
-    from gigapath_tpu.ops.pallas_flash import pallas_flash_attention
-    from gigapath_tpu.ops.attention import attention_with_lse
-
-    if not _on_tpu():
-        print("no TPU backend — nothing to check (suite covers interpret mode)")
-        return
-
-    from gigapath_tpu.models.longnet_config import flagship_geometry
-
-    rng = np.random.default_rng(0)
-    _G = flagship_geometry()
-    H, Dh = _G["heads"], _G["head_dim"]
-    SEGS, RATIOS = _G["segment_lengths"], _G["dilated_ratios"]
-    # L=2048 keeps the on-chip jnp reference (the slow part: dense [L, L]
-    # logits per branch) under the ~3-minute per-round budget while still
-    # exercising multi-segment branch 1 and every dilation ratio
-    L = 2048
-    q, k, v = (jnp.asarray(rng.normal(size=(1, L, H, Dh)), jnp.bfloat16) for _ in range(3))
-    qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
-
-    # plain flash kernel vs jnp (bf16 inputs; fp32 softmax both sides)
-    o_p, l_p = pallas_flash_attention(q, k, v)
-    o_j, l_j = attention_with_lse(q, k, v)
-    check("pallas flash fwd (L=2048)", o_p, o_j, 3e-2)
-    check("pallas flash lse (L=2048)", l_p, l_j, 3e-2)
-
-    # head-major dilated path (the model default) vs generic jnp path
-    ref = da.dilated_attention_bhld(qf, kf, vf, SEGS, RATIOS, valid_len=2001, use_pallas=False)
-    out = da.dilated_attention_bhld(q, k, v, SEGS, RATIOS, valid_len=2001)
-    check("dilated bhld (flagship schedule, valid_len)", out[:, :2001], ref[:, :2001], 5e-2)
-
-    # phase-major fused kernels vs the same reference
-    out_f = da.dilated_attention_fused(q, k, v, SEGS, RATIOS, valid_len=2001)
-    check("dilated fused (flagship schedule, valid_len)", out_f[:, :2001], ref[:, :2001], 5e-2)
-
-    # Gradients through the compiled backward kernels. dq/dk/dv ride ONE
-    # jax.grad(argnums=(0,1,2)) per path — one XLA compile covers all three
-    # (three separate grads tripled the compile bill and previously pushed
-    # the dK/dV checks past a 10-minute budget). Short schedule + L=1024
-    # keeps each backward compile small.
-    segs, ratios = [256, 512], [1, 2]
-    Lb = 1024
-    qb, kb, vb = q[:, :Lb], k[:, :Lb], v[:, :Lb]
-    qbf, kbf, vbf = qf[:, :Lb], kf[:, :Lb], vf[:, :Lb]
-
-    def loss_pallas(x, y, z):
-        return da.dilated_attention_bhld(x, y, z, segs, ratios).astype(jnp.float32).var()
-
-    def loss_jnp(x, y, z):
-        return da.dilated_attention_bhld(
-            x, y, z, segs, ratios, use_pallas=False
-        ).var()
-
-    def loss_fused(x, y, z):
-        return da.dilated_attention_fused(x, y, z, segs, ratios).astype(jnp.float32).var()
-
-    grads_p = jax.jit(jax.grad(loss_pallas, argnums=(0, 1, 2)))(qb, kb, vb)
-    grads_j = jax.jit(jax.grad(loss_jnp, argnums=(0, 1, 2)))(qbf, kbf, vbf)
-    grads_f = jax.jit(jax.grad(loss_fused, argnums=(0, 1, 2)))(qb, kb, vb)
-    for name, g_p, g_f, g_j in zip("qkv", grads_p, grads_f, grads_j):
-        scale = float(jnp.abs(g_j).max())
-        check(
-            f"dilated bhld d{name} (rel to {scale:.2e})",
-            g_p.astype(jnp.float32) / scale, g_j / scale, 6e-2,
+    if jax.default_backend() != "tpu":
+        print(
+            f"tpu_selfcheck: default backend is {jax.default_backend()!r}, "
+            "not 'tpu' — the compiled kernels were NOT checked",
+            file=sys.stderr,
         )
-        check(
-            f"dilated fused d{name} (rel to {scale:.2e})",
-            g_f.astype(jnp.float32) / scale, g_j / scale, 6e-2,
+        return 2
+    enable_compile_cache()
+    from bench import N  # stay in lockstep with the driver's bench
+
+    t0 = time.time()
+
+    def report(row):
+        print(
+            f"[{time.time() - t0:6.1f}s] {row['name']:55s} "
+            f"max_err={row['max_abs_err']:.4e} (atol {row['atol']:g})  "
+            f"{'ok' if row['ok'] else 'FAIL'}"
         )
 
-    # --- bench-geometry block coverage (fwd AND bwd) -------------------
-    # Every distinct (fwd block, bwd block pair) the adaptive dispatcher
-    # can choose at the driver's bench geometry must compile + run in BOTH
-    # directions on chip before the driver runs bench.py. Round-3
-    # regression this guards: the selfcheck shapes produced no block
-    # > 1024, so the 1408 single-block branch was never compiled on
-    # hardware, and its backward scoped-vmem OOM shipped to the driver
-    # (BENCH_r03 rc=1).
-    from gigapath_tpu.ops import pallas_flash as pf
-
-    from bench import N as _BENCH_N  # stay in lockstep with the driver's bench
-
-    N_BENCH = _BENCH_N + 1  # + the model's cls token
-    seen = {}
-    for sl, r in zip(SEGS, RATIOS):
-        _g, _Lp, _n, _gp, m, block = da._bhld_geom(N_BENCH, sl, r)
-        bq, bk = pf.bwd_blocks(block)
-        # the flat (zero-glue) path and the segmented path are DIFFERENT
-        # kernels even at the same block triple — the dedup key uses the
-        # shared dispatch predicate so both variants get compiled
-        flat = da._flat_eligible(_g, r)
-        seen.setdefault((block, bq, bk, flat), (sl, r))
-    qN = jnp.asarray(rng.normal(size=(1, H, N_BENCH, Dh)), jnp.bfloat16)
-    kN = jnp.asarray(rng.normal(size=(1, H, N_BENCH, Dh)), jnp.bfloat16)
-    vN = jnp.asarray(rng.normal(size=(1, H, N_BENCH, Dh)), jnp.bfloat16)
-
-    for (block, bq, bk, flat), (sl, r) in sorted(seen.items()):
-        tag = f"sl={sl} r={r} blk={block} bwd=({bq},{bk})" + (" flat" if flat else "")
-        g_seg = min(sl, N_BENCH)
-        # A near-empty tail segment (e.g. the r=1 branch's 1-token tail at
-        # 10241 = 10x1024 + 1) has analytically-zero dq/dk — softmax over
-        # one key — so both paths produce only rounding noise there
-        # (measured ~7e-8 abs vs a 5e-7 global max: 14% under max-relative
-        # scaling). Exclude such tails from the dq/dk comparison; their
-        # values still must be finite.
-        tail = N_BENCH % g_seg
-        cmp_len = N_BENCH - tail if 0 < tail < 8 else N_BENCH
-
-        def branch_loss(x, y, z, use_pallas):
-            o, _ = da._branch_bhld(
-                x, y, z, sl, r, is_causal=False, real_len=N_BENCH,
-                interpret=False, use_pallas=use_pallas,
-            )
-            return (o.astype(jnp.float32) ** 2).mean()
-
-        val_and_grads = jax.jit(
-            jax.value_and_grad(branch_loss, argnums=(0, 1, 2)),
-            static_argnums=3,
-        )
-        loss_p, grads_p = val_and_grads(qN, kN, vN, True)
-        loss_j, grads_j = val_and_grads(qN, kN, vN, False)
-        check(f"bench-geom fwd {tag}", loss_p, loss_j, 1e-3)
-        for name, g_p, g_j in zip("qkv", grads_p, grads_j):
-            g_p = g_p.astype(jnp.float32)
-            g_j = g_j.astype(jnp.float32)
-            if not bool(jnp.isfinite(g_p).all()):
-                check(f"bench-geom d{name} {tag} finite", 1.0, 0.0, 0.0)
-                continue
-            cut = N_BENCH if name == "v" else cmp_len  # dv exact on 1-key segs
-            scale = max(float(jnp.abs(g_j[:, :, :cut]).max()), 1e-12)
-            check(
-                f"bench-geom d{name} {tag}",
-                g_p[:, :, :cut] / scale,
-                g_j[:, :, :cut] / scale,
-                6e-2,
-            )
-
-    # --- fused (phase-major, the DEFAULT) path at the bench geometry ----
-    # The pack/unpack + attention kernels the default dispatch runs at
-    # N_BENCH must compile fwd+bwd on chip before the driver's bench does,
-    # including the traced-valid-len variant the fine-tune train path uses.
-    def fused_loss(x, y, z, vl):
-        o = da.dilated_attention_fused(x, y, z, SEGS, RATIOS, valid_len=vl)
-        return (o.astype(jnp.float32) ** 2).mean()
-
-    def bhld_loss(x, y, z):
-        o = da.dilated_attention_bhld(x, y, z, SEGS, RATIOS, valid_len=N_BENCH - 64)
-        return (o.astype(jnp.float32) ** 2).mean()
-
-    qb = jnp.asarray(rng.normal(size=(1, N_BENCH, H, Dh)), jnp.bfloat16)
-    kb = jnp.asarray(rng.normal(size=(1, N_BENCH, H, Dh)), jnp.bfloat16)
-    vb = jnp.asarray(rng.normal(size=(1, N_BENCH, H, Dh)), jnp.bfloat16)
-    # static_argnums: a jitted int operand would be traced, silently
-    # routing the "static" check through the dynamic-kvlen path too
-    vg_f = jax.jit(
-        jax.value_and_grad(fused_loss, argnums=(0, 1, 2)), static_argnums=3
-    )
-    vg_t = jax.jit(jax.value_and_grad(fused_loss, argnums=(0, 1, 2)))
-    loss_f, grads_f = vg_f(qb, kb, vb, N_BENCH - 64)
-    loss_t, grads_t = vg_t(qb, kb, vb, jnp.asarray([N_BENCH - 64], jnp.int32))
-    loss_b, grads_b = jax.jit(jax.value_and_grad(bhld_loss, argnums=(0, 1, 2)))(
-        qb, kb, vb
-    )
-    check("fused bench-geom fwd (static vl)", loss_f, loss_b, 1e-3)
-    check("fused bench-geom fwd (traced vl == static)", loss_t, loss_f, 1e-6)
-    for name, g_f, g_t, g_b in zip("qkv", grads_f, grads_t, grads_b):
-        g_f, g_t, g_b = (x.astype(jnp.float32) for x in (g_f, g_t, g_b))
-        scale = max(float(jnp.abs(g_b).max()), 1e-12)
-        check(f"fused bench-geom d{name}", g_f / scale, g_b / scale, 6e-2)
-        check(f"fused bench-geom d{name} traced==static", g_t, g_f, 1e-6)
-
-    # --- round-5 env-flagged kernel variants at the bench geometry -----
-    # GIGAPATH_PIPELINED_ATTN (software-pipelined forward) and
-    # GIGAPATH_PACK_DIRECT (dense-layout pack/unpack) must compile and
-    # agree on chip BEFORE any bench/dispatch default flips to them —
-    # the BENCH_r03 lesson, applied to this round's candidates. Flags are
-    # read at trace time; a fresh function identity per combo defeats the
-    # jit cache.
-    def make_fused_loss():
-        def f(x, y, z, vl):
-            o = da.dilated_attention_fused(x, y, z, SEGS, RATIOS, valid_len=vl)
-            return (o.astype(jnp.float32) ** 2).mean()
-
-        return f
-
-    combos = [
-        ("pipe", {"GIGAPATH_PIPELINED_ATTN": "1"}, 1e-3),
-        ("direct", {"GIGAPATH_PACK_DIRECT": "1"}, 1e-6),  # bit-identical path
-        ("pipebwd", {"GIGAPATH_PIPELINED_BWD": "1"}, 1e-6),  # fwd unchanged
-        (
-            "all",
-            {
-                "GIGAPATH_PIPELINED_ATTN": "1",
-                "GIGAPATH_PACK_DIRECT": "1",
-                "GIGAPATH_PIPELINED_BWD": "1",
-            },
-            1e-3,
-        ),
-    ]
-    for tag, env, tol in combos:
-        prior = {key: os.environ.get(key) for key in env}
-        os.environ.update(env)
-        try:
-            vg = jax.jit(
-                jax.value_and_grad(make_fused_loss(), argnums=(0, 1, 2)),
-                static_argnums=3,
-            )
-            loss_v, grads_v = vg(qb, kb, vb, N_BENCH - 64)
-            # traced valid_len (the fine-tune train path) on the same combo
-            loss_tv, _ = jax.jit(
-                jax.value_and_grad(make_fused_loss(), argnums=(0, 1, 2))
-            )(qb, kb, vb, jnp.asarray([N_BENCH - 64], jnp.int32))
-        finally:
-            for key, val in prior.items():
-                if val is None:
-                    os.environ.pop(key, None)
-                else:
-                    os.environ[key] = val
-        check(f"flagged[{tag}] bench-geom fwd", loss_v, loss_f, tol)
-        check(f"flagged[{tag}] traced vl == static", loss_tv, loss_v, 1e-6)
-        for name, g_v, g_f2 in zip("qkv", grads_v, grads_f):
-            g_v, g_f2 = (x.astype(jnp.float32) for x in (g_v, g_f2))
-            scale = max(float(jnp.abs(g_f2).max()), 1e-12)
-            check(
-                f"flagged[{tag}] d{name}", g_v / scale, g_f2 / scale,
-                1e-6 if tag == "direct" else 1e-2,
-            )
-
-    if FAILED:
-        print("FAILED:", FAILED)
-        sys.exit(1)
-    print(f"all on-chip checks passed in {time.time() - _T0:.1f}s")  # gigalint: waive GL008 -- whole-script wall; every check() already fetched its operands to the host
+    rows = run_kernel_checks(flagship(N), flagged_variants=True, report=report)
+    failed = [r["name"] for r in rows if not r["ok"]]
+    if failed:
+        print("FAILED:", failed)
+        return 1
+    print(f"all {len(rows)} on-chip checks passed in {time.time() - t0:.1f}s")  # gigalint: waive GL008 -- whole-script wall; every check already fetched its operands to the host
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

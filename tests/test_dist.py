@@ -959,3 +959,21 @@ class TestStreamingConsumer:
         events = _run_events(tmp_path / "clean")
         assert _of(events, "stream_open")
         assert _of(events, "stream_finalize")
+
+
+def test_stage_process_devices_names_the_cause(monkeypatch):
+    """A stage process that cannot get a device fails with an error that
+    says what the two-process layout needs — not with whatever the backend
+    raised, swallowed by a DEVNULL stderr."""
+    from gigapath_tpu.dist import stagemesh
+
+    def in_use(*a, **k):
+        raise RuntimeError("TPU is already in use by another process")
+
+    monkeypatch.setattr(jax, "devices", in_use)
+    with pytest.raises(RuntimeError, match="needs a device per process") as err:
+        stagemesh.stage_process_devices()
+    assert "already in use" in str(err.value)
+    monkeypatch.undo()
+    assert stagemesh.stage_process_devices() == jax.devices()[:1]
+

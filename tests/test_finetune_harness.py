@@ -176,7 +176,7 @@ class TestCheckpoint:
 
     def test_kill_and_resume_reproduces_training(self, rng):
         """Save params+opt_state mid-run; resuming reproduces the same
-        trajectory as the uninterrupted run (VERDICT r1 next-step 7)."""
+        trajectory as the uninterrupted run (an earlier review's next step)."""
         import tempfile
 
         params = {"w": jnp.asarray(rng.normal(size=(4, 4)), jnp.float32)}

@@ -335,15 +335,13 @@ def test_ring_per_shard_bytes_scale_with_chunk_not_segment(tmp_path):
     the segment — the gather path materializes the full-segment K/V on
     every shard (plus full-width logits), the ring only chunk-sized
     buffers. Captured through the perf ledger on an 8-way CPU mesh."""
-    from jax.sharding import Mesh, PartitionSpec as P
-
     import numpy as np
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
 
     from gigapath_tpu.ops.dilated_attention import dilated_attention
     from gigapath_tpu.ops.pallas_dilated import PipelineFlags
-    from gigapath_tpu.parallel.sharding import shard_map_compat
 
-    shard_map, check_kw = shard_map_compat()
     L, H, Dh, ndev = 512, 4, 8, 8  # one oversized branch: sl == L, 8 ranks
     mesh = Mesh(np.array(jax.devices()[:ndev]), ("seq",))
     q = jnp.ones((1, L, H, Dh), jnp.float32)
@@ -355,7 +353,7 @@ def test_ring_per_shard_bytes_scale_with_chunk_not_segment(tmp_path):
                 flags=PipelineFlags(ring_attn=ring),
             ),
             mesh=mesh, in_specs=(P(None, "seq"),) * 3,
-            out_specs=P(None, "seq"), **check_kw,
+            out_specs=P(None, "seq"), check_vma=False,
         ))
 
     docs = {}

@@ -136,7 +136,7 @@ def test_unaligned_lengths(rng):
 def test_bwd_blocks_fit_budget():
     """The backward block pair must fit the backward's scoped-vmem budget.
 
-    Regression for the BENCH_r03 crash: m=1281 (flagship r=8 branch at
+    Regression for the round-3 driver crash: m=1281 (flagship r=8 branch at
     N=10241) picks a 1408 forward single block, and reusing it squared in
     the backward overflowed scoped vmem (20.12 MB vs the 16 MB limit)."""
     from gigapath_tpu.ops.dilated_attention import _bhld_geom

@@ -61,6 +61,17 @@ PALLAS = PipelineFlags(fold_pallas=True)
 _COVERED = NEG_INF * 0.5
 
 
+@pytest.fixture(autouse=True)
+def _pallas_interpret_mode():
+    """The fold's dispatch seam (``flags.fold_pallas``) passes no
+    ``interpret`` of its own: on this CPU platform the tests ask for
+    interpret mode explicitly, through Pallas' own switch."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
 @pytest.fixture
 def clean_env(monkeypatch, tmp_path):
     """Zero kernel env flags + a private registry path (mirrors

@@ -1,0 +1,248 @@
+"""Compile the main path's Pallas kernels for a DESCRIBED TPU v5e.
+
+No chip is attached here: the TPU compiler that ships with the installation
+compiles for a topology that is described (``v5e:2x2``), and refuses what
+the chip's compiler would refuse — a slice off the tiling, a kernel over its
+scoped-VMEM budget (the failure that once reached the driver from the
+m=1281 / 1408-block backward), a program that does not fit the device. A
+compile that passes is not a chip run: it says nothing about results or time.
+
+Kernels only, at flagship widths (H=16, Dh=48, the flagship schedule) and the
+shapes ``chip_smoke.py`` executes; the 100-second whole-model compiles stay
+out of the suite.
+
+Rules this file keeps (the on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped, non-autouse fixture that skips
+when it cannot be — never at import, in a ``skipif`` or in a ``parametrize``
+argument; every compile runs in the test's own process (the worker that
+describes the topology holds the TPU library until it exits); all such tests
+live in this one file; the persistent compile cache is off around them (a
+cached entry for a described chip cannot be read back without one).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+H, DH = 16, 48
+N_BENCH = 10241          # bench N + the cls token
+N_BUCKET = 16384 + 1     # the larger ragged PANDA bucket + cls
+FOLD_CHUNK = 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to compile for
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def schedule():
+    from gigapath_tpu.models.longnet_config import flagship_geometry
+
+    g = flagship_geometry()
+    assert (g["heads"], g["head_dim"]) == (H, DH)
+    return list(g["segment_lengths"]), list(g["dilated_ratios"])
+
+
+def _compile(fn, one_chip, *avals):
+    """Compile ``fn`` for the described chip; returns the compiled text's
+    kernel count (a dispatch that fell to the jnp tier compiles to zero)."""
+    args = [
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip) for a in avals
+    ]
+    from gigapath_tpu.obs.ledger import custom_calls_of
+
+    n = custom_calls_of(jax.jit(fn).lower(*args).compile())
+    assert n, "compiled program holds no tpu_custom_call"
+    return n
+
+
+def _blhd(L, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct((1, L, H, DH), dtype)
+
+
+def _sq_mean(o):
+    return (o.astype(jnp.float32) ** 2).mean()
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize("path", ["fused", "bhld"])
+def test_dilated_attention_at_bench_length(topo, one_chip, schedule, path, grad):
+    from gigapath_tpu.ops import dilated_attention as da
+
+    segs, ratios = schedule
+
+    def fwd(q, k, v):
+        if path == "fused":
+            return da.dilated_attention_fused(q, k, v, segs, ratios)
+        return da.dilated_attention_bhld(q, k, v, segs, ratios, use_pallas=True)
+
+    fn = fwd
+    if grad:
+        fn = jax.grad(lambda q, k, v: _sq_mean(fwd(q, k, v)), argnums=(0, 1, 2))
+    x = _blhd(N_BENCH)
+    _compile(fn, one_chip, x, x, x)
+
+
+def test_fused_grad_ragged_bucket_traced_valid_len(topo, one_chip, schedule):
+    """The fine-tune train path: the 16,384 bucket, ``valid_len`` a traced
+    [B] count (it rides the kernels' SMEM tables)."""
+    from gigapath_tpu.ops import dilated_attention as da
+
+    segs, ratios = schedule
+
+    def loss(q, k, v, valid_len):
+        return _sq_mean(
+            da.dilated_attention_fused(q, k, v, segs, ratios, valid_len=valid_len)
+        )
+
+    x = _blhd(N_BUCKET)
+    _compile(
+        jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+        x, x, x, jax.ShapeDtypeStruct((1,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("branch", range(5))
+def test_pair_partial_flagship_pairs(topo, one_chip, schedule, branch):
+    """The streaming fold kernel at chunk 2,048, forward and backward, for
+    each flagship (segment, ratio) pair."""
+    from gigapath_tpu.ops.pallas_streaming import pallas_pair_partial
+
+    segs, ratios = schedule
+    sl, r = int(segs[branch]), int(ratios[branch])
+
+    def loss(q, k, v, q0, k0, valid_len):
+        out, lse = pallas_pair_partial(
+            q, k, v, q0, k0, segment_len=sl, ratio=r, valid_len=valid_len
+        )
+        return _sq_mean(out) + lse.mean()
+
+    x = _blhd(FOLD_CHUNK)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
+        x, x, x, scalar, scalar, scalar,
+    )
+
+
+def test_r03_branch_backward(topo, one_chip):
+    """The shape that died on the driver in round 3: the flagship r=8 branch
+    at N=10,241 has sparse length m=1,281 and picks one 1,408 forward block;
+    squared in the backward it overflowed scoped VMEM (20.12 MB > 16 MB).
+    ``bwd_blocks`` now splits the k side — this compiles the backward the
+    chip's compiler once refused."""
+    from gigapath_tpu.ops import dilated_attention as da
+
+    sl, r = 185363, 8
+    *_rest, m, block = da._bhld_geom(N_BENCH, sl, r)
+    assert (m, block) == (1281, 1408)
+
+    def loss(q, k, v):
+        out, _ = da._branch_bhld(
+            q, k, v, sl, r, is_causal=False, real_len=N_BENCH,
+            interpret=False, use_pallas=True,
+        )
+        return _sq_mean(out)
+
+    x = jax.ShapeDtypeStruct((1, H, N_BENCH, DH), jnp.bfloat16)  # head-major
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip, x, x, x)
+
+
+def test_flash_and_flat_segment_standalone(topo, one_chip):
+    from gigapath_tpu.ops.pallas_flash import flat_segment_flash, pallas_flash_attention
+
+    def flash_loss(q, k, v):
+        out, lse = pallas_flash_attention(q, k, v)
+        return _sq_mean(out) + lse.mean()
+
+    x = _blhd(2048)
+    _compile(jax.value_and_grad(flash_loss, argnums=(0, 1, 2)), one_chip, x, x, x)
+
+    def flat_loss(q, k, v):
+        out, lse = flat_segment_flash(q, k, v, segment_len=1024, real_len=N_BENCH)
+        return _sq_mean(out) + lse.mean()
+
+    xh = jax.ShapeDtypeStruct((1, H, N_BENCH, DH), jnp.bfloat16)
+    _compile(jax.value_and_grad(flat_loss, argnums=(0, 1, 2)), one_chip, xh, xh, xh)
+
+
+def test_quant_kernels_at_vit_g_widths(topo, one_chip):
+    """``quant/`` Pallas tiers at the tile encoder's widths: d=1536 into the
+    SwiGLU hidden 8192 over a batch-128 x 197-token activation, and int8-logit
+    attention over heads of 64 (block-aligned length: 197 itself rides the
+    reference tier by design)."""
+    from gigapath_tpu.quant.qflash import q_flash_attention_pallas
+    from gigapath_tpu.quant.qmatmul import q_matmul_pallas
+    from gigapath_tpu.quant.qtensor import QTensor
+
+    def matmul(x, w_q, scale):
+        return q_matmul_pallas(x, QTensor(w_q, scale))
+
+    _compile(
+        matmul, one_chip,
+        jax.ShapeDtypeStruct((128, 197, 1536), jnp.bfloat16),
+        jax.ShapeDtypeStruct((1536, 8192), jnp.int8),
+        jax.ShapeDtypeStruct((1, 8192), jnp.float32),
+    )
+    x = jax.ShapeDtypeStruct((8, 256, 24, 64), jnp.bfloat16)
+    _compile(lambda q, k, v: q_flash_attention_pallas(q, k, v), one_chip, x, x, x)
+
+
+def test_fused_local_branches_inside_shard_map_on_four_chips(topo, schedule):
+    """The sequence-parallel recipe's LOCAL branches on a four-chip ``seq``
+    mesh: per shard they are the single-device fused kernels, inside
+    ``shard_map(check_vma=False)`` — a program no CPU platform ever built.
+    16,384 tokens per chip, as ``chip_smoke.py --chips 4`` runs it."""
+    from jax import shard_map
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from gigapath_tpu.ops.pallas_dilated import dilated_branch_attention
+
+    segs, ratios = schedule
+    mesh = Mesh(np.array(topo.devices[:4]), ("seq",))
+    L, E = 4 * 16384, H * DH
+
+    def local(q, k, v):
+        outs = []
+        for sl, r in zip(segs, ratios):
+            if sl > q.shape[1]:
+                continue  # gathered branches take the generic path
+            out, _ = dilated_branch_attention(q, k, v, int(sl), int(r), H)
+            outs.append(out)
+        assert len(outs) == 2  # segments 1,024 and 5,792 are local
+        return sum(outs)
+
+    fn = jax.jit(shard_map(
+        local, mesh=mesh, in_specs=(P(None, "seq"),) * 3,
+        out_specs=P(None, "seq"), check_vma=False,
+    ))
+    x = jax.ShapeDtypeStruct(
+        (1, L, E), jnp.bfloat16, sharding=NamedSharding(mesh, P(None, "seq"))
+    )
+    from gigapath_tpu.obs.ledger import custom_calls_of
+
+    assert custom_calls_of(fn.lower(x, x, x).compile())
