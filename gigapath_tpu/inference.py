@@ -221,7 +221,7 @@ def _run_inference_bucketed(model, params, feature_files, output_file,
     """
     from gigapath_tpu.serve import ServeConfig, SlideService
 
-    def forward(p, embeds, coords, pad_mask):
+    def serve_forward(p, embeds, coords, pad_mask):
         return model.apply({"params": p}, embeds, coords,
                            pad_mask=pad_mask, deterministic=True)
 
@@ -239,7 +239,7 @@ def _run_inference_bucketed(model, params, feature_files, output_file,
         f"|feat{getattr(model, 'feat_layer', '?')}"
         f"|cls{getattr(model, 'n_classes', '?')}"
     )
-    service = SlideService(forward, params, config=config, runlog=runlog,
+    service = SlideService(serve_forward, params, config=config, runlog=runlog,
                            identity=identity, name="serve")
     results = []
     warned: list = []

@@ -136,15 +136,18 @@ class ViTAttention(nn.Module):
             3 * D, quant=self.quant, quant_pallas=self.quant_pallas,
             dtype=self.dtype, param_dtype=self.param_dtype, name="qkv"
         )(x)
-        qkv = qkv.reshape(B, N, 3, H, hd)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        if self.quant and self.quant.endswith("+attn"):
-            from gigapath_tpu.quant.qflash import q_flash_attention
+        with jax.named_scope("attn_core"):
+            qkv = qkv.reshape(B, N, 3, H, hd)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            if self.quant and self.quant.endswith("+attn"):
+                from gigapath_tpu.quant.qflash import q_flash_attention
 
-            out, _ = q_flash_attention(q, k, v, use_pallas=self.quant_pallas)
-        else:
-            out, _ = attention_with_lse(q, k, v)
-        out = out.reshape(B, N, D)
+                out, _ = q_flash_attention(
+                    q, k, v, use_pallas=self.quant_pallas
+                )
+            else:
+                out, _ = attention_with_lse(q, k, v)
+            out = out.reshape(B, N, D)
         return _dense(
             D, quant=self.quant, quant_pallas=self.quant_pallas,
             dtype=self.dtype, param_dtype=self.param_dtype, name="proj"

@@ -227,6 +227,7 @@ def _undilate_bhld(
     return out_d.reshape(B, H, n, m * ratio, Dh), lse_d.reshape(B, H, n, m * ratio)
 
 
+@jax.named_scope("kernel_fwd")
 def _segment_attention_jnp(
     q5: jnp.ndarray, k5: jnp.ndarray, v5: jnp.ndarray, kvlen, is_causal: bool
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -275,6 +276,7 @@ def _bhld_geom(L: int, sl: int, r: int) -> Tuple[int, int, int, int, int, int]:
     return g, Lp, n, gp, m, block
 
 
+@jax.named_scope("dilate")
 def _seg_dilate(x: jnp.ndarray, g: int, Lp: int, n: int, gp: int, r: int) -> jnp.ndarray:
     """[B, H, L, D] -> dilated segment view [B, H, n, m, D] (static slices)."""
     B, H, L, Dh = x.shape
@@ -286,6 +288,7 @@ def _seg_dilate(x: jnp.ndarray, g: int, Lp: int, n: int, gp: int, r: int) -> jnp
     return _dilate_bhld(x, r)
 
 
+@jax.named_scope("undilate")
 def _undilate_to_dense(out_s, lse_s, r, g, Lp, L):
     B, H = out_s.shape[:2]
     Dh = out_s.shape[-1]
@@ -496,6 +499,7 @@ def _branch_bhld(
     return _undilate_to_dense(out_s, lse_s, r, g, Lp, L)
 
 
+@jax.named_scope("dilated_attn")
 def dilated_attention_fused(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -582,18 +586,19 @@ def dilated_attention_fused(
 
     def branch(sl, r):
         sl, r = int(sl), int(r)
-        if H % r == 0 and E % r == 0:
-            return dilated_branch_attention(
-                qE, kE, vE, sl, r, H,
-                real_len=real_len, valid_len_dyn=valid_dyn,
-                is_causal=is_causal, interpret=interpret, flags=flags,
+        with jax.named_scope(f"branch_r{r}"):
+            if H % r == 0 and E % r == 0:
+                return dilated_branch_attention(
+                    qE, kE, vE, sl, r, H,
+                    real_len=real_len, valid_len_dyn=valid_dyn,
+                    is_causal=is_causal, interpret=interpret, flags=flags,
+                )
+            qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+            o4, l = _branch_bhld(
+                qh, kh, vh, sl, r, is_causal=is_causal, real_len=real_len,
+                interpret=interpret, use_pallas=None, valid_len_dyn=valid_dyn,
             )
-        qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-        o4, l = _branch_bhld(
-            qh, kh, vh, sl, r, is_causal=is_causal, real_len=real_len,
-            interpret=interpret, use_pallas=None, valid_len_dyn=valid_dyn,
-        )
-        return o4.transpose(0, 2, 1, 3).reshape(B, L, E), l
+            return o4.transpose(0, 2, 1, 3).reshape(B, L, E), l
 
     if streaming_fusion and len(segment_lengths) > 1:
         # Online softmax over the branch axis (same math as the stacked
@@ -608,20 +613,22 @@ def dilated_attention_fused(
         acc = m_run = l_run = None
         for sl, r in zip(segment_lengths, dilated_ratios):
             o, l = branch(sl, r)
-            l = jax.lax.stop_gradient(l)  # [B, H, L]
-            o = o.reshape(B, L, H, Dh)
-            if acc is None:
-                m_run = l
-                l_run = jnp.ones_like(l)
-                acc = o.astype(jnp.float32)
-            else:
-                m_new = jnp.maximum(m_run, l)
-                a = jnp.exp(m_run - m_new)
-                b_ = jnp.exp(l - m_new)
-                l_run = l_run * a + b_
-                acc = acc * bLH1(a) + o.astype(jnp.float32) * bLH1(b_)
-                m_run = m_new
-        return (acc / bLH1(l_run)).astype(q.dtype)
+            with jax.named_scope("merge"):
+                l = jax.lax.stop_gradient(l)  # [B, H, L]
+                o = o.reshape(B, L, H, Dh)
+                if acc is None:
+                    m_run = l
+                    l_run = jnp.ones_like(l)
+                    acc = o.astype(jnp.float32)
+                else:
+                    m_new = jnp.maximum(m_run, l)
+                    a = jnp.exp(m_run - m_new)
+                    b_ = jnp.exp(l - m_new)
+                    l_run = l_run * a + b_
+                    acc = acc * bLH1(a) + o.astype(jnp.float32) * bLH1(b_)
+                    m_run = m_new
+        with jax.named_scope("merge"):
+            return (acc / bLH1(l_run)).astype(q.dtype)
 
     outs, lses = [], []
     for sl, r in zip(segment_lengths, dilated_ratios):
@@ -632,19 +639,22 @@ def dilated_attention_fused(
     if len(outs) == 1:
         out = outs[0]
     else:
-        lse = jnp.stack(lses)  # [n_branch, B, H, L]
-        weights = jax.nn.softmax(jax.lax.stop_gradient(lse), axis=0)
-        acc = 0.0
-        for o, w in zip(outs, weights):
-            # w [B,H,L] -> [B,L,H,1] broadcast over the head's lanes; the
-            # whole fusion is one elementwise pass over the branch outputs
-            acc = acc + o.reshape(B, L, H, Dh).astype(jnp.float32) * (
-                w.transpose(0, 2, 1)[..., None]
-            )
-        out = acc.reshape(B, L, E)
+        with jax.named_scope("merge"):
+            lse = jnp.stack(lses)  # [n_branch, B, H, L]
+            weights = jax.nn.softmax(jax.lax.stop_gradient(lse), axis=0)
+            acc = 0.0
+            for o, w in zip(outs, weights):
+                # w [B,H,L] -> [B,L,H,1] broadcast over the head's lanes;
+                # the whole fusion is one elementwise pass over the branch
+                # outputs
+                acc = acc + o.reshape(B, L, H, Dh).astype(jnp.float32) * (
+                    w.transpose(0, 2, 1)[..., None]
+                )
+            out = acc.reshape(B, L, E)
     return out.astype(q.dtype).reshape(B, L, H, Dh)
 
 
+@jax.named_scope("dilated_attn")
 def dilated_attention_bhld(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -677,7 +687,7 @@ def dilated_attention_bhld(
     # optimization barriers pin the op's boundaries: without them XLA fuses
     # the entry/exit relayouts into the surrounding layernorm/projection
     # fusions, which then read the 48-lane-minor head-major layout strided
-    # (measured +0.65 ms/layer on the flagship, scripts/profile_slide.py)
+    # (measured +0.65 ms/layer on the flagship, PERFORMANCE.md)
     q, k, v = jax.lax.optimization_barrier((q, k, v))
     qh = q.transpose(0, 2, 1, 3)
     kh = k.transpose(0, 2, 1, 3)
@@ -700,46 +710,51 @@ def dilated_attention_bhld(
         # BOTH layouts and pushed 256k from 12.7 GB to an OOM at 15.9 GB.
         acc = m_run = l_run = None
         for sl, r in zip(segment_lengths, dilated_ratios):
-            o, l = _branch_bhld(
-                qh, kh, vh, int(sl), int(r),
-                is_causal=is_causal, real_len=real_len,
-                interpret=interpret, use_pallas=use_pallas,
-                valid_len_dyn=valid_dyn,
-            )
-            l = jax.lax.stop_gradient(l)[..., None]  # [B, H, L, 1]
-            if acc is None:
-                m_run = l
-                l_run = jnp.ones_like(l)
-                acc = o.astype(jnp.float32)
-            else:
-                m_new = jnp.maximum(m_run, l)
-                a = jnp.exp(m_run - m_new)
-                b_ = jnp.exp(l - m_new)
-                l_run = l_run * a + b_
-                acc = acc * a + o.astype(jnp.float32) * b_
-                m_run = m_new
-        out = acc / l_run
+            with jax.named_scope(f"branch_r{int(r)}"):
+                o, l = _branch_bhld(
+                    qh, kh, vh, int(sl), int(r),
+                    is_causal=is_causal, real_len=real_len,
+                    interpret=interpret, use_pallas=use_pallas,
+                    valid_len_dyn=valid_dyn,
+                )
+            with jax.named_scope("merge"):
+                l = jax.lax.stop_gradient(l)[..., None]  # [B, H, L, 1]
+                if acc is None:
+                    m_run = l
+                    l_run = jnp.ones_like(l)
+                    acc = o.astype(jnp.float32)
+                else:
+                    m_new = jnp.maximum(m_run, l)
+                    a = jnp.exp(m_run - m_new)
+                    b_ = jnp.exp(l - m_new)
+                    l_run = l_run * a + b_
+                    acc = acc * a + o.astype(jnp.float32) * b_
+                    m_run = m_new
+        with jax.named_scope("merge"):
+            out = acc / l_run
         return jax.lax.optimization_barrier(
             out.astype(q.dtype).transpose(0, 2, 1, 3)
         )
 
     outs, lses = [], []
     for sl, r in zip(segment_lengths, dilated_ratios):
-        o, l = _branch_bhld(
-            qh, kh, vh, int(sl), int(r),
-            is_causal=is_causal, real_len=real_len,
-            interpret=interpret, use_pallas=use_pallas,
-            valid_len_dyn=valid_dyn,
-        )
+        with jax.named_scope(f"branch_r{int(r)}"):
+            o, l = _branch_bhld(
+                qh, kh, vh, int(sl), int(r),
+                is_causal=is_causal, real_len=real_len,
+                interpret=interpret, use_pallas=use_pallas,
+                valid_len_dyn=valid_dyn,
+            )
         outs.append(o)
         lses.append(l)
 
     if len(outs) == 1:
         out = outs[0]
     else:
-        lse = jnp.stack(lses)  # [n_branch, B, H, L]
-        weights = jax.nn.softmax(jax.lax.stop_gradient(lse), axis=0)[..., None]
-        out = sum(o.astype(jnp.float32) * w for o, w in zip(outs, weights))
+        with jax.named_scope("merge"):
+            lse = jnp.stack(lses)  # [n_branch, B, H, L]
+            weights = jax.nn.softmax(jax.lax.stop_gradient(lse), axis=0)[..., None]
+            out = sum(o.astype(jnp.float32) * w for o, w in zip(outs, weights))
     return jax.lax.optimization_barrier(
         out.astype(q.dtype).transpose(0, 2, 1, 3)
     )
@@ -1226,62 +1241,67 @@ def dilated_attention(
             vl_local, seq_axis_name, axis=0
         )  # [W, B]
 
-    outs, lses = [], []
-    for i, (sl, r) in enumerate(zip(segment_lengths, dilated_ratios)):
-        sl_i, r_i = int(sl), int(r)
-        if seq_active and sl_i < k.shape[1] and k.shape[1] % sl_i:
-            # each shard segments its own tokens from its own start: a
-            # local segment that does not divide the shard puts segment
-            # boundaries elsewhere than the unsharded op does
-            _warn_once(
-                "sequence-parallel dilated attention: segment length %d does "
-                "not divide the %d-token shard, so this branch's segments "
-                "restart at every shard boundary and the result differs from "
-                "the unsharded op" % (sl_i, k.shape[1])
-            )
-        if (
-            fused_local
-            and sl_i <= k.shape[1]
-            and H % r_i == 0
-            and (H * Dh) % r_i == 0
-        ):
-            from gigapath_tpu.ops.pallas_dilated import dilated_branch_attention
+    with jax.named_scope("dilated_attn"):
+        outs, lses = [], []
+        for i, (sl, r) in enumerate(zip(segment_lengths, dilated_ratios)):
+            sl_i, r_i = int(sl), int(r)
+            if seq_active and sl_i < k.shape[1] and k.shape[1] % sl_i:
+                # each shard segments its own tokens from its own start: a
+                # local segment that does not divide the shard puts segment
+                # boundaries elsewhere than the unsharded op does
+                _warn_once(
+                    "sequence-parallel dilated attention: segment length %d "
+                    "does not divide the %d-token shard, so this branch's "
+                    "segments restart at every shard boundary and the result "
+                    "differs from the unsharded op" % (sl_i, k.shape[1])
+                )
+            with jax.named_scope(f"branch_r{r_i}"):
+                if (
+                    fused_local
+                    and sl_i <= k.shape[1]
+                    and H % r_i == 0
+                    and (H * Dh) % r_i == 0
+                ):
+                    from gigapath_tpu.ops.pallas_dilated import (
+                        dilated_branch_attention,
+                    )
 
-            oE, l = dilated_branch_attention(
-                q.reshape(B, L, H * Dh), k.reshape(B, L, H * Dh),
-                v.reshape(B, L, H * Dh), sl_i, r_i, H,
-                real_len=sp_real_len, valid_len_dyn=sp_valid_dyn,
-                is_causal=is_causal, flags=sp_flags,
-            )
-            outs.append(oE.reshape(B, L, H, Dh))
+                    oE, l = dilated_branch_attention(
+                        q.reshape(B, L, H * Dh), k.reshape(B, L, H * Dh),
+                        v.reshape(B, L, H * Dh), sl_i, r_i, H,
+                        real_len=sp_real_len, valid_len_dyn=sp_valid_dyn,
+                        is_causal=is_causal, flags=sp_flags,
+                    )
+                    o = oE.reshape(B, L, H, Dh)
+                else:
+                    branch_fn = attn_fn
+                    if dropout_rate > 0.0 and dropout_rng is not None:
+                        branch_fn = make_attn_fn(rngs[i])
+                    o, l = _dilated_branch(
+                        q, k, v, sl_i, r_i,
+                        is_causal=is_causal, offset=offset, attn_fn=branch_fn,
+                        seq_axis_name=seq_axis_name,
+                        seq_axis_size=seq_axis_size,
+                        valid_len=valid_len, gathered_counts=gathered_counts,
+                        ring=ring_attn, ring_allow_pallas=ring_allow_pallas,
+                    )
+            outs.append(o)
             lses.append(l)
-            continue
-        branch_fn = attn_fn
-        if dropout_rate > 0.0 and dropout_rng is not None:
-            branch_fn = make_attn_fn(rngs[i])
-        o, l = _dilated_branch(
-            q, k, v, sl_i, r_i,
-            is_causal=is_causal, offset=offset, attn_fn=branch_fn,
-            seq_axis_name=seq_axis_name, seq_axis_size=seq_axis_size,
-            valid_len=valid_len, gathered_counts=gathered_counts,
-            ring=ring_attn, ring_allow_pallas=ring_allow_pallas,
-        )
-        outs.append(o)
-        lses.append(l)
 
-    if len(outs) == 1:
-        return outs[0]
+        if len(outs) == 1:
+            return outs[0]
 
-    # LSE-weighted fusion across branches; weights are constants in backward
-    # (parity with reference scattering:119-128 under torch.no_grad).
-    lse = jnp.stack(lses)  # [n, B, H, L]
-    weights = jax.nn.softmax(jax.lax.stop_gradient(lse), axis=0)
-    out = sum(
-        o.astype(jnp.float32) * w[..., None].transpose(0, 2, 1, 3)  # [B,H,L,1]->[B,L,H,1]
-        for o, w in zip(outs, weights)
-    )
-    return out.astype(q.dtype)
-
+        # LSE-weighted fusion across branches; weights are constants in
+        # backward (parity with reference scattering:119-128 under
+        # torch.no_grad).
+        with jax.named_scope("merge"):
+            lse = jnp.stack(lses)  # [n, B, H, L]
+            weights = jax.nn.softmax(jax.lax.stop_gradient(lse), axis=0)
+            out = sum(
+                o.astype(jnp.float32) * w[..., None].transpose(0, 2, 1, 3)  # [B,H,L,1]->[B,L,H,1]
+                for o, w in zip(outs, weights)
+            )
+            return out.astype(q.dtype)
 
 def _dilated_branch(
     q: jnp.ndarray,
@@ -1332,17 +1352,18 @@ def _dilated_branch(
         seq_axis_name is not None and seq_axis_size > 1 and sl > k.shape[1]
     )
 
-    g_q = min(sl, Lq)
-    qp = _pad_to_multiple(q, g_q, axis=1)
-    n_seg = qp.shape[1] // g_q
-    qs = qp.reshape(B * n_seg, g_q, H, Dh)
-    qs = dense_to_sparse(qs, r)
+    with jax.named_scope("pack"):
+        g_q = min(sl, Lq)
+        qp = _pad_to_multiple(q, g_q, axis=1)
+        n_seg = qp.shape[1] // g_q
+        qs = qp.reshape(B * n_seg, g_q, H, Dh)
+        qs = dense_to_sparse(qs, r)
 
-    g_k = min(sl, k.shape[1])
-    kp = _pad_to_multiple(k, g_k, axis=1).reshape(-1, g_k, H, Dh)
-    vp = _pad_to_multiple(v, g_k, axis=1).reshape(-1, g_k, H, Dh)
-    ks = dense_to_sparse(kp, r)
-    vs = dense_to_sparse(vp, r)
+        g_k = min(sl, k.shape[1])
+        kp = _pad_to_multiple(k, g_k, axis=1).reshape(-1, g_k, H, Dh)
+        vp = _pad_to_multiple(v, g_k, axis=1).reshape(-1, g_k, H, Dh)
+        ks = dense_to_sparse(kp, r)
+        vs = dense_to_sparse(vp, r)
 
     kv_valid_len = None
     sp_causal_bias = None
@@ -1496,11 +1517,12 @@ def _dilated_branch(
             qs, ks, vs, is_causal=is_causal, kv_valid_len=kv_valid_len
         )
 
-    out_d, lse_d = sparse_to_dense(out_s, lse_s, r, g_q)
-    out = out_d.reshape(B, n_seg * g_q, H, Dh)
-    lse = lse_d.reshape(B, n_seg, H, g_q).transpose(0, 2, 1, 3).reshape(B, H, -1)
-    start = offset % sl if offset > 0 else 0
-    return out[:, start : start + L], lse[..., start : start + L]
+    with jax.named_scope("unpack"):
+        out_d, lse_d = sparse_to_dense(out_s, lse_s, r, g_q)
+        out = out_d.reshape(B, n_seg * g_q, H, Dh)
+        lse = lse_d.reshape(B, n_seg, H, g_q).transpose(0, 2, 1, 3).reshape(B, H, -1)
+        start = offset % sl if offset > 0 else 0
+        return out[:, start : start + L], lse[..., start : start + L]
 
 
 class DilatedAttention(MultiheadAttention):

@@ -72,9 +72,10 @@ def flash_attention(
         from gigapath_tpu.ops.pallas_flash import pallas_flash_attention
 
         return pallas_flash_attention(q, k, v, is_causal=is_causal, kv_len=kv_valid_len)
-    return attention_with_lse(
-        q, k, v, is_causal=is_causal, bias=bias, kv_valid_len=kv_valid_len
-    )
+    with jax.named_scope("kernel_fwd"):
+        return attention_with_lse(
+            q, k, v, is_causal=is_causal, bias=bias, kv_valid_len=kv_valid_len
+        )
 
 
 def partial_attention(

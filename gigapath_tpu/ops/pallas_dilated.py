@@ -327,23 +327,25 @@ def _fwd_impl_pipe(q6, k6, v6, kvlen, scale, heads, head_dim,
         _fwd_kernel_pipe, scale=scale,
         block_q=block_q, block_k=block_k, hb=hb, nk=nk,
     )
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(B, S, r, nq, total + 1),
-        in_specs=[spec_q, spec_k, spec_v, pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=[spec_o, lse_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct(q6.shape, q6.dtype),
-            jax.ShapeDtypeStruct((B, S, r, M, LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, head_dim), jnp.float32),
-            pltpu.VMEM((2, block_q, block_k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q6, k6, v6, kvlen)
+    with jax.named_scope("kernel_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=(B, S, r, nq, total + 1),
+            in_specs=[spec_q, spec_k, spec_v, pl.BlockSpec(memory_space=pltpu.SMEM)],
+            out_specs=[spec_o, lse_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct(q6.shape, q6.dtype),
+                jax.ShapeDtypeStruct((B, S, r, M, LANES), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, head_dim), jnp.float32),
+                pltpu.VMEM((2, block_q, block_k), jnp.float32),
+            ],
+            interpret=interpret,
+            name="dilated_fwd_pipe",
+        )(q6, k6, v6, kvlen)
     return out, lse
 
 
@@ -372,22 +374,24 @@ def _fwd_impl(q6, k6, v6, kvlen, causal, scale, heads, head_dim,
         _fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k,
     )
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(B, S, r, nq, heads, nk),
-        in_specs=[spec_q, spec_k, spec_k, pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=[spec_q, lse_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct(q6.shape, q6.dtype),
-            jax.ShapeDtypeStruct((B, S, r, M, LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, head_dim), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q6, k6, v6, kvlen)
+    with jax.named_scope("kernel_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=(B, S, r, nq, heads, nk),
+            in_specs=[spec_q, spec_k, spec_k, pl.BlockSpec(memory_space=pltpu.SMEM)],
+            out_specs=[spec_q, lse_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct(q6.shape, q6.dtype),
+                jax.ShapeDtypeStruct((B, S, r, M, LANES), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, head_dim), jnp.float32),
+            ],
+            interpret=interpret,
+            name="dilated_fwd",
+        )(q6, k6, v6, kvlen)
     return out, lse
 
 
@@ -735,23 +739,25 @@ def _bwd_impl_pipe(q6, k6, v6, do6, lse, delta, kvlen, scale,
     )
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel_pipe, scale=scale,
-            block_q=block_q, block_k=block_k, hb=hb, nk=nk,
-        ),
-        grid=(B, S, r, nq, total_q + 1),
-        in_specs=[spec_q, spec_k_prod, spec_k_prod, spec_k_cons, spec_q,
-                  vec_spec, vec_spec, smem],
-        out_specs=[spec_dq],
-        out_shape=[jax.ShapeDtypeStruct(q6.shape, q6.dtype)],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, head_dim), jnp.float32),
-            pltpu.VMEM((2, block_q, block_k), jnp.float32),
-            pltpu.VMEM((2, block_q, block_k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q6, k6p, v6p, k6p, do6, lse, delta, kvlen)[0]
+    with jax.named_scope("kernel_dq"):
+        dq = pl.pallas_call(
+            functools.partial(
+                _dq_kernel_pipe, scale=scale,
+                block_q=block_q, block_k=block_k, hb=hb, nk=nk,
+            ),
+            grid=(B, S, r, nq, total_q + 1),
+            in_specs=[spec_q, spec_k_prod, spec_k_prod, spec_k_cons, spec_q,
+                      vec_spec, vec_spec, smem],
+            out_specs=[spec_dq],
+            out_shape=[jax.ShapeDtypeStruct(q6.shape, q6.dtype)],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, head_dim), jnp.float32),
+                pltpu.VMEM((2, block_q, block_k), jnp.float32),
+                pltpu.VMEM((2, block_q, block_k), jnp.float32),
+            ],
+            interpret=interpret,
+            name="dilated_dq_pipe",
+        )(q6, k6p, v6p, k6p, do6, lse, delta, kvlen)[0]
 
     # ---- dK/dV: grid (B, S, r, nk, hb*nq + 1) ----
     total_kv = hb * nq
@@ -795,27 +801,29 @@ def _bwd_impl_pipe(q6, k6, v6, do6, lse, delta, kvlen, scale,
     vec_spec_c = pl.BlockSpec(
         (1, 1, 1, block_q, LANES), vec_c_map, memory_space=pltpu.VMEM,
     )
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel_pipe, scale=scale,
-            block_q=block_q, block_k=block_k, hb=hb, nq=nq,
-        ),
-        grid=(B, S, r, nk, total_kv + 1),
-        in_specs=[spec_q_prod, spec_k_kv, spec_k_kv, spec_q_cons, spec_q_cons,
-                  spec_q_prod, vec_spec_c, vec_spec_c, smem],
-        out_specs=[spec_dk, spec_dk],
-        out_shape=[
-            jax.ShapeDtypeStruct(k6p.shape, k6.dtype),
-            jax.ShapeDtypeStruct(v6p.shape, v6.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, head_dim), jnp.float32),
-            pltpu.VMEM((block_k, head_dim), jnp.float32),
-            pltpu.VMEM((2, block_q, block_k), jnp.float32),
-            pltpu.VMEM((2, block_q, block_k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q6, k6p, v6p, q6, do6, do6, lse, delta, kvlen)
+    with jax.named_scope("kernel_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(
+                _dkv_kernel_pipe, scale=scale,
+                block_q=block_q, block_k=block_k, hb=hb, nq=nq,
+            ),
+            grid=(B, S, r, nk, total_kv + 1),
+            in_specs=[spec_q_prod, spec_k_kv, spec_k_kv, spec_q_cons, spec_q_cons,
+                      spec_q_prod, vec_spec_c, vec_spec_c, smem],
+            out_specs=[spec_dk, spec_dk],
+            out_shape=[
+                jax.ShapeDtypeStruct(k6p.shape, k6.dtype),
+                jax.ShapeDtypeStruct(v6p.shape, v6.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, head_dim), jnp.float32),
+                pltpu.VMEM((block_k, head_dim), jnp.float32),
+                pltpu.VMEM((2, block_q, block_k), jnp.float32),
+                pltpu.VMEM((2, block_q, block_k), jnp.float32),
+            ],
+            interpret=interpret,
+            name="dilated_dkv_pipe",
+        )(q6, k6p, v6p, q6, do6, do6, lse, delta, kvlen)
     if Mkp != Mk:
         dk = dk[:, :, :, :, :Mk]
         dv = dv[:, :, :, :, :Mk]
@@ -1013,18 +1021,20 @@ def _bwd_impl(q6, k6, v6, do6, lse, delta, kvlen, causal, scale,
     )
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
-        ),
-        grid=(B, S, r, nq, heads, nk),
-        in_specs=[spec_q, spec_k, spec_k, spec_q, vec_spec, vec_spec, smem],
-        out_specs=[spec_q],
-        out_shape=[jax.ShapeDtypeStruct(q6.shape, q6.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
-        interpret=interpret,
-    )(q6, k6, v6, do6, lse, delta, kvlen)[0]
+    with jax.named_scope("kernel_dq"):
+        dq = pl.pallas_call(
+            functools.partial(
+                _dq_kernel, scale=scale, causal=causal,
+                block_q=block_q, block_k=block_k,
+            ),
+            grid=(B, S, r, nq, heads, nk),
+            in_specs=[spec_q, spec_k, spec_k, spec_q, vec_spec, vec_spec, smem],
+            out_specs=[spec_q],
+            out_shape=[jax.ShapeDtypeStruct(q6.shape, q6.dtype)],
+            scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
+            interpret=interpret,
+            name="dilated_dq",
+        )(q6, k6, v6, do6, lse, delta, kvlen)[0]
 
     # grid (B, S, r, nk, hb, nq): index maps see (b, s, p, j, t, i)
     spec_q_kv = pl.BlockSpec(
@@ -1041,25 +1051,27 @@ def _bwd_impl(q6, k6, v6, do6, lse, delta, kvlen, causal, scale,
         (1, 1, 1, block_q, LANES), lambda b, s, p, j, t, i: (b, s, p, i, 0),
         memory_space=pltpu.VMEM,
     )
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
-        ),
-        grid=(B, S, r, nk, heads, nq),
-        in_specs=[spec_q_kv, spec_k_kv, spec_k_kv, spec_q_kv,
-                  vec_spec_kv, vec_spec_kv, smem],
-        out_specs=[spec_k_kv, spec_k_kv],
-        out_shape=[
-            jax.ShapeDtypeStruct(k6.shape, k6.dtype),
-            jax.ShapeDtypeStruct(v6.shape, v6.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, head_dim), jnp.float32),
-            pltpu.VMEM((block_k, head_dim), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q6, k6, v6, do6, lse, delta, kvlen)
+    with jax.named_scope("kernel_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(
+                _dkv_kernel, scale=scale, causal=causal,
+                block_q=block_q, block_k=block_k,
+            ),
+            grid=(B, S, r, nk, heads, nq),
+            in_specs=[spec_q_kv, spec_k_kv, spec_k_kv, spec_q_kv,
+                      vec_spec_kv, vec_spec_kv, smem],
+            out_specs=[spec_k_kv, spec_k_kv],
+            out_shape=[
+                jax.ShapeDtypeStruct(k6.shape, k6.dtype),
+                jax.ShapeDtypeStruct(v6.shape, v6.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, head_dim), jnp.float32),
+                pltpu.VMEM((block_k, head_dim), jnp.float32),
+            ],
+            interpret=interpret,
+            name="dilated_dkv",
+        )(q6, k6, v6, do6, lse, delta, kvlen)
     return dq, dk, dv
 
 
@@ -1251,6 +1263,7 @@ def _pad_segments(x: jnp.ndarray, g: int, S: int, gp2: int) -> jnp.ndarray:
     return x
 
 
+@jax.named_scope("pack")
 def _pack_phases(x: jnp.ndarray, g: int, S: int, r: int, Mp: int, H: int,
                  interpret: bool, pack_direct: bool = False) -> jnp.ndarray:
     """[B, L, E] -> packed [B, S, r, hb, Mp, Dh] holding ONLY the diagonal
@@ -1280,6 +1293,7 @@ def _pack_phases(x: jnp.ndarray, g: int, S: int, r: int, Mp: int, H: int,
             ),
             out_shape=jax.ShapeDtypeStruct((B, 1, r, hb, Mp, Dh), x.dtype),
             interpret=interpret,
+            name="dilated_pack_direct",
         )(x)
     # [B, S, Mp, r*E]: rows are token groups of r, phases live on lanes
     xp = _pad_segments(x, g, S, Mp * r).reshape(B, S, Mp, r * E)
@@ -1299,9 +1313,11 @@ def _pack_phases(x: jnp.ndarray, g: int, S: int, r: int, Mp: int, H: int,
         ),
         out_shape=jax.ShapeDtypeStruct((B, S, r, hb, Mp, Dh), x.dtype),
         interpret=interpret,
+        name="dilated_pack",
     )(xp)
 
 
+@jax.named_scope("unpack")
 def _unpack_phases(p6: jnp.ndarray, L: int, E: int, g: int, S: int,
                    r: int, interpret: bool,
                    pack_direct: bool = False) -> jnp.ndarray:
@@ -1333,6 +1349,7 @@ def _unpack_phases(p6: jnp.ndarray, L: int, E: int, g: int, S: int,
             ),
             out_shape=jax.ShapeDtypeStruct((B, L, E), p6.dtype),
             interpret=interpret,
+            name="dilated_unpack_direct",
         )(p6)
     bt = _pack_bt(Mp, r, E, p6.dtype.itemsize)
     x = pl.pallas_call(
@@ -1350,6 +1367,7 @@ def _unpack_phases(p6: jnp.ndarray, L: int, E: int, g: int, S: int,
         ),
         out_shape=jax.ShapeDtypeStruct((B, S, Mp, r * E), p6.dtype),
         interpret=interpret,
+        name="dilated_unpack",
     )(p6)
     x = x.reshape(B, S, Mp * r, E)
     return x[:, :, :g].reshape(B, S * g, E)[:, :L]
@@ -1365,6 +1383,7 @@ def _phase_kvlen(S: int, g: int, r: int, m: int, real_len: int) -> np.ndarray:
     return np.clip(counts, 0, m).astype(np.int32)
 
 
+@jax.named_scope("unpack")
 def _scatter_lse(lse5: jnp.ndarray, B: int, L: int, H: int, g: int, S: int,
                  r: int, m: int) -> jnp.ndarray:
     """Kernel lse [B, S, r, Mp, LANES] -> dense [B, H, L] with NEG_INF at
@@ -1952,6 +1971,7 @@ def _epilogue_pass_call(operands, geoms, B, plan, BT, first, final,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=plan.interpret,
+        name="dilated_epilogue_fwd",
     )(*operands)
 
 
@@ -2020,6 +2040,7 @@ def _epilogue_bwd_call(dy, fused_lse, lse5, geom, bt, plan):
         out_specs=do_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, r, hb, Mp, Dh), dy.dtype),
         interpret=plan.interpret,
+        name="dilated_epilogue_bwd",
     )(dy, fused_lse, lse5)
 
 
@@ -2120,11 +2141,13 @@ def dilated_attention_stream_fused(
     assert plan is not None, "caller must gate on plan_stream_fusion"
     outs, lses = [], []
     for sl, r in zip(segment_lengths, dilated_ratios):
-        o6, l5 = dilated_branch_attention_packed(
-            q, k, v, int(sl), int(r), num_heads,
-            real_len=real_len, valid_len_dyn=valid_len_dyn,
-            is_causal=is_causal, interpret=interpret, flags=flags,
-        )
+        with jax.named_scope(f"branch_r{int(r)}"):
+            o6, l5 = dilated_branch_attention_packed(
+                q, k, v, int(sl), int(r), num_heads,
+                real_len=real_len, valid_len_dyn=valid_len_dyn,
+                is_causal=is_causal, interpret=interpret, flags=flags,
+            )
         outs.append(o6)
         lses.append(l5)
-    return _fusion_epilogue(tuple(outs), tuple(lses), plan)
+    with jax.named_scope("merge"):
+        return _fusion_epilogue(tuple(outs), tuple(lses), plan)

@@ -12,7 +12,7 @@ replacement for that CUDA dependency:
   over K blocks, loop Q) — recomputing probabilities from the saved LSE
   rather than storing the attention matrix.
 
-Performance notes (v5e measurements in scripts/profile_slide.py):
+Performance notes (v5e measurements of earlier rounds, PERFORMANCE.md):
 - kernels run on ``[B, H, L, D]`` layout with ``(1, 1, block_q, D)`` blocks —
   the only layout whose trailing block dims satisfy Mosaic's (8, 128)
   tiling rule for head counts > 1; the public API stays ``[B, L, H, D]``
@@ -364,25 +364,27 @@ def _fwd_impl(q, k, v, kv_lens, causal, scale, block_q, block_k, interpret):
     q_spec = pl.BlockSpec((1, 1, 1, block_q, D), lambda b, h, s, i, j: (b, h, s, i, 0), memory_space=pltpu.VMEM)
     k_spec = pl.BlockSpec((1, 1, 1, block_k, D), lambda b, h, s, i, j: (b, h, s, j, 0), memory_space=pltpu.VMEM)
     kvlen_spec = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole (B,H,S) array; indexed by program_id
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(B, H, S, nq, nk),
-        in_specs=[q_spec, k_spec, k_spec, kvlen_spec],
-        out_specs=[
-            q_spec,
-            pl.BlockSpec((1, 1, 1, block_q, LANES), lambda b, h, s, i, j: (b, h, s, i, 0), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, S, Mqp, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, S, Mqp, LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qp, kp, vp, kvlen)
+    with jax.named_scope("kernel_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=(B, H, S, nq, nk),
+            in_specs=[q_spec, k_spec, k_spec, kvlen_spec],
+            out_specs=[
+                q_spec,
+                pl.BlockSpec((1, 1, 1, block_q, LANES), lambda b, h, s, i, j: (b, h, s, i, 0), memory_space=pltpu.VMEM),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, H, S, Mqp, D), q.dtype),
+                jax.ShapeDtypeStruct((B, H, S, Mqp, LANES), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, D), jnp.float32),
+            ],
+            interpret=interpret,
+            name="flash_fwd",
+        )(qp, kp, vp, kvlen)
     return out[:, :, :, :Mq], lse[:, :, :, :Mq, 0]
 
 
@@ -412,41 +414,45 @@ def _bwd_impl(q, k, v, lse, delta, do, kv_lens, causal, scale, block_q, block_k,
     vec_spec = pl.BlockSpec((1, 1, 1, block_q, LANES), lambda b, h, s, i, j: (b, h, s, i, 0), memory_space=pltpu.VMEM)
     kvlen_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
 
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
-        ),
-        grid=(B, H, S, nq, nk),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, vec_spec, vec_spec, kvlen_spec],
-        out_specs=[q_spec],
-        out_shape=[jax.ShapeDtypeStruct((B, H, S, Mqp, D), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
-    )(qp, kp, vp, dop, lsep, deltap, kvlen)[0]
+    with jax.named_scope("kernel_dq"):
+        dq = pl.pallas_call(
+            functools.partial(
+                _dq_kernel, scale=scale, causal=causal,
+                block_q=block_q, block_k=block_k,
+            ),
+            grid=(B, H, S, nq, nk),
+            in_specs=[q_spec, k_spec, k_spec, q_spec, vec_spec, vec_spec, kvlen_spec],
+            out_specs=[q_spec],
+            out_shape=[jax.ShapeDtypeStruct((B, H, S, Mqp, D), q.dtype)],
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+            interpret=interpret,
+            name="flash_dq",
+        )(qp, kp, vp, dop, lsep, deltap, kvlen)[0]
 
     # grid (B, H, S, nk, nq): index maps see (b, h, s, j, i)
     q_spec_kv = pl.BlockSpec((1, 1, 1, block_q, D), lambda b, h, s, j, i: (b, h, s, i, 0), memory_space=pltpu.VMEM)
     k_spec_kv = pl.BlockSpec((1, 1, 1, block_k, D), lambda b, h, s, j, i: (b, h, s, j, 0), memory_space=pltpu.VMEM)
     vec_spec_kv = pl.BlockSpec((1, 1, 1, block_q, LANES), lambda b, h, s, j, i: (b, h, s, i, 0), memory_space=pltpu.VMEM)
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k,
-        ),
-        grid=(B, H, S, nk, nq),
-        in_specs=[q_spec_kv, k_spec_kv, k_spec_kv, q_spec_kv, vec_spec_kv, vec_spec_kv, kvlen_spec],
-        out_specs=[k_spec_kv, k_spec_kv],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, S, Mkp, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, S, Mkp, D), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qp, kp, vp, dop, lsep, deltap, kvlen)
+    with jax.named_scope("kernel_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(
+                _dkv_kernel, scale=scale, causal=causal,
+                block_q=block_q, block_k=block_k,
+            ),
+            grid=(B, H, S, nk, nq),
+            in_specs=[q_spec_kv, k_spec_kv, k_spec_kv, q_spec_kv, vec_spec_kv, vec_spec_kv, kvlen_spec],
+            out_specs=[k_spec_kv, k_spec_kv],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, H, S, Mkp, D), k.dtype),
+                jax.ShapeDtypeStruct((B, H, S, Mkp, D), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, D), jnp.float32),
+            ],
+            interpret=interpret,
+            name="flash_dkv",
+        )(qp, kp, vp, dop, lsep, deltap, kvlen)
     return (
         dq[:, :, :, :Mq],
         dk[:, :, :, :Mk],
@@ -491,22 +497,24 @@ def _flat_fwd_impl(q, k, v, g, real_len, causal, interpret):
     kernel = functools.partial(
         _fwd_kernel, scale=D ** -0.5, causal=causal, block_q=g, block_k=g
     )
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(B, H, S, 1, 1),
-        in_specs=[q_spec, q_spec, q_spec, pl.BlockSpec(memory_space=pltpu.SMEM)],
-        out_specs=[q_spec, lse_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, 1, L, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, 1, L, LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((g, LANES), jnp.float32),
-            pltpu.VMEM((g, LANES), jnp.float32),
-            pltpu.VMEM((g, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q5, k5, v5, kvlen)
+    with jax.named_scope("kernel_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=(B, H, S, 1, 1),
+            in_specs=[q_spec, q_spec, q_spec, pl.BlockSpec(memory_space=pltpu.SMEM)],
+            out_specs=[q_spec, lse_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, H, 1, L, D), q.dtype),
+                jax.ShapeDtypeStruct((B, H, 1, L, LANES), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((g, LANES), jnp.float32),
+                pltpu.VMEM((g, LANES), jnp.float32),
+                pltpu.VMEM((g, D), jnp.float32),
+            ],
+            interpret=interpret,
+            name="flash_fwd",
+        )(q5, k5, v5, kvlen)
     return out[:, :, 0], lse[:, :, 0, :, 0]
 
 
@@ -552,37 +560,41 @@ def _flat_bwd_impl(q, k, v, lse, delta, do, g, real_len, causal, interpret):
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     scale = D ** -0.5
 
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, scale=scale, causal=causal, block_q=g, block_k=g,
-            flat=True,
-        ),
-        grid=(B, H, S, 1, 1),
-        in_specs=[q_spec, q_spec, q_spec, q_spec, lse_spec, lse_spec, smem],
-        out_specs=[q_spec],
-        out_shape=[jax.ShapeDtypeStruct((B, H, 1, L, D), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((g, D), jnp.float32)],
-        interpret=interpret,
-    )(q5, k5, v5, do5, lseL, deltaL, kvlen)[0]
+    with jax.named_scope("kernel_dq"):
+        dq = pl.pallas_call(
+            functools.partial(
+                _dq_kernel, scale=scale, causal=causal, block_q=g, block_k=g,
+                flat=True,
+            ),
+            grid=(B, H, S, 1, 1),
+            in_specs=[q_spec, q_spec, q_spec, q_spec, lse_spec, lse_spec, smem],
+            out_specs=[q_spec],
+            out_shape=[jax.ShapeDtypeStruct((B, H, 1, L, D), q.dtype)],
+            scratch_shapes=[pltpu.VMEM((g, D), jnp.float32)],
+            interpret=interpret,
+            name="flash_dq",
+        )(q5, k5, v5, do5, lseL, deltaL, kvlen)[0]
 
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, scale=scale, causal=causal, block_q=g, block_k=g,
-            flat=True,
-        ),
-        grid=(B, H, S, 1, 1),
-        in_specs=[q_spec, q_spec, q_spec, q_spec, lse_spec, lse_spec, smem],
-        out_specs=[q_spec, q_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, 1, L, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, 1, L, D), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((g, D), jnp.float32),
-            pltpu.VMEM((g, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q5, k5, v5, do5, lseL, deltaL, kvlen)
+    with jax.named_scope("kernel_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(
+                _dkv_kernel, scale=scale, causal=causal, block_q=g, block_k=g,
+                flat=True,
+            ),
+            grid=(B, H, S, 1, 1),
+            in_specs=[q_spec, q_spec, q_spec, q_spec, lse_spec, lse_spec, smem],
+            out_specs=[q_spec, q_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, H, 1, L, D), k.dtype),
+                jax.ShapeDtypeStruct((B, H, 1, L, D), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((g, D), jnp.float32),
+                pltpu.VMEM((g, D), jnp.float32),
+            ],
+            interpret=interpret,
+            name="flash_dkv",
+        )(q5, k5, v5, do5, lseL, deltaL, kvlen)
     return dq[:, :, 0], dk[:, :, 0], dv[:, :, 0]
 
 

@@ -323,26 +323,28 @@ def _fwd_impl(info, q, k, v, segment_len, ratio, block_q, block_k,
     k_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h, j, 0),
                           memory_space=pltpu.VMEM)
     info_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(B, H, nq, nk),
-        in_specs=[info_spec, q_spec, k_spec, k_spec],
-        out_specs=[
-            q_spec,
-            pl.BlockSpec((1, 1, bq, LANES), lambda b, h, i, j: (b, h, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, cqp, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, cqp, LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(info, qp, kp, vp)
+    with jax.named_scope("kernel_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=(B, H, nq, nk),
+            in_specs=[info_spec, q_spec, k_spec, k_spec],
+            out_specs=[
+                q_spec,
+                pl.BlockSpec((1, 1, bq, LANES), lambda b, h, i, j: (b, h, i, 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, H, cqp, D), q.dtype),
+                jax.ShapeDtypeStruct((B, H, cqp, LANES), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, D), jnp.float32),
+            ],
+            interpret=interpret,
+            name="stream_fold",
+        )(info, qp, kp, vp)
     return out[:, :, :cq], lse[:, :, :cq, 0]
 
 
@@ -377,19 +379,21 @@ def _bwd_impl(info, q, k, v, lse, delta, do, segment_len, ratio,
                             memory_space=pltpu.VMEM)
     info_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
 
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, scale=scale, segment_len=segment_len, ratio=ratio,
-            hpg=hpg, block_q=bq, block_k=bk,
-        ),
-        grid=(B, H, nq, nk),
-        in_specs=[info_spec, q_spec, k_spec, k_spec, q_spec, vec_spec,
-                  vec_spec],
-        out_specs=[q_spec],
-        out_shape=[jax.ShapeDtypeStruct((B, H, cqp, D), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=interpret,
-    )(info, qp, kp, vp, dop, lsep, deltap)[0]
+    with jax.named_scope("kernel_dq"):
+        dq = pl.pallas_call(
+            functools.partial(
+                _dq_kernel, scale=scale, segment_len=segment_len, ratio=ratio,
+                hpg=hpg, block_q=bq, block_k=bk,
+            ),
+            grid=(B, H, nq, nk),
+            in_specs=[info_spec, q_spec, k_spec, k_spec, q_spec, vec_spec,
+                      vec_spec],
+            out_specs=[q_spec],
+            out_shape=[jax.ShapeDtypeStruct((B, H, cqp, D), q.dtype)],
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+            interpret=interpret,
+            name="stream_fold_dq",
+        )(info, qp, kp, vp, dop, lsep, deltap)[0]
 
     # grid (B, H, nk, nq): index maps see (b, h, j, i)
     q_spec_kv = pl.BlockSpec((1, 1, bq, D), lambda b, h, j, i: (b, h, i, 0),
@@ -400,25 +404,27 @@ def _bwd_impl(info, q, k, v, lse, delta, do, segment_len, ratio,
         (1, 1, bq, LANES), lambda b, h, j, i: (b, h, i, 0),
         memory_space=pltpu.VMEM,
     )
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, scale=scale, segment_len=segment_len, ratio=ratio,
-            hpg=hpg, block_q=bq, block_k=bk,
-        ),
-        grid=(B, H, nk, nq),
-        in_specs=[info_spec, q_spec_kv, k_spec_kv, k_spec_kv, q_spec_kv,
-                  vec_spec_kv, vec_spec_kv],
-        out_specs=[k_spec_kv, k_spec_kv],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, ckp, D), k.dtype),
-            jax.ShapeDtypeStruct((B, H, ckp, D), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(info, qp, kp, vp, dop, lsep, deltap)
+    with jax.named_scope("kernel_dkv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(
+                _dkv_kernel, scale=scale, segment_len=segment_len, ratio=ratio,
+                hpg=hpg, block_q=bq, block_k=bk,
+            ),
+            grid=(B, H, nk, nq),
+            in_specs=[info_spec, q_spec_kv, k_spec_kv, k_spec_kv, q_spec_kv,
+                      vec_spec_kv, vec_spec_kv],
+            out_specs=[k_spec_kv, k_spec_kv],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, H, ckp, D), k.dtype),
+                jax.ShapeDtypeStruct((B, H, ckp, D), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bk, D), jnp.float32),
+                pltpu.VMEM((bk, D), jnp.float32),
+            ],
+            interpret=interpret,
+            name="stream_fold_dkv",
+        )(info, qp, kp, vp, dop, lsep, deltap)
     return dq[:, :, :cq], dk[:, :, :ck], dv[:, :, :ck]
 
 

@@ -234,12 +234,13 @@ def fold_pair(
     resolved ``PipelineFlags`` carrier or None, static under jit —
     NamedTuples hash, so plan on-vs-off lands distinct jit cache
     entries) selects the pair tier; None is the plain jnp path."""
-    o, l = pair_partial_attention(
-        q_blk, k_blk, v_blk, q0, k0,
-        segment_len=segment_len, ratio=ratio, valid_len=valid_len,
-        flags=flags,
-    )
-    return combine_partials(acc_out, acc_lse, o, l)
+    with jax.named_scope("fold"):
+        o, l = pair_partial_attention(
+            q_blk, k_blk, v_blk, q0, k0,
+            segment_len=segment_len, ratio=ratio, valid_len=valid_len,
+            flags=flags,
+        )
+        return combine_partials(acc_out, acc_lse, o, l)
 
 
 def fuse_branch_partials(

@@ -126,21 +126,23 @@ def tile_encode_fn(tile_encoder):
     ride as an argument, never as 4 GB of inline constants)."""
 
     @jax.jit
-    def encode(params, imgs):
+    def tile_encode(params, imgs):
         return tile_encoder.apply({"params": params}, imgs)
 
-    return encode
+    return tile_encode
 
 
 def slide_forward_fn(slide_encoder_model):
     """The jitted all-layer slide forward ``(params, tile_embeds [B, N, D]
     bf16, coords [B, N, 2]) -> per-layer embeddings`` that
     :func:`run_inference_with_slide_encoder` runs."""
-    return jax.jit(
-        lambda p, x, c: slide_encoder_model.apply(
-            {"params": p}, x, c, all_layer_embed=True
+    @jax.jit
+    def slide_forward(params, tile_embeds, coords):
+        return slide_encoder_model.apply(
+            {"params": params}, tile_embeds, coords, all_layer_embed=True
         )
-    )
+
+    return slide_forward
 
 
 def run_inference_with_tile_encoder(

@@ -147,23 +147,25 @@ def q_flash_attention_pallas(
     lse_spec = pl.BlockSpec((1, 1, block_q, LANES),
                             lambda b, h, i, j: (b, h, i, 0),
                             memory_space=pltpu.VMEM)
-    out, lse = pl.pallas_call(
-        functools.partial(_qflash_kernel, block_q=block_q, block_k=block_k),
-        grid=(B, H, nq, nk),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  spec_q, spec_k, spec_k],
-        out_specs=[spec_q, lse_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, L, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, L, LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )(combined, qq.data, kq.data, vh)
+    with jax.named_scope("kernel_fwd"):
+        out, lse = pl.pallas_call(
+            functools.partial(_qflash_kernel, block_q=block_q, block_k=block_k),
+            grid=(B, H, nq, nk),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                      spec_q, spec_k, spec_k],
+            out_specs=[spec_q, lse_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, H, L, D), q.dtype),
+                jax.ShapeDtypeStruct((B, H, L, LANES), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, D), jnp.float32),
+            ],
+            interpret=interpret,
+            name="q_flash_fwd",
+        )(combined, qq.data, kq.data, vh)
     return out.transpose(0, 2, 1, 3), lse[..., 0]
 
 

@@ -125,6 +125,7 @@ def q_matmul_pallas(x: jnp.ndarray, qt: QTensor, *, block_m: int = 256,
         out_shape=jax.ShapeDtypeStruct((mp, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="q_matmul",
     )(x2, qt.data, scale)
     return out[:m].reshape(*lead, N)
 

@@ -65,34 +65,3 @@ def collect_moe_metadata(intermediates: Dict[str, Any]) -> Dict[str, float]:
         for key, leaf in iter_moe_metadata(intermediates)
     }
 
-
-def xla_op_totals(trace_dir: str) -> Dict[str, Dict[str, float]]:
-    """Aggregate a captured xplane trace into per-op total microseconds.
-
-    Returns ``{"ops": {...}, "async": {...}}`` — the 'XLA Ops' line (real
-    per-op device time for THIS process; contention-independent) and the
-    async line (overlap-capable DMA spans; double-counts overlap, use for
-    orientation only). One implementation shared by the profile scripts.
-    """
-    import glob
-    import os
-
-    from jax.profiler import ProfileData
-
-    traces = sorted(
-        glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
-    )
-    ops: Dict[str, float] = {}
-    asyncs: Dict[str, float] = {}
-    pd = ProfileData.from_file(traces[-1])
-    for plane in pd.planes:
-        if "TPU" not in plane.name:
-            continue
-        for line in plane.lines:
-            if line.name == "XLA Ops":
-                for ev in line.events:
-                    ops[ev.name] = ops.get(ev.name, 0.0) + ev.duration_ns / 1e3
-            elif "Async" in line.name:
-                for ev in line.events:
-                    asyncs[ev.name] = asyncs.get(ev.name, 0.0) + ev.duration_ns / 1e3
-    return {"ops": ops, "async": asyncs}

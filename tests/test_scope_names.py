@@ -1,0 +1,242 @@
+"""The names the program gives its own work on the device (PERF.md §3):
+``jax.named_scope`` in both encoders and the attention ops, ``name=`` on
+every ``pl.pallas_call``, and named jitted steps. Each name a path can reach
+has to stand in that path's lowered text, one case per name, so a refactor
+that drops one fails here; and the scopes add no equation to either forward.
+
+Lowering only: nothing here runs a kernel. The Pallas paths lower in
+interpret mode (the library never picks it: the tests ask for it)."""
+
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.pallas import tpu as pltpu
+
+from benchmarks.lib import tables
+
+_TILE = tables.load("configs", "gigapath_tile_enc")["tiny"]
+_SLIDE = tables.load("configs", "gigapath_slide_enc12l768d")["tiny"]
+_N_TOKENS = 40  # + class token = 41: three 16-token and two 32-token segments
+
+
+def _tile_forward():
+    from gigapath_tpu import pipeline
+    from gigapath_tpu.utils.registry import create_model_from_registry
+    import gigapath_tpu.models.tile_encoder  # noqa: F401  (registers the archs)
+
+    model = create_model_from_registry(_TILE["arch"], dtype=jnp.bfloat16)
+    s = _TILE["img_size"]
+    x = jax.ShapeDtypeStruct((2, s, s, 3), jnp.bfloat16)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), x)["params"]
+    return pipeline.tile_encode_fn(model), (params, x)
+
+
+def _slide_forward():
+    from gigapath_tpu import pipeline
+    from gigapath_tpu.utils.registry import create_model_from_registry
+    import gigapath_tpu.models.slide_encoder  # noqa: F401
+
+    model = create_model_from_registry(
+        _SLIDE["arch"], in_chans=_SLIDE["in_chans"], global_pool=False,
+        dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((2, _N_TOKENS, _SLIDE["in_chans"]), jnp.bfloat16)
+    c = jax.ShapeDtypeStruct((2, _N_TOKENS, 2), jnp.float32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), x, c)["params"]
+    return pipeline.slide_forward_fn(model), (params, x, c)
+
+
+def _on_kernels(monkeypatch_context, build):
+    """``build()`` with the device gate answering "TPU" and every
+    ``pallas_call`` in interpret mode: the slide encoder then takes the fused
+    phase-major path, as it does on the chip."""
+    import gigapath_tpu.ops.flash_attention as fa
+
+    monkeypatch_context.setattr(fa, "_on_tpu", lambda: True)
+    with pltpu.force_tpu_interpret_mode():
+        return build()
+
+
+def _qkv(L=64, H=4, Dh=8, B=1):
+    return [jax.ShapeDtypeStruct((B, L, H, Dh), jnp.float32)] * 3
+
+
+def _grad_of(op):
+    return jax.jit(jax.grad(lambda q, k, v: op(q, k, v).astype(jnp.float32).sum(),
+                            argnums=(0, 1, 2)))
+
+
+def _text(fn, args) -> str:
+    return fn.lower(*args).as_text(debug_info=True)
+
+
+@functools.lru_cache(maxsize=None)
+def _lowered(path: str) -> str:
+    """The lowered text of one path through the program, made once."""
+    from gigapath_tpu.ops import dilated_attention as da
+    from gigapath_tpu.ops import pallas_dilated as pd
+
+    with pytest.MonkeyPatch.context() as mp:
+        if path == "tile":
+            return _text(*_tile_forward())
+        if path == "slide_jnp":
+            return _text(*_slide_forward())
+        if path == "slide_kernels":
+            return _on_kernels(mp, lambda: _text(*_slide_forward()))
+        if path == "fused_grad":
+            return _text(_grad_of(functools.partial(
+                da.dilated_attention_fused, segment_lengths=[16, 32],
+                dilated_ratios=[1, 2], interpret=True)), _qkv())
+        if path == "fused_variants_grad":  # the kernel variants that are off by default
+            flags = pd.snapshot_flags()._replace(
+                pipelined_fwd=True, pipelined_bwd=True, pack_direct=True,
+                stream_fusion=True)
+            return _text(_grad_of(functools.partial(
+                da.dilated_attention_fused, segment_lengths=[32, 64],
+                dilated_ratios=[1, 2], interpret=True, flags=flags)), _qkv())
+        if path == "fused_streaming":
+            return _text(jax.jit(functools.partial(
+                da.dilated_attention_fused, segment_lengths=[16, 32],
+                dilated_ratios=[1, 2], interpret=True, streaming_fusion=True)), _qkv())
+        if path == "head_major_grad":
+            return _text(_grad_of(functools.partial(
+                da.dilated_attention_bhld, segment_lengths=[16, 32],
+                dilated_ratios=[1, 2], interpret=True, use_pallas=True)), _qkv())
+        if path == "head_major_streaming":
+            return _text(jax.jit(functools.partial(
+                da.dilated_attention_bhld, segment_lengths=[16, 32],
+                dilated_ratios=[1, 2], use_pallas=False, streaming_fusion=True)), _qkv())
+        if path == "flash_grad":
+            from gigapath_tpu.ops.pallas_flash import pallas_flash_attention
+
+            return _text(_grad_of(lambda q, k, v: pallas_flash_attention(
+                q, k, v, interpret=True)[0]), _qkv(L=128))
+        if path == "stream_fold_grad":
+            from gigapath_tpu.ops.pallas_dilated import snapshot_flags
+            from gigapath_tpu.ops.streaming_prefill import fold_pair
+
+            flags = snapshot_flags()._replace(fold_pallas=True)
+            acc = jax.ShapeDtypeStruct((1, 64, 4, 8), jnp.float32)
+            lse = jax.ShapeDtypeStruct((1, 4, 64), jnp.float32)
+
+            def loss(q, k, v, acc, lse):
+                out, _ = fold_pair(acc, lse, q, k, v, 0, 0, None,
+                                   segment_len=32, ratio=2, flags=flags)
+                return out.sum()
+
+            with pltpu.force_tpu_interpret_mode():  # the fold takes no interpret=
+                return _text(jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+                             _qkv() + [acc, lse])
+        if path == "quant":
+            from gigapath_tpu.quant.qflash import q_flash_attention_pallas
+            from gigapath_tpu.quant.qmatmul import q_matmul_pallas
+            from gigapath_tpu.quant.qtensor import quantize_per_channel
+
+            def both(q, k, v, x, w):
+                out, _ = q_flash_attention_pallas(q, k, v, interpret=True)
+                return out, q_matmul_pallas(x, quantize_per_channel(w), interpret=True)
+
+            x = jax.ShapeDtypeStruct((128, 128), jnp.float32)
+            return _text(jax.jit(both), _qkv(L=128) + [x, x])
+    raise KeyError(path)
+
+
+# path -> every name of PERF.md §3 that the path reaches
+_NAMES = {
+    "tile": ["jit_tile_encode", "attn_core"],
+    "slide_jnp": ["jit_slide_forward", "dilated_attn", "branch_r1", "branch_r2", "pack",
+                  "kernel_fwd", "unpack", "merge"],
+    "slide_kernels": ["jit_slide_forward", "dilated_attn", "branch_r1", "branch_r2", "pack",
+                      "kernel_fwd", "unpack", "merge", "dilated_pack", "dilated_fwd",
+                      "dilated_unpack"],
+    "fused_grad": ["dilated_attn", "branch_r2", "pack", "kernel_fwd", "kernel_dq",
+                   "kernel_dkv", "unpack", "merge", "dilated_pack", "dilated_fwd",
+                   "dilated_dq", "dilated_dkv", "dilated_unpack"],
+    "fused_variants_grad": ["dilated_attn", "branch_r2", "merge", "dilated_fwd_pipe",
+                            "dilated_dq_pipe", "dilated_dkv_pipe", "dilated_pack_direct",
+                            "dilated_unpack_direct", "dilated_epilogue_fwd",
+                            "dilated_epilogue_bwd"],
+    "fused_streaming": ["dilated_attn", "branch_r1", "branch_r2", "merge"],
+    "head_major_grad": ["dilated_attn", "branch_r1", "branch_r2", "dilate", "kernel_fwd",
+                        "kernel_dq", "kernel_dkv", "undilate", "merge", "flash_fwd",
+                        "flash_dq", "flash_dkv"],
+    "head_major_streaming": ["dilated_attn", "branch_r2", "dilate", "kernel_fwd", "undilate",
+                             "merge"],
+    "flash_grad": ["kernel_fwd", "kernel_dq", "kernel_dkv", "flash_fwd", "flash_dq",
+                   "flash_dkv"],
+    "stream_fold_grad": ["fold", "kernel_fwd", "kernel_dq", "kernel_dkv", "stream_fold",
+                         "stream_fold_dq", "stream_fold_dkv"],
+    "quant": ["kernel_fwd", "q_flash_fwd", "q_matmul"],
+}
+
+
+@pytest.mark.parametrize("path,name", [(p, n) for p, names in _NAMES.items() for n in names])
+def test_name_stands_in_the_lowered_text(path, name):
+    text = _lowered(path)
+    if name.startswith("jit_"):  # the module on the trace's "XLA Modules" line
+        assert re.search(rf"module @{name}\b", text)
+        assert "jit__lambda" not in text
+    else:  # a component of some operation's op_name path
+        assert re.search(rf'"[^"]*[/(]{name}[/)][^"]*"', text), name
+
+
+def test_a_branch_holds_its_steps_in_order_of_the_path():
+    """``.../dilated_attn/branch_r2/pack|kernel_fwd|unpack/<kernel name>/...``:
+    the scope-keyed reduction (benchmarks/lib/scopes.py) matches on this."""
+    text = _lowered("slide_kernels")
+    for step, kernel in (("pack", "dilated_pack"), ("kernel_fwd", "dilated_fwd"),
+                         ("unpack", "dilated_unpack")):
+        assert re.search(
+            rf'"[^"]*/layers_1/self_attn/self_attn\._attend/dilated_attn/branch_r2/{step}/{kernel}/',
+            text)
+    assert re.search(r'"[^"]*/self_attn\._attend/dilated_attn/merge/', text)
+    assert re.search(r'"[^"]*/blocks_1/attn/attn_core/', _lowered("tile"))
+
+
+# Equation counts of the parent commit (7f80832), every nested jaxpr counted,
+# written down once from that tree with ``_count``: scopes are metadata and
+# add none, and neither a ``jit`` nor a ``custom_vjp_call``.
+_PARENT_EQUATIONS = {
+    "tile": {"all": 245, "jit": 3, "custom_vjp_call": 0},
+    "slide_jnp": {"all": 761, "jit": 17, "custom_vjp_call": 0},
+    "slide_kernels": {"all": 1661, "jit": 45, "custom_vjp_call": 4},
+}
+
+
+def _count(jaxpr) -> dict:
+    counts = {"all": 0, "jit": 0, "custom_vjp_call": 0}
+
+    def walk(j):
+        for eqn in j.eqns:
+            counts["all"] += 1
+            if eqn.primitive.name in counts:
+                counts[eqn.primitive.name] += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return counts
+
+
+def _equations(path: str) -> dict:
+    build = _tile_forward if path == "tile" else _slide_forward
+
+    def make():
+        fn, args = build()
+        return _count(jax.make_jaxpr(fn)(*args))
+
+    if path != "slide_kernels":
+        return make()
+    with pytest.MonkeyPatch.context() as mp:
+        return _on_kernels(mp, make)
+
+
+@pytest.mark.parametrize("path", sorted(_PARENT_EQUATIONS))
+def test_scopes_add_no_equation(path):
+    assert _equations(path) == _PARENT_EQUATIONS[path]
+
+
+if __name__ == "__main__":  # prints the counts of whatever tree is on sys.path
+    print({path: _equations(path) for path in sorted(_PARENT_EQUATIONS)})
