@@ -336,6 +336,8 @@ def phase_b(args, sizes: Sizes, out_dir: str) -> dict:
     encode = pipeline.tile_encode_fn(tile_model)
     imgs_aval = jax.ShapeDtypeStruct((B, S, S, 3), jnp.bfloat16)
     tile_calls = custom_calls(encode.lower(tile_params, imgs_aval).compile())
+    if not sizes.tiny:  # the rehearsal's heads of 8 ride the jnp tier
+        require(tile_calls > 0, "tile encoder compiled without any tpu_custom_call")
     tile_embeds = []
     for _ in range(sizes.tile_batches):
         imgs = jnp.asarray(rng.normal(size=(B, S, S, 3)), jnp.bfloat16)

@@ -16,8 +16,12 @@ embed (1,181,184), cls (1,536), pos (302,592), final norm (3,072) =
 (a standard GELU MLP would give 1.39 B). Verified in
 ``tests/test_tile_encoder.py``.
 
-TPU-first notes: attention rides the shared fused ``attention_with_lse``
-(fp32 softmax statistics, bf16-safe); there is no interpolate-at-forward —
+TPU-first notes: on a TPU, where the heads tile the 128-lane groups and the
+whole sequence fits VMEM (ViT-G/14: 24 heads of 64 over 197 tokens), the
+attention core is one Pallas kernel over the packed qkv output
+(``ops/pallas_vit_attention.py``); everywhere else (the CPU, heads of 8, a
+sequence too long) it rides the shared ``attention_with_lse`` — fp32 softmax
+statistics, bf16-safe, in both; there is no interpolate-at-forward —
 positional embeddings are resized once at conversion time so every shape
 under ``jit`` is static; ``param_dtype`` lets the 1.13 B params live in bf16
 end-to-end (no fp16 GradScaler needed on TPU).
@@ -36,7 +40,7 @@ import numpy as np
 from flax import linen as nn
 
 from gigapath_tpu.obs import console
-from gigapath_tpu.ops.attention import attention_with_lse
+from gigapath_tpu.ops import flash_attention, pallas_vit_attention
 from gigapath_tpu.ops.droppath import DropPath
 from gigapath_tpu.utils.registry import create_model_from_registry, register_model
 from gigapath_tpu.utils.torch_convert import (
@@ -115,6 +119,13 @@ def _dense(features: int, *, quant: str, quant_pallas: bool, dtype,
 class ViTAttention(nn.Module):
     """Packed-qkv multi-head self-attention (timm ``Attention``).
 
+    Between ``qkv`` and ``proj`` (the ``attn_core`` scope) one of two forms
+    runs, chosen from what the code can observe and by no flag: on a TPU
+    (``ops.flash_attention._on_tpu``) with shapes the kernel takes
+    (``pallas_vit_attention.fits``), ``packed_qkv_attention`` reads the
+    ``[B, N, 3*D]`` array as the GEMM wrote it and writes ``[B, N, D]``;
+    otherwise its jnp form, the split into q, k, v and ``attention_with_lse``.
+
     ``quant`` routes the qkv/proj matmuls through the quantized tier
     (gigapath_tpu/quant/); the ``+attn`` rider additionally computes
     the attention logits from dynamically-quantized int8 Q/K
@@ -137,17 +148,21 @@ class ViTAttention(nn.Module):
             dtype=self.dtype, param_dtype=self.param_dtype, name="qkv"
         )(x)
         with jax.named_scope("attn_core"):
-            qkv = qkv.reshape(B, N, 3, H, hd)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             if self.quant and self.quant.endswith("+attn"):
                 from gigapath_tpu.quant.qflash import q_flash_attention
 
+                qkv = qkv.reshape(B, N, 3, H, hd)
                 out, _ = q_flash_attention(
-                    q, k, v, use_pallas=self.quant_pallas
+                    qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
+                    use_pallas=self.quant_pallas,
                 )
+                out = out.reshape(B, N, D)
+            elif flash_attention._on_tpu() and pallas_vit_attention.fits(
+                qkv.shape, H, qkv.dtype
+            ):
+                out = pallas_vit_attention.packed_qkv_attention(qkv, H)
             else:
-                out, _ = attention_with_lse(q, k, v)
-            out = out.reshape(B, N, D)
+                out = pallas_vit_attention.packed_qkv_attention_jnp(qkv, H)
         return _dense(
             D, quant=self.quant, quant_pallas=self.quant_pallas,
             dtype=self.dtype, param_dtype=self.param_dtype, name="proj"

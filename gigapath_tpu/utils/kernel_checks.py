@@ -4,7 +4,8 @@ The one copy of the on-chip kernel gate, shared by ``chip_smoke.py``
 (phase A) and ``scripts/tpu_selfcheck.py``: every Pallas attention path the
 flagship slide encoder dispatches to — flash, head-major (bhld), phase-major
 (fused), both backward families, every block triple the adaptive dispatcher
-picks at the bench geometry, the streaming ``pair_partial`` fold — compared
+picks at the bench geometry, the streaming ``pair_partial`` fold — and the
+ViT tile encoder's attention core over packed qkv, compared
 with the jnp tier on float32 inputs under
 ``jax.default_matmul_precision("highest")``.
 
@@ -36,6 +37,7 @@ class Geometry(NamedTuple):
     grad_len: int
     bench_len: int        # block-coverage length (bench N + cls token)
     fold_chunk: int       # streaming pair_partial chunk
+    vit_attn: Sequence[int]        # (B, N, heads, head_dim) of the ViT's packed-qkv core
 
 
 def flagship(bench_tokens: int = 10240) -> Geometry:
@@ -51,6 +53,7 @@ def flagship(bench_tokens: int = 10240) -> Geometry:
         seq_len=2048,
         grad_segments=[256, 512], grad_ratios=[1, 2], grad_len=1024,
         bench_len=bench_tokens + 1, fold_chunk=2048,
+        vit_attn=(2, 197, 24, 64),  # ViT-G/14
     )
 
 
@@ -58,7 +61,7 @@ def flagship(bench_tokens: int = 10240) -> Geometry:
 TINY = Geometry(
     heads=4, head_dim=8, segment_lengths=[32, 64], dilated_ratios=[1, 2],
     seq_len=128, grad_segments=[32, 64], grad_ratios=[1, 2], grad_len=64,
-    bench_len=65, fold_chunk=32,
+    bench_len=65, fold_chunk=32, vit_attn=(2, 19, 2, 64),
 )
 
 
@@ -258,6 +261,16 @@ def run_kernel_checks(
             np.where(covered, np.asarray(l_p), 0.0),
             np.where(covered, np.asarray(l_j), 0.0), 3e-2,
         )
+
+    # --- the ViT tile encoder's attention core over packed qkv ----------
+    from gigapath_tpu.ops import pallas_vit_attention as pva
+
+    Bv, Nv, Hv, Dv = geom.vit_attn
+    packed = jnp.asarray(rng.normal(size=(Bv, Nv, 3 * Hv * Dv)), jnp.bfloat16)
+    with highest:
+        ref = pva.packed_qkv_attention_jnp(packed.astype(jnp.float32), Hv)
+    check(f"vit packed-qkv attention fwd (N={Nv}, {Hv}x{Dv})",
+          pva.packed_qkv_attention(packed, Hv), ref, 3e-2)
 
     if flagged_variants:
         _flagged_variant_checks(

@@ -34,6 +34,19 @@ def _tile_forward():
     return pipeline.tile_encode_fn(model), (params, x)
 
 
+def _tile_forward_heads_of_64():
+    """A two-block ViT whose heads fill half a lane group, as ViT-G/14's do:
+    with the device gate on, its attention core is the packed-QKV kernel."""
+    from gigapath_tpu import pipeline
+    from gigapath_tpu.models.tile_encoder import VisionTransformer
+
+    model = VisionTransformer(img_size=32, patch_size=16, embed_dim=128, depth=2,
+                              num_heads=2, mlp_ratio=2.0, dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.bfloat16)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), x)["params"]
+    return pipeline.tile_encode_fn(model), (params, x)
+
+
 def _slide_forward():
     from gigapath_tpu import pipeline
     from gigapath_tpu.utils.registry import create_model_from_registry
@@ -81,6 +94,8 @@ def _lowered(path: str) -> str:
     with pytest.MonkeyPatch.context() as mp:
         if path == "tile":
             return _text(*_tile_forward())
+        if path == "tile_kernels":
+            return _on_kernels(mp, lambda: _text(*_tile_forward_heads_of_64()))
         if path == "slide_jnp":
             return _text(*_slide_forward())
         if path == "slide_kernels":
@@ -146,6 +161,7 @@ def _lowered(path: str) -> str:
 # path -> every name of PERF.md §3 that the path reaches
 _NAMES = {
     "tile": ["jit_tile_encode", "attn_core"],
+    "tile_kernels": ["jit_tile_encode", "attn_core", "kernel_fwd", "vit_attn_fwd"],
     "slide_jnp": ["jit_slide_forward", "dilated_attn", "branch_r1", "branch_r2", "pack",
                   "kernel_fwd", "unpack", "merge"],
     "slide_kernels": ["jit_slide_forward", "dilated_attn", "branch_r1", "branch_r2", "pack",
@@ -179,7 +195,10 @@ def test_name_stands_in_the_lowered_text(path, name):
         assert re.search(rf"module @{name}\b", text)
         assert "jit__lambda" not in text
     else:  # a component of some operation's op_name path
-        assert re.search(rf'"[^"]*[/(]{name}[/)][^"]*"', text), name
+        # (the path starts anew inside a function jitted on its own, as the
+        # ViT's kernel is; the compiled program joins it to the caller's:
+        # tests/test_tpu_compile.py holds that)
+        assert re.search(rf'"(?:[^"]*[/(])?{name}[/)][^"]*"', text), name
 
 
 def test_a_branch_holds_its_steps_in_order_of_the_path():
@@ -193,6 +212,9 @@ def test_a_branch_holds_its_steps_in_order_of_the_path():
             text)
     assert re.search(r'"[^"]*/self_attn\._attend/dilated_attn/merge/', text)
     assert re.search(r'"[^"]*/blocks_1/attn/attn_core/', _lowered("tile"))
+    kernels = _lowered("tile_kernels")
+    assert re.search(r'"[^"]*/blocks_1/attn/attn_core/jit\(packed_qkv_attention\)"', kernels)
+    assert re.search(r'"kernel_fwd/vit_attn_fwd/', kernels)
 
 
 # Equation counts of the parent commit (7f80832), every nested jaxpr counted,

@@ -8,8 +8,8 @@ m=1281 / 1408-block backward), a program that does not fit the device. A
 compile that passes is not a chip run: it says nothing about results or time.
 
 Kernels only, at flagship widths (H=16, Dh=48, the flagship schedule) and the
-shapes ``chip_smoke.py`` executes; the 100-second whole-model compiles stay
-out of the suite.
+shapes ``chip_smoke.py`` executes, and one ViT-G/14 block around its attention
+kernel; the 100-second whole-model compiles stay out of the suite.
 
 Rules this file keeps (the on-chip-measurement guide, section 2): the
 topology is described inside a module-scoped, non-autouse fixture that skips
@@ -21,6 +21,7 @@ cached entry for a described chip cannot be read back without one).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -210,6 +211,30 @@ def test_quant_kernels_at_vit_g_widths(topo, one_chip):
     )
     x = jax.ShapeDtypeStruct((8, 256, 24, 64), jnp.bfloat16)
     _compile(lambda q, k, v: q_flash_attention_pallas(q, k, v), one_chip, x, x, x)
+
+
+def test_vit_block_at_full_width_holds_one_kernel(topo, one_chip, monkeypatch):
+    """One ViT-G/14 block at the cell's batch (B=128, N=197, D=1536, 24 heads
+    of 64) with the device gate answering "TPU": the attention core is the
+    one custom call, reading the qkv GEMM's output and feeding ``proj``."""
+    import gigapath_tpu.ops.flash_attention as fa
+    from gigapath_tpu.models.tile_encoder import ViTBlock
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    block = ViTBlock(dim=1536, num_heads=24, mlp_hidden_dim=8192, dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((128, 197, 1536), jnp.bfloat16)
+    params = jax.eval_shape(block.init, jax.random.PRNGKey(0), x)
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), (params, x)
+    )
+    from gigapath_tpu.obs.ledger import custom_calls_of
+
+    compiled = jax.jit(block.apply).lower(*avals).compile()
+    assert custom_calls_of(compiled) == 1
+    # the name the trace's reduction finds it by (benchmarks/scopes/tile.json)
+    assert re.search(
+        r'op_name="[^"]*/attn/attn_core/jit\(packed_qkv_attention\)/kernel_fwd/vit_attn_fwd/',
+        compiled.as_text())
 
 
 def test_fused_local_branches_inside_shard_map_on_four_chips(topo, schedule):
