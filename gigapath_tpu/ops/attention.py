@@ -51,11 +51,16 @@ def attention_with_lse(
       (keys at index >= count are masked) — same contract as the Pallas
       kernel's ragged masking.
     - ``is_causal``: lower-triangular mask (query i attends keys <= i).
+    - grouped KV heads: ``k`` / ``v`` may carry ``H / g`` heads; query head
+      ``h`` then attends KV head ``h // g``.
     """
     B, Lq, H, D = q.shape
     Lk = k.shape[1]
     if scale is None:
         scale = D**-0.5
+    if k.shape[2] != H:
+        k = jnp.repeat(k, H // k.shape[2], axis=2)
+        v = jnp.repeat(v, H // v.shape[2], axis=2)
 
     logits = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32

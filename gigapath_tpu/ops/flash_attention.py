@@ -38,8 +38,13 @@ def flash_attention(
     bias: Optional[jnp.ndarray] = None,
     kv_valid_len=None,
     use_pallas: Optional[bool] = None,
+    scale: Optional[float] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Attention on [B, L, H, D] returning ``(out [B,L,H,D], lse [B,H,L])``.
+
+    ``k`` / ``v`` may carry fewer heads than ``q`` (grouped KV heads: ``H_kv``
+    divides ``H``, query head ``h`` reads KV head ``h // (H / H_kv)``).
+    ``scale`` multiplies the logits; ``None`` is ``D ** -0.5``.
 
     ``kv_valid_len``: [B, H] valid-key counts (ragged tail masking). Static
     (numpy/tuple) counts ride both backends; *traced* counts (dynamic
@@ -71,10 +76,13 @@ def flash_attention(
     if use_pallas:
         from gigapath_tpu.ops.pallas_flash import pallas_flash_attention
 
-        return pallas_flash_attention(q, k, v, is_causal=is_causal, kv_len=kv_valid_len)
+        return pallas_flash_attention(
+            q, k, v, is_causal=is_causal, kv_len=kv_valid_len, scale=scale
+        )
     with jax.named_scope("kernel_fwd"):
         return attention_with_lse(
-            q, k, v, is_causal=is_causal, bias=bias, kv_valid_len=kv_valid_len
+            q, k, v, is_causal=is_causal, bias=bias, kv_valid_len=kv_valid_len,
+            scale=scale,
         )
 
 

@@ -3,5 +3,6 @@ from gigapath_tpu.ops.moe.routing import (  # noqa: F401
     Top2Gate,
     top1_gating,
     top2_gating,
+    topk_softmax_gating,
 )
-from gigapath_tpu.ops.moe.moe_layer import MOELayer  # noqa: F401
+from gigapath_tpu.ops.moe.moe_layer import DroplessMoE, MOELayer  # noqa: F401

@@ -17,6 +17,13 @@ along capacity, exactly the ``ecm -> gecm`` reshape dance of
 Prefer the GSPMD path in :class:`~gigapath_tpu.ops.moe.moe_layer.MOELayer`
 (annotation-only) for training; this module is the manual-control variant
 and doubles as the executable spec of the collective pattern.
+
+The dropless layer's share of that pattern is here too: a chip is told which
+experts it holds (``expert_offset``, ``experts_held``), sorts the
+(token, expert) choices that name one of them to the front, expert by expert
+(:func:`dispatch_to_held`), and runs one grouped matrix product per projection
+over those rows (:func:`grouped_matmul`). What the other experts would add is
+another chip's part; nothing here stands in for it.
 """
 
 from __future__ import annotations
@@ -27,6 +34,62 @@ import jax
 import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
+
+from gigapath_tpu.ops.common import round_up
+
+# Rows of a grouped product's tile on the chip: a tile that straddles two
+# experts is visited once for each, so smaller wastes less and re-reads the
+# weights more often (36 experts of ~2,300 rows each: 160 tiles, ~36 twice).
+GMM_TILE_ROWS = 512
+
+
+def dispatch_to_held(experts: jnp.ndarray, *, expert_offset: int, experts_held: int):
+    """Sort the ``[S, k]`` choices of a top-k router by held expert.
+
+    Returns ``(order, position, group_sizes)``: ``order [S * k]`` lists the
+    flat choices (token ``i // k``) with those of held expert 0 first, then 1,
+    ..., and every choice of an expert that lives elsewhere last;
+    ``position [S, k]`` is where each choice stands in that order;
+    ``group_sizes [experts_held]`` int32 counts the rows of each held expert,
+    so rows from ``group_sizes.sum()`` on belong to no expert here."""
+    local = experts.reshape(-1) - expert_offset
+    key = jnp.where((local >= 0) & (local < experts_held), local, experts_held)
+    order = jnp.argsort(key, stable=True)
+    position = jnp.argsort(order).reshape(experts.shape)
+    group_sizes = (key[:, None] == jnp.arange(experts_held)).sum(0, dtype=jnp.int32)
+    return order, position, group_sizes
+
+
+def _gmm_tiling(k: int, n: int):
+    """(rows, contraction, columns) of a grouped product's tile: whole-width
+    divisors, the weight tile under 2 MB so that two of each operand, the
+    output and the float32 accumulator stay inside the 16 MB of fast memory."""
+    tn = next((t for t in (1536, 1024, 768, 512, 256, 128) if n % t == 0), None)
+    tk = next((t for t in (1024, 768, 512, 256, 128)
+               if k % t == 0 and tn and t * tn <= 1 << 20), None)
+    if not (tk and tn):
+        raise ValueError(f"grouped product [{k}, {n}]: both widths must be multiples of 128")
+    return GMM_TILE_ROWS, tk, tn
+
+
+def grouped_matmul(rows: jnp.ndarray, weights: jnp.ndarray, group_sizes: jnp.ndarray):
+    """``rows [M, K]`` sorted by group, ``weights [G, K, N]``, ``group_sizes
+    [G]``: each group's rows times its own matrix, ``[M, N]``. Rows past the
+    last group are not visited and hold whatever was there (the Pallas
+    ``megablox.gmm`` on a TPU) or zeros (``lax.ragged_dot`` elsewhere): the
+    caller masks them."""
+    from gigapath_tpu.ops.flash_attention import _on_tpu
+
+    with jax.named_scope("kernel_fwd"):
+        if not _on_tpu():
+            return jax.lax.ragged_dot(rows, weights, group_sizes)
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        M = rows.shape[0]
+        padded = jnp.pad(rows, ((0, round_up(M, GMM_TILE_ROWS) - M), (0, 0)))
+        out = gmm(padded, weights, group_sizes, preferred_element_type=rows.dtype,
+                  tiling=_gmm_tiling(rows.shape[1], weights.shape[2]))
+        return out[:M]
 
 
 def moe_shard_fn(

@@ -30,7 +30,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from gigapath_tpu.ops.feedforward import FeedForwardNetwork
-from gigapath_tpu.ops.moe.routing import Top1Gate, Top2Gate
+from gigapath_tpu.ops.moe.expert_parallel import dispatch_to_held, grouped_matmul
+from gigapath_tpu.ops.moe.routing import Top1Gate, Top2Gate, topk_softmax_gating
 
 
 def _maybe_expert_constraint(x: jnp.ndarray, axis: str = "expert") -> jnp.ndarray:
@@ -204,3 +205,61 @@ class MOELayer(nn.Module):
             "sec,ecm->sm", combine.astype(tokens.dtype), expert_output
         )
         return combined.reshape(B, L, M), l_aux.astype(jnp.float32)
+
+
+class DroplessMoE(nn.Module):
+    """Dropless top-k expert layer over ``[S, M]`` tokens, for the experts
+    held here: ``g = u W_r`` in float32 over all ``num_experts``; the ``top_k``
+    largest; ``w = softmax`` over those values; ``sum_i w_i W2_e(silu(a) * b)``
+    with ``[a | b] = W1_e u``, summed over the choices whose expert is one of
+    ``[expert_offset, expert_offset + experts_held)``. The other choices are
+    another chip's part of the sum and are left out.
+
+    Shapes are static, so the sorted rows are sized for every choice landing
+    here (``S * top_k``); the grouped products skip the rows past the last
+    held expert's. Returns ``(output [S, M], tokens each held expert received
+    [experts_held] int32)``; the choices and counts are sowed as
+    ``moe_metadata`` as :class:`MOELayer` sows its gating telemetry."""
+
+    embed_dim: int
+    ffn_dim: int
+    num_experts: int
+    top_k: int
+    expert_offset: int = 0
+    experts_held: Optional[int] = None
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        S, M = x.shape
+        held = self.num_experts - self.expert_offset if self.experts_held is None \
+            else self.experts_held
+        logits = nn.Dense(
+            self.num_experts, use_bias=False, dtype=jnp.float32,
+            param_dtype=self.param_dtype, precision=jax.lax.Precision.HIGHEST,
+            name="router",
+        )(x)
+        init = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=-2,
+                                                out_axis=-1, batch_axis=(0,))
+        w1 = self.param("w1", init, (held, M, 2 * self.ffn_dim), self.param_dtype)
+        w2 = self.param("w2", init, (held, self.ffn_dim, M), self.param_dtype)
+
+        with jax.named_scope("router"):
+            weights, experts = topk_softmax_gating(logits, self.top_k)
+        with jax.named_scope("dispatch"):
+            order, position, group_sizes = dispatch_to_held(
+                experts, expert_offset=self.expert_offset, experts_held=held)
+            rows = x.astype(self.dtype)[order // self.top_k]
+        self.sow("intermediates", "moe_metadata",
+                 {"experts": experts, "held_counts": group_sizes})
+        with jax.named_scope("experts"):
+            a, b = jnp.split(grouped_matmul(rows, w1, group_sizes), 2, axis=-1)
+            out_rows = grouped_matmul(jax.nn.silu(a) * b, w2, group_sizes)
+        with jax.named_scope("combine"):
+            # a select, not a product with a zero weight: rows past the last
+            # held expert's were never written by the grouped product
+            here = (position < group_sizes.sum())[..., None]
+            picked = jnp.where(here, out_rows[position], 0).astype(jnp.float32)
+            out = (picked * weights[..., None]).sum(axis=1).astype(self.dtype)
+        return out, group_sizes

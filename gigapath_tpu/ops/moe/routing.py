@@ -312,3 +312,12 @@ class Top2Gate(_GateBase):
             eval_capacity_token_fraction=self.eval_capacity_token_fraction,
             batch_prioritized_routing=self.batch_prioritized_routing,
         )
+
+
+def topk_softmax_gating(logits: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Dropless top-k routing: the ``k`` largest of each token's router
+    logits ``[S, E]``, then a softmax over those ``k`` values alone. No
+    capacity, no dropped token, no ``[S, E, C]`` tensor. Returns ``(weights
+    [S, k] float32, experts [S, k] int32)``."""
+    values, experts = jax.lax.top_k(logits.astype(jnp.float32), k)
+    return jax.nn.softmax(values, axis=-1), experts.astype(jnp.int32)

@@ -20,6 +20,7 @@ class RMSNorm(nn.Module):
     eps: float = 1e-6
     elementwise_affine: bool = True
     dtype: Any = None
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -27,6 +28,6 @@ class RMSNorm(nn.Module):
         normed = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
         normed = normed.astype(x.dtype)
         if self.elementwise_affine:
-            weight = self.param("weight", nn.initializers.ones, (self.dim,))
+            weight = self.param("weight", nn.initializers.ones, (self.dim,), self.param_dtype)
             normed = normed * weight.astype(normed.dtype)
         return normed

@@ -40,7 +40,7 @@ Performance notes (v5e measurements of earlier rounds, PERFORMANCE.md):
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -173,14 +173,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvlen_ref, o_ref, lse_ref, m_ref, l_ref, ac
     # full key blocks skip the col-bias pass entirely (one fewer VPU pass
     # over the [bq, bk] tile — the inner loop is VPU-bound); only the block
     # straddling the valid-key boundary pays for masking
-    @pl.when((j + 1) * block_k <= kvlen_ref[b, h, sg])
+    def visited(block_has_keys):
+        # a key block wholly above the diagonal (its first key after the q
+        # block's last row) is not visited: no compute here, and no copy,
+        # because _fwd_impl's index map names the diagonal block again
+        return block_has_keys & (j * block_k < (i + 1) * block_q) if causal else block_has_keys
+
+    @pl.when(visited((j + 1) * block_k <= kvlen_ref[b, h, sg]))
     def _compute_full():
         _online_step(masked=False)
 
-    @pl.when(
+    @pl.when(visited(
         (j * block_k < kvlen_ref[b, h, sg])
         & ((j + 1) * block_k > kvlen_ref[b, h, sg])
-    )
+    ))
     def _compute_partial():
         _online_step(masked=True)
 
@@ -346,10 +352,14 @@ def _fwd_impl(q, k, v, kv_lens, causal, scale, block_q, block_k, interpret):
 
     Each of the S segments attends independently (block-diagonal attention);
     the segment axis is a grid dimension, so segmented layouts coming from
-    dilated attention need no batch-axis reshuffling.
+    dilated attention need no batch-axis reshuffling. ``k`` / ``v`` may carry
+    ``H / group`` heads (grouped KV heads): the K/V index map sends query head
+    ``h`` to KV head ``h // group``, so a KV head is read from where it lies
+    and never repeated in memory.
     """
     B, H, S, Mq, D = q.shape
     Mk = k.shape[3]
+    group = H // k.shape[1]
     block_q = min(block_q, _round_up(Mq, LANES))
     block_k = min(block_k, _round_up(Mk, LANES))
     Mqp, Mkp = _round_up(Mq, block_q), _round_up(Mk, block_k)
@@ -361,8 +371,14 @@ def _fwd_impl(q, k, v, kv_lens, causal, scale, block_q, block_k, interpret):
         _fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k,
     )
+    def kv_index(b, h, s, i, j):
+        if causal:  # past the diagonal: the block already there, so no new copy
+            j = jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+        # h itself where H_kv = H: that program lowers to the text it always had
+        return (b, h // group if group > 1 else h, s, j, 0)
+
     q_spec = pl.BlockSpec((1, 1, 1, block_q, D), lambda b, h, s, i, j: (b, h, s, i, 0), memory_space=pltpu.VMEM)
-    k_spec = pl.BlockSpec((1, 1, 1, block_k, D), lambda b, h, s, i, j: (b, h, s, j, 0), memory_space=pltpu.VMEM)
+    k_spec = pl.BlockSpec((1, 1, 1, block_k, D), kv_index, memory_space=pltpu.VMEM)
     kvlen_spec = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole (B,H,S) array; indexed by program_id
     with jax.named_scope("kernel_fwd"):
         out, lse = pl.pallas_call(
@@ -647,32 +663,39 @@ def flat_segment_flash(
     return _flat_with_lse(segment_len, rl, is_causal, interpret, q, k, v)
 
 
-def _flash_fwd_rule(kv_lens, causal, interpret, block_q, block_k, q, k, v):
-    scale = q.shape[-1] ** -0.5
+def _scale_of(scale, q):
+    return q.shape[-1] ** -0.5 if scale is None else scale
+
+
+def _flash_fwd_rule(kv_lens, causal, interpret, block_q, block_k, scale, q, k, v):
     out, lse = _fwd_impl(
-        q, k, v, kv_lens, causal, scale, block_q, block_k, interpret
+        q, k, v, kv_lens, causal, _scale_of(scale, q), block_q, block_k, interpret
     )
     return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(kv_lens, causal, interpret, block_q, block_k, res, cotangents):
+def _flash_bwd_rule(kv_lens, causal, interpret, block_q, block_k, scale, res, cotangents):
     q, k, v, out, lse = res
     do, _dlse = cotangents  # no gradient flows through the lse output
-    scale = q.shape[-1] ** -0.5
     delta = jnp.sum(
         do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     )  # [B, H, S, Mq]
+    group = q.shape[1] // k.shape[1]
+    if group > 1:  # the backward kernels take one KV head per query head
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     dq, dk, dv = _bwd_impl(
-        q, k, v, lse, delta, do, kv_lens, causal, scale,
+        q, k, v, lse, delta, do, kv_lens, causal, _scale_of(scale, q),
         block_q, block_k, interpret,
     )
+    if group > 1:  # a KV head's gradient: the sum over the query heads that read it
+        dk, dv = (d.reshape(d.shape[0], -1, group, *d.shape[2:]).sum(2) for d in (dk, dv))
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
-def _flash_with_lse(kv_lens, causal, interpret, block_q, block_k, q, k, v):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
+def _flash_with_lse(kv_lens, causal, interpret, block_q, block_k, scale, q, k, v):
     out, lse = _fwd_impl(
-        q, k, v, kv_lens, causal, q.shape[-1] ** -0.5,
+        q, k, v, kv_lens, causal, _scale_of(scale, q),
         block_q, block_k, interpret,
     )
     return out, lse
@@ -695,8 +718,12 @@ def pallas_flash_attention(
     is_causal: bool = False,
     kv_len=None,
     interpret: bool = False,
+    scale: Optional[float] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Flash attention on [B, L, H, D] -> (out [B,L,H,D], lse [B,H,L]).
+
+    ``k`` / ``v`` may be ``[B, L, H_kv, D]`` with ``H_kv`` dividing ``H``
+    (grouped KV heads). ``scale`` multiplies the logits (``None``: ``D ** -0.5``).
 
     ``kv_len``: optional static [B, H] array-like of per-(batch, head)
     valid key counts (trace-time constants — this wrapper's custom VJP
@@ -716,6 +743,6 @@ def pallas_flash_attention(
     k5 = k.transpose(0, 2, 1, 3)[:, :, None]
     v5 = v.transpose(0, 2, 1, 3)[:, :, None]
     out, lse = _flash_with_lse(
-        kv_lens, is_causal, interpret, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, q5, k5, v5
+        kv_lens, is_causal, interpret, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, scale, q5, k5, v5
     )
     return out[:, :, 0].transpose(0, 2, 1, 3), lse[:, :, 0]

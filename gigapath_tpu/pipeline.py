@@ -20,6 +20,7 @@ build; HF-hub names fall back to random init with a warning).
 
 from __future__ import annotations
 
+import functools
 import os
 from pathlib import Path
 from typing import List, Optional, Tuple
@@ -145,6 +146,23 @@ def slide_forward_fn(slide_encoder_model):
     return slide_forward
 
 
+@functools.lru_cache(maxsize=8)
+def lm_forward_fn(lm):
+    """The jitted scoring forward ``(params, ids [B, L] int32, positions [B,
+    P] int32) -> (logits [B, P, vocab] float32, tokens each held expert
+    received [layers, experts_held] int32)`` that
+    :func:`run_inference_with_lm` runs. The head runs on the rows
+    ``positions`` names and on no other: all 16,384 rows of a long document
+    would be 3.3 GB of logits a request. One function a model (flax modules
+    hash by their fields), so a second call of the entry traces nothing."""
+
+    @jax.jit
+    def lm_forward(params, ids, positions):
+        return lm.apply({"params": params}, ids, positions)
+
+    return lm_forward
+
+
 def run_inference_with_tile_encoder(
     image_paths: List[str],
     tile_encoder,
@@ -255,3 +273,32 @@ def run_inference_with_slide_encoder(
     }
     outputs["last_layer_embed"] = np.asarray(slide_embeds[-1], np.float32)
     return outputs
+
+
+def run_inference_with_lm(
+    token_ids: np.ndarray,
+    positions: Optional[np.ndarray] = None,
+    lm=None,
+    lm_params=None,
+) -> dict:
+    """Score token ids with a causal LM of the registry (``granite_4_0_h_small``
+    is the one there is): ``token_ids [L]`` or ``[B, L]`` int, ``positions
+    [P]`` or ``[B, P]`` the rows whose next-token logits are wanted (the last
+    row where none is given). ``lm`` may be the ``(model, params)`` pair
+    ``models.granite_hybrid.create_lm`` returns. Returns ``{'logits' [B, P,
+    vocab] float32, 'positions' [B, P], 'expert_tokens' [layers,
+    experts_held]}``."""
+    if lm_params is None:
+        lm, lm_params = lm
+    ids = np.atleast_2d(np.asarray(token_ids)).astype(np.int32)
+    if positions is None:
+        positions = np.full((ids.shape[0], 1), ids.shape[1] - 1)
+    positions = np.broadcast_to(
+        np.atleast_2d(np.asarray(positions)), (ids.shape[0], np.shape(positions)[-1])
+    ).astype(np.int32)
+    logits, received = lm_forward_fn(lm)(lm_params, jnp.asarray(ids), jnp.asarray(positions))
+    return {
+        "logits": np.asarray(logits, np.float32),
+        "positions": positions,
+        "expert_tokens": np.asarray(received),
+    }

@@ -242,3 +242,50 @@ def test_flat_bwd_fallback_masks_invalid_row_cotangents(rng, monkeypatch):
         np.asarray(g_fallback[0][:, :, :rl]), np.asarray(g_normal[0][:, :, :rl]),
         atol=1e-5, rtol=1e-4, err_msg="dq differs on the valid region",
     )
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("heads,kv_heads", [(4, 4), (4, 2), (8, 2), (4, 1)])
+def test_grouped_kv_heads_and_scale_match_reference(rng, causal, heads, kv_heads):
+    """Query head h reads KV head h // (heads / kv_heads) through the K/V
+    index map; the scale is an argument (granite's 1/128 is not D ** -0.5).
+    L = 300 with blocks of 128: three blocks a row, so a causal run skips the
+    blocks above the diagonal and clamps their index."""
+    L, D, scale = 300, 16, 0.11
+    q = jnp.asarray(rng.normal(size=(2, L, heads, D)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(2, L, kv_heads, D)), jnp.float32) for _ in range(2))
+    from gigapath_tpu.ops import pallas_flash as pf
+
+    q5, k5, v5 = (x.transpose(0, 2, 1, 3)[:, :, None] for x in (q, k, v))
+    out, lse = pf._fwd_impl(q5, k5, v5, None, causal, scale, 128, 128, True)
+    ref_out, ref_lse = attention_with_lse(q, k, v, is_causal=causal, scale=scale)
+    np.testing.assert_allclose(out[:, :, 0].transpose(0, 2, 1, 3), ref_out, atol=2e-5, rtol=1e-4)
+    np.testing.assert_allclose(lse[:, :, 0], ref_lse, atol=2e-5, rtol=1e-4)
+    out2, _ = flash(q, k, v, is_causal=causal, scale=scale)  # the public wrapper, default blocks
+    np.testing.assert_allclose(out2, ref_out, atol=2e-5, rtol=1e-4)
+
+
+def test_grouped_kv_gradients_sum_over_the_group(rng):
+    q = jnp.asarray(rng.normal(size=(1, 192, 4, 16)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(1, 192, 2, 16)), jnp.float32) for _ in range(2))
+
+    def loss(op):
+        return lambda q, k, v: (op(q, k, v, is_causal=True, scale=0.2)[0] ** 2).sum()
+
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(attention_with_lse), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=5e-4, rtol=1e-3)
+
+
+def test_equal_heads_default_scale_lowers_to_the_parents_text():
+    """The slide encoder's and the ViT's side of the shared kernel: with H_kv
+    = H, no ``scale`` and no causal mask the wrapper lowers to the text it
+    lowered to before grouped KV heads, ``scale`` and diagonal skipping were
+    added (sha256 taken from commit 7080963's tree, same JAX)."""
+    import hashlib
+
+    q = jax.ShapeDtypeStruct((1, 256, 4, 64), jnp.bfloat16)
+    text = jax.jit(lambda q, k, v: flash(q, k, v)).lower(q, q, q).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "03973aa7e43ea55861e1e390559dab264c31b7d950e17740d78379dac376b52c")

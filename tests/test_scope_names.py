@@ -19,6 +19,7 @@ from benchmarks.lib import tables
 
 _TILE = tables.load("configs", "gigapath_tile_enc")["tiny"]
 _SLIDE = tables.load("configs", "gigapath_slide_enc12l768d")["tiny"]
+_LM = tables.load("configs", "granite4h_small_ep2")["tiny"]
 _N_TOKENS = 40  # + class token = 41: three 16-token and two 32-token segments
 
 
@@ -61,6 +62,21 @@ def _slide_forward():
     return pipeline.slide_forward_fn(model), (params, x, c)
 
 
+def _lm_forward(length=40, **widths):
+    from gigapath_tpu import pipeline
+    from gigapath_tpu.utils.registry import create_model_from_registry
+    import gigapath_tpu.models.granite_hybrid  # noqa: F401
+
+    model = create_model_from_registry(
+        _LM["arch"], depth=_LM["depth"], vocab_size=_LM["vocab_size"],
+        experts_held=_LM["num_local_experts"], expert_offset=_LM["expert_offset"], **widths)
+    ids = jax.ShapeDtypeStruct((2, length), jnp.int32)
+    rows = jax.ShapeDtypeStruct((2, 4), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids, rows)["params"]
+    # a function of its own: the entry's cached one may hold a trace made under the other gate
+    return pipeline.lm_forward_fn.__wrapped__(model), (params, ids, rows)
+
+
 def _on_kernels(monkeypatch_context, build):
     """``build()`` with the device gate answering "TPU" and every
     ``pallas_call`` in interpret mode: the slide encoder then takes the fused
@@ -100,6 +116,12 @@ def _lowered(path: str) -> str:
             return _text(*_slide_forward())
         if path == "slide_kernels":
             return _on_kernels(mp, lambda: _text(*_slide_forward()))
+        if path == "lm_jnp":
+            return _text(*_lm_forward())
+        if path == "lm_kernels":  # the grouped product and the causal grouped-KV core
+            # the grouped product tiles widths that are multiples of 128 and no other
+            return _on_kernels(mp, lambda: _text(*_lm_forward(
+                length=512, hidden_size=128, intermediate_size=128)))
         if path == "fused_grad":
             return _text(_grad_of(functools.partial(
                 da.dilated_attention_fused, segment_lengths=[16, 32],
@@ -167,6 +189,11 @@ _NAMES = {
     "slide_kernels": ["jit_slide_forward", "dilated_attn", "branch_r1", "branch_r2", "pack",
                       "kernel_fwd", "unpack", "merge", "dilated_pack", "dilated_fwd",
                       "dilated_unpack"],
+    "lm_jnp": ["jit_lm_forward", "ssm_mixer", "in_proj", "conv", "ssd_scan", "gate_norm",
+               "out_proj", "moe", "router", "dispatch", "experts", "kernel_fwd", "combine",
+               "shared_mlp", "self_attn", "attn_core", "lm_head"],
+    "lm_kernels": ["jit_lm_forward", "ssd_scan", "moe", "experts", "kernel_fwd", "gmm",
+                   "attn_core", "flash_fwd", "lm_head"],
     "fused_grad": ["dilated_attn", "branch_r2", "pack", "kernel_fwd", "kernel_dq",
                    "kernel_dkv", "unpack", "merge", "dilated_pack", "dilated_fwd",
                    "dilated_dq", "dilated_dkv", "dilated_unpack"],

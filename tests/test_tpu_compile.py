@@ -271,3 +271,38 @@ def test_fused_local_branches_inside_shard_map_on_four_chips(topo, schedule):
     from gigapath_tpu.obs.ledger import custom_calls_of
 
     assert custom_calls_of(fn.lower(x, x, x).compile())
+
+
+@pytest.mark.parametrize("piece", ["attention", "experts"])
+def test_granite_layers_at_published_widths_hold_their_kernels(topo, one_chip, monkeypatch, piece):
+    """granite-4.0-h-small's two kernel-bearing layers at the cell's 16,384
+    tokens, the device gate answering "TPU": the causal core is one
+    ``flash_fwd`` call over 32 query and 8 KV heads of 128 (no repeated K/V in
+    memory), the dropless expert layer two ``gmm`` calls over the 36 held
+    experts, each under the scope the trace's reduction finds it by
+    (benchmarks/scopes/lm.json, benchmarks/kernels/*_by_name.json)."""
+    import gigapath_tpu.ops.flash_attention as fa
+    from gigapath_tpu.models.granite_hybrid import CausalGQAttention
+    from gigapath_tpu.obs.ledger import custom_calls_of
+    from gigapath_tpu.ops.moe import DroplessMoE
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    if piece == "attention":
+        layer, shape = CausalGQAttention(4096, 32, 8, 1 / 128), (1, 16384, 4096)
+        calls, scope = 1, r"attn_core/kernel_fwd/flash_fwd/"
+    else:
+        layer, shape = DroplessMoE(4096, 768, 72, 10, experts_held=36), (16384, 4096)
+        calls, scope = 2, r"experts/kernel_fwd/jit\(gmm\)/"
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), (params, x))
+    compiled = jax.jit(layer.apply).lower(*avals).compile()
+    assert custom_calls_of(compiled) == calls
+    text = compiled.as_text()
+    assert re.search(rf'op_name="[^"]*/{scope}', text)
+    # the instruction name the by-name kernel tables look for in the trace
+    kernel = "flash_fwd" if piece == "attention" else "gmm"
+    assert len(re.findall(rf"%{kernel}(?:\.\d+)? = [^\n]* custom-call\(", text)) == calls
+    if piece == "attention":  # a KV head is read where it lies: no [.., 32, 128] copy of k or v
+        assert not re.search(r"broadcast[^\n]*bf16\[1,16384,8,4,128\]", text)
