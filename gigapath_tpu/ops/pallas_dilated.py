@@ -49,6 +49,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -846,7 +847,6 @@ class PipelineFlags(NamedTuple):
     pipelined_bwd: bool = False
     pipe_block_k: Optional[int] = None  # None: VMEM-budget auto choice
     pipe_bwd_block_k: Optional[int] = None
-    pack_direct: bool = False
     stream_fusion: bool = False
     # ring-scheduled K/V exchange for gathered sequence-parallel branches
     # (ops/dilated_attention.py): per-shard memory O(local chunk) instead
@@ -906,7 +906,6 @@ FLAG_ENV = {
     "pipelined_bwd": "GIGAPATH_PIPELINED_BWD",
     "pipe_block_k": "GIGAPATH_PIPE_BLOCK_K",
     "pipe_bwd_block_k": "GIGAPATH_PIPE_BWD_BLOCK_K",
-    "pack_direct": "GIGAPATH_PACK_DIRECT",
     "stream_fusion": "GIGAPATH_STREAM_FUSION",
     "streaming_fusion": "GIGAPATH_STREAMING_FUSION",
     "ring_attn": "GIGAPATH_RING_ATTN",
@@ -921,8 +920,7 @@ FLAG_ENV = {
 
 def snapshot_flags() -> PipelineFlags:
     """Read GIGAPATH_PIPELINED_ATTN/_BWD, GIGAPATH_PIPE(_BWD)_BLOCK_K,
-    GIGAPATH_PACK_DIRECT, GIGAPATH_STREAM_FUSION,
-    GIGAPATH_STREAMING_FUSION, GIGAPATH_RING_ATTN,
+    GIGAPATH_STREAM_FUSION, GIGAPATH_STREAMING_FUSION, GIGAPATH_RING_ATTN,
     GIGAPATH_CHUNKED_PREFILL, GIGAPATH_QUANT_TILE,
     GIGAPATH_QUANT_PALLAS, GIGAPATH_FOLD_PALLAS and
     GIGAPATH_FOLD_BLOCK_Q/_K from the environment, once."""
@@ -943,7 +941,6 @@ def snapshot_flags() -> PipelineFlags:
         pipelined_bwd=env_flag("GIGAPATH_PIPELINED_BWD"),
         pipe_block_k=_int("GIGAPATH_PIPE_BLOCK_K"),
         pipe_bwd_block_k=_int("GIGAPATH_PIPE_BWD_BLOCK_K"),
-        pack_direct=env_flag("GIGAPATH_PACK_DIRECT"),
         stream_fusion=env_flag("GIGAPATH_STREAM_FUSION"),
         ring_attn=env_flag("GIGAPATH_RING_ATTN"),
         chunked_prefill=env_flag("GIGAPATH_CHUNKED_PREFILL"),
@@ -1127,24 +1124,32 @@ def _plan_geometry(L: int, E: int, sl: int, r: int, flags):
     return _branch_geometry(L, E, sl, r, _plan_block(flags, sl, r))
 
 
-def _pack_bt(Mp: int, r: int, E: int, itemsize: int) -> int:
-    """Row-block size for the pack/unpack copy kernels: each cell holds a
-    [bt, r*E] dense row-block in VMEM, so bt*r*E*itemsize must stay well
-    under the budget with double buffering (itemsize matters: the public
-    op is dtype-generic, and fp32 doubles the footprint). Mp is always a
-    multiple of 128 (block sizes are), so every candidate divides it.
+_COPY_WINDOW_BYTES = 3 * 2 ** 20
 
-    bt is a SUBLANE block dim (lanes are r*E, always full-width), so it may
+
+def _pack_bt(Mp: int, r: int, E: int, itemsize: int,
+             budget: int = _COPY_WINDOW_BYTES) -> int:
+    """Row-block size for the pack/unpack copy kernels: each cell holds a
+    dense [bt*r, E] window in VMEM, double-buffered, beside its re-tiled
+    [bt, r*E] copy and the packed blocks, so bt*r*E*itemsize must stay well
+    under the budget (itemsize matters: the public op is dtype-generic, and
+    fp32 doubles the footprint). Mp is always a multiple of 128 (block sizes
+    are), so every candidate divides it.
+
+    bt is a SUBLANE block dim (lanes are E, always full-width), so it may
     legally shrink below 128 down to the 8-row fp32 tile — which is what
     enforces the budget when r*E*itemsize is large: at the flagship r=16
     branch in fp32, bt=128 would be ~6.3 MB in + 6.3 MB out (~25 MB
     double-buffered, over the ~16 MB scoped-VMEM ceiling — the the round-3 driver run
-    OOM class); bt=64 lands back inside the budget. A lane split is NOT
-    available here: the per-phase window is W = E/r lanes (48 at the
-    flagship), and Mosaic only allows lane blocks that are 128-multiples
-    or the whole dim."""
+    OOM class); bt=64 lands back inside the budget. 3 MiB and not 4: heads
+    of 64 at r=8 (E=1024, bt=256) are exactly 4 MiB a window and compile to
+    19.1 MB of scoped VMEM against the 16 MB there is (PR 29, compiled for
+    a described v5e); every 3 MiB window of the flagship and of the heads
+    of 96 fits. A lane split is NOT available here: the per-phase window is
+    W = E/r lanes (48 at the flagship), and Mosaic only allows lane blocks
+    that are 128-multiples or the whole dim."""
     bt = 512
-    while bt > 8 and bt * r * E * itemsize > 4 * 2 ** 20:
+    while bt > 8 and bt * r * E * itemsize > budget:
         bt //= 2
     while Mp % bt:
         bt //= 2
@@ -1199,57 +1204,184 @@ def _assemble_bands(x_ref, r, hb, Dh, E, bt, dtype):
     return jnp.concatenate(pieces, axis=-1)
 
 
-def _pack_kernel(x_ref, o_ref, *, r, hb, Dh, bt):
-    """One dense row-block [bt, r*E] of the [B, S, Mp, r*E] padded view ->
+def _sublane_tile(itemsize: int) -> int:
+    """Rows of one HBM / VMEM tile: 8 of a 32-bit type, 16 of bfloat16."""
+    return 32 // itemsize
+
+
+def _copy_plan(L: int, g: int, S: int, r: int, Mp: int, E: int,
+               itemsize: int) -> Tuple[str, int]:
+    """(windows, bt): where the copy kernels' dense windows
+    ``[s*g + i*bt*r, +bt*r)`` of the ``[B, L, E]`` activation sit, and the
+    row block that goes with it, decided from shapes alone:
+
+    ``"grid"``: on the block grid of ``bt*r`` rows (one segment, or a segment
+    that is a whole number of blocks): plain blocked windows.
+    ``"element"``: segment starts off that grid but on the sublane tile
+    (the flagship's r=2 branch, g = 5,792 = 2^5 * 181): the kernel copies
+    each window in or out by hand. Pack holds such a window twice over, so
+    the row block is sized for half the budget.
+    ``"padded"``: a segment start or the sequence end off the sublane tile
+    with more than one segment (or a sequence shorter than one window): no
+    window of the dense array can be moved legally, so the zero-padded
+    ``[B, S, Mp, r*E]`` view is built by XLA.
+    """
+    bt = _pack_bt(Mp, r, E, itemsize)
+    if S == 1 or g % (bt * r) == 0:
+        return "grid", bt
+    tile = _sublane_tile(itemsize)
+    half = _pack_bt(Mp, r, E, itemsize, budget=_COPY_WINDOW_BYTES // 2)
+    if g % tile or L % tile or half * r > L:
+        return "padded", bt
+    return ("grid" if g % (half * r) == 0 else "element"), half
+
+
+def _valid_rows(g: int, L: int, s, i, rows: int):
+    """How many of the ``rows`` dense rows of window (s, i) are tokens of
+    segment s (inside the segment AND inside the sequence); may be <= 0.
+    (``lax`` primitives on int32 scalars here and below, not ``jnp.minimum``
+    or the ``*`` / ``-`` / ``//`` / ``<`` operators: inside a kernel each of
+    those is a ``jit`` of its own to trace and lower, and the program
+    holds 240 of these kernels.)"""
+    in_segment = lax.min(np.int32(g), lax.sub(np.int32(L), lax.mul(s, np.int32(g))))
+    return lax.sub(in_segment, lax.mul(i, np.int32(rows)))
+
+
+def _emit_packed(x, valid, o_ref, *, r, hb, Dh, bt):
+    """A dense [bt*r, E] window, of which the first ``valid`` rows are
+    tokens of this segment -> all phases' [r, hb, bt, Dh] packed blocks:
+    the rows-of-r-tokens -> r*E-lanes re-tile happens in VMEM. Rows past
+    the segment's end (the NEXT segment's real tokens when Mp*r > g) or
+    past L (whatever stands there, possibly non-finite) are zeroed by
+    LOGICAL row index first: packed K/V MUST be exact zeros at padded
+    slots or p=0 x NaN poisons the PV matmul. One straight-line body for
+    full, partial and empty windows alike: a second copy of the band
+    extraction under ``pl.when`` doubled what every one of the program's
+    180 pack calls costs to trace and lower."""
+    row = lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    real = lax.lt(row, lax.broadcast_in_dim(valid, x.shape, ()))
+    x = lax.select(real, x, lax.full_like(x, 0))
+    _extract_bands(x.reshape(bt, r * x.shape[-1]), o_ref, r, hb, Dh)
+
+
+def _pack_kernel(x_ref, o_ref, *, g, L, **kw):
+    """Windows on the block grid: the dense [bt*r, E] block is read STRAIGHT
+    off the [B, L, E] activation by the pipeline."""
+    valid = _valid_rows(
+        g, L, pl.program_id(1), pl.program_id(2), x_ref.shape[0]
+    )
+    _emit_packed(x_ref[...], valid, o_ref, **kw)
+
+
+def _pack_kernel_element(x_hbm, o_ref, buf, sem, *, g, L, **kw):
+    """Windows off the block grid: each is copied in by hand, the next
+    step's while this one is re-tiled. A copy's height is static, so a
+    window that reaches past L is read from ``L - rows`` instead, and
+    lands that much higher in its ``[2*rows, E]`` slot: a window's rows
+    always start at row ``rows`` of the slot, whatever stood before them
+    (one copy per window whatever its valid height: a ``pl.when`` per
+    height and site was a third of what these calls cost to lower)."""
+    rows = buf.shape[1] // 2
+    tile = _sublane_tile(buf.dtype.itemsize)
+    one, zero = np.int32(1), np.int32(0)
+    nB, nS, nb = (pl.num_programs(d) for d in range(3))
+    step = lax.add(
+        lax.mul(lax.add(lax.mul(pl.program_id(0), nS), pl.program_id(1)), nb),
+        pl.program_id(2),
+    )
+
+    def copy_of(n):
+        """(copy of grid step n's window into its slot, its valid rows)."""
+        rest, i = lax.div(n, nb), lax.rem(n, nb)
+        b, s = lax.div(rest, nS), lax.rem(rest, nS)
+        start = lax.add(lax.mul(s, np.int32(g)), lax.mul(i, np.int32(rows)))
+        source = lax.min(start, np.int32(L - rows))
+        landing = lax.sub(np.int32(rows), lax.sub(start, source))
+        slot = lax.rem(n, np.int32(2))
+        copy = pltpu.make_async_copy(
+            x_hbm.at[b, pl.ds(pl.multiple_of(source, tile), rows)],
+            buf.at[slot, pl.ds(pl.multiple_of(landing, tile), rows)],
+            sem.at[slot],
+        )
+        return copy, _valid_rows(g, L, s, i, rows)
+
+    here, valid = copy_of(step)
+    after, after_valid = copy_of(lax.add(step, one))
+
+    @pl.when(lax.eq(step, zero))
+    def _first():
+        here.start()  # window (0, 0, 0) always holds tokens
+
+    more = lax.lt(lax.add(step, one), lax.mul(lax.mul(nB, nS), nb))
+
+    @pl.when(lax.bitwise_and(more, lax.gt(after_valid, zero)))
+    def _prefetch():
+        after.start()
+
+    @pl.when(lax.gt(valid, zero))
+    def _arrived():
+        here.wait()
+
+    _emit_packed(buf[lax.rem(step, np.int32(2)), rows:], valid, o_ref, **kw)
+
+
+def _pack_kernel_padded(x_ref, o_ref, *, r, hb, Dh, bt):
+    """One row-block [bt, r*E] of the zero-padded [B, S, Mp, r*E] view ->
     ALL phases' [r, hb, bt, Dh] packed blocks (see _band_lanes)."""
     _extract_bands(x_ref[0, 0], o_ref, r, hb, Dh)
 
 
+def _unpack_rows(x_ref, r, hb, Dh, E, bt, dtype):
+    """Packed [r, hb, bt, Dh] blocks -> the dense [bt*r, E] rows they
+    cover, off-band lanes exact 0."""
+    return _assemble_bands(x_ref, r, hb, Dh, E, bt, dtype).reshape(bt * r, E)
+
+
 def _unpack_kernel(x_ref, o_ref, *, r, hb, Dh, bt):
-    """All phases' [r, hb, bt, Dh] packed blocks -> one dense row-block
+    """Packed blocks -> a dense [bt*r, E] window written straight into the
+    [B, L, E] output (windows on the block grid). The window that
+    straddles L is truncated by its copy; windows that would START past
+    L are not in the grid (clamping would slide them backward over valid
+    rows)."""
+    o_ref[...] = _unpack_rows(x_ref, r, hb, Dh, o_ref.shape[-1], bt, o_ref.dtype)
+
+
+def _unpack_kernel_element(x_ref, o_hbm, buf, sem, *, r, hb, Dh, bt, g, L,
+                           heights):
+    """As :func:`_unpack_kernel` for windows off the block grid: the last
+    window of a segment reaches into the next segment's rows (and the last
+    segment's past L), so each window is assembled in VMEM and exactly its
+    valid rows are copied out by hand: one of the static ``heights`` (a
+    copy's height cannot be traced), none for an empty window. The copy is
+    waited for in its own step; the packed blocks of the next step arrive
+    meanwhile all the same."""
+    rows = bt * r
+    b, s, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    valid = lax.min(_valid_rows(g, L, s, i, rows), np.int32(rows))
+    start = pl.multiple_of(
+        lax.add(lax.mul(s, np.int32(g)), lax.mul(i, np.int32(rows))),
+        _sublane_tile(buf.dtype.itemsize),
+    )
+
+    @pl.when(lax.gt(valid, np.int32(0)))
+    def _write():
+        buf[...] = _unpack_rows(x_ref, r, hb, Dh, buf.shape[-1], bt, buf.dtype)
+        for h in heights:
+            @pl.when(lax.eq(valid, np.int32(h)))
+            def _copy_out(h=h):
+                copy = pltpu.make_async_copy(
+                    buf.at[pl.ds(0, h)],
+                    o_hbm.at[b, pl.ds(start, h)], sem.at[0],
+                )
+                copy.start()
+                copy.wait()
+
+
+def _unpack_kernel_padded(x_ref, o_ref, *, r, hb, Dh, bt):
+    """All phases' [r, hb, bt, Dh] packed blocks -> one row-block
     [bt, r*E] of the padded view."""
     E = o_ref.shape[-1] // r
     o_ref[0, 0] = _assemble_bands(x_ref, r, hb, Dh, E, bt, o_ref.dtype)
-
-
-def _pack_kernel_direct(x_ref, o_ref, *, r, hb, Dh, bt, L):
-    """Dense [bt*r, E] row-block read STRAIGHT off the [B, L, E] activation
-    -> all phases' [r, hb, bt, Dh] packed blocks, merging the XLA
-    pad+reshape re-tile pass (~40-53 us/tensor HBM round-trip, round-4
-    decomposition) into the copy kernel: the (bt*r, E) -> (bt, r*E)
-    re-tile happens in VMEM. Tail rows >= L are zeroed by LOGICAL row
-    index before the reshape — correct no matter what the clamped OOB
-    block DMA delivered (garbage may be non-finite, and packed K/V MUST
-    be exact zeros at padded slots or p=0 x NaN poisons the PV matmul);
-    full blocks skip the select. Single-segment branches only: with
-    S > 1 the per-segment padding makes dense row offsets
-    non-block-aligned."""
-    i = pl.program_id(1)
-
-    def emit(x):
-        _extract_bands(x.reshape(bt, r * x.shape[-1]), o_ref, r, hb, Dh)
-
-    @pl.when((i + 1) * bt * r <= L)
-    def _full():
-        emit(x_ref[0])
-
-    @pl.when((i + 1) * bt * r > L)
-    def _partial():
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bt * r, 1), 0) + i * bt * r
-        emit(jnp.where(rows < L, x_ref[0], 0))
-
-
-def _unpack_kernel_direct(x_ref, o_ref, *, r, hb, Dh, bt):
-    """Packed [r, hb, bt, Dh] blocks -> a dense [bt*r, E] row-block written
-    straight into the [B, L, E] output. The straddling tail block's OOB
-    rows are truncated by the block DMA; blocks that would START past L
-    are excluded from the grid by the caller (clamping would otherwise
-    slide them backward over valid rows). Off-band lanes exact 0, as in
-    _unpack_kernel."""
-    E = o_ref.shape[-1]
-    o_ref[0] = _assemble_bands(
-        x_ref, r, hb, Dh, E, bt, o_ref.dtype
-    ).reshape(bt * r, E)
 
 
 def _pad_segments(x: jnp.ndarray, g: int, S: int, gp2: int) -> jnp.ndarray:
@@ -1265,102 +1397,133 @@ def _pad_segments(x: jnp.ndarray, g: int, S: int, gp2: int) -> jnp.ndarray:
 
 @jax.named_scope("pack")
 def _pack_phases(x: jnp.ndarray, g: int, S: int, r: int, Mp: int, H: int,
-                 interpret: bool, pack_direct: bool = False) -> jnp.ndarray:
+                 interpret: bool) -> jnp.ndarray:
     """[B, L, E] -> packed [B, S, r, hb, Mp, Dh] holding ONLY the diagonal
     (phase == band) data — 1/r of the dense volume. The old 7-D layout
     materialized all r^2 (phase, band) blocks and transposed the full
     tensor; the kernels only ever read the diagonal. One pallas_call,
-    reading every dense byte exactly once."""
+    reading every dense byte at most once; the dense array is its operand
+    as it stands (no XLA pad or relayout) unless :func:`_copy_plan`
+    says ``"padded"``."""
     B, L, E = x.shape
     hb = H // r
     Dh = E // H
-    if S == 1 and r > 1 and pack_direct:
-        bt = _pack_bt(Mp, r, E, x.dtype.itemsize)
-        return pl.pallas_call(
-            functools.partial(
-                _pack_kernel_direct, r=r, hb=hb, Dh=Dh, bt=bt, L=L
-            ),
-            grid=(B, Mp // bt),
-            in_specs=[
-                pl.BlockSpec(
-                    (1, bt * r, E), lambda b, i: (b, i, 0),
-                    memory_space=pltpu.VMEM,
-                )
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, r, hb, bt, Dh), lambda b, i: (b, 0, 0, 0, i, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            out_shape=jax.ShapeDtypeStruct((B, 1, r, hb, Mp, Dh), x.dtype),
-            interpret=interpret,
-            name="dilated_pack_direct",
-        )(x)
-    # [B, S, Mp, r*E]: rows are token groups of r, phases live on lanes
-    xp = _pad_segments(x, g, S, Mp * r).reshape(B, S, Mp, r * E)
-    bt = _pack_bt(Mp, r, E, xp.dtype.itemsize)
-    return pl.pallas_call(
-        functools.partial(_pack_kernel, r=r, hb=hb, Dh=Dh, bt=bt),
-        grid=(B, S, Mp // bt),
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, bt, r * E), lambda b, s, i: (b, s, i, 0),
-                memory_space=pltpu.VMEM,
-            )
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, r, hb, bt, Dh), lambda b, s, i: (b, s, 0, 0, i, 0),
+    windows, bt = _copy_plan(L, g, S, r, Mp, E, x.dtype.itemsize)
+    rows = bt * r
+    out_spec = pl.BlockSpec(
+        (1, 1, r, hb, bt, Dh), lambda b, s, i: (b, s, 0, 0, i, 0),
+        memory_space=pltpu.VMEM,
+    )
+    kw = dict(r=r, hb=hb, Dh=Dh, bt=bt)
+    scratch = ()
+    if windows == "padded":
+        # [B, S, Mp, r*E]: rows are token groups of r, phases live on lanes
+        x = _pad_segments(x, g, S, Mp * r).reshape(B, S, Mp, r * E)
+        kernel = functools.partial(_pack_kernel_padded, **kw)
+        in_spec = pl.BlockSpec(
+            (1, 1, bt, r * E), lambda b, s, i: (b, s, i, 0),
             memory_space=pltpu.VMEM,
-        ),
+        )
+    elif windows == "grid":
+        # a window wholly past L is clamped to the last one that starts
+        # inside (its rows are all zeroed, and an unchanged block index
+        # is not copied again)
+        per_seg, last = np.int32(g // rows), np.int32((L - 1) // rows)
+        kernel = functools.partial(_pack_kernel, g=g, L=L, **kw)
+        in_spec = pl.BlockSpec(
+            (None, rows, E),
+            lambda b, s, i: (b, lax.min(lax.add(lax.mul(s, per_seg), i), last), 0),
+            memory_space=pltpu.VMEM,
+        )
+    else:
+        kernel = functools.partial(_pack_kernel_element, g=g, L=L, **kw)
+        in_spec = pl.BlockSpec(memory_space=pl.ANY)
+        scratch = (
+            pltpu.VMEM((2, 2 * rows, E), x.dtype), pltpu.SemaphoreType.DMA((2,))
+        )
+    return pl.pallas_call(
+        kernel,
+        grid=(B, S, Mp // bt),
+        in_specs=[in_spec],
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, r, hb, Mp, Dh), x.dtype),
+        scratch_shapes=scratch,
+        # one step after another: the element kernel fetches the next
+        # step's window during this one
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3
+        ),
         interpret=interpret,
         name="dilated_pack",
-    )(xp)
+    )(x)
 
 
 @jax.named_scope("unpack")
 def _unpack_phases(p6: jnp.ndarray, L: int, E: int, g: int, S: int,
-                   r: int, interpret: bool,
-                   pack_direct: bool = False) -> jnp.ndarray:
+                   r: int, interpret: bool) -> jnp.ndarray:
     """Packed [B, S, r, hb, Mp, Dh] -> dense [B, L, E]; off-band lanes are
-    written as exact zeros by the kernel. The [B, S, Mp, r*E] output view
-    is token-major already, so no XLA transpose exists on either side."""
+    written as exact zeros by the kernel, which writes the dense array
+    itself (no XLA slice or relayout) unless :func:`_copy_plan` says
+    ``"padded"``."""
     B, _, _, hb, Mp, Dh = p6.shape
-    if p6.shape[1] == 1 and r > 1 and pack_direct:
-        bt = _pack_bt(Mp, r, E, p6.dtype.itemsize)
-        # Grid covers only blocks that START inside L: Pallas block DMAs
-        # have dynamic-slice semantics — a straddling block's tail is
-        # truncated, but a block starting PAST the array end would be
-        # clamped BACKWARD and overwrite the last valid rows with padded-
-        # row garbage. ceil(L / (bt*r)) blocks cover every dense row < L
-        # (packed rows beyond nb*bt are padding with nothing to write).
-        nb = min(Mp // bt, -(-L // (bt * r)))
+    windows, bt = _copy_plan(L, g, S, r, Mp, E, p6.dtype.itemsize)
+    rows = bt * r
+    kw = dict(r=r, hb=hb, Dh=Dh, bt=bt)
+    dense = jax.ShapeDtypeStruct((B, L, E), p6.dtype)
+    if windows == "grid":
+        # one step a dense window that STARTS inside L: cdiv(L, rows) of
+        # them cover every row, and packed rows past them are padding
+        if S == 1:
+            packed_block = lambda b, d: (b, 0, 0, 0, d, 0)
+        else:
+            per_seg = np.int32(g // rows)
+            packed_block = lambda b, d: (
+                b, lax.div(d, per_seg), 0, 0, lax.rem(d, per_seg), 0
+            )
         return pl.pallas_call(
-            functools.partial(_unpack_kernel_direct, r=r, hb=hb, Dh=Dh, bt=bt),
-            grid=(B, nb),
+            functools.partial(_unpack_kernel, **kw),
+            grid=(B, -(-L // rows)),
             in_specs=[
                 pl.BlockSpec(
-                    (1, 1, r, hb, bt, Dh), lambda b, i: (b, 0, 0, 0, i, 0),
-                    memory_space=pltpu.VMEM,
+                    (1, 1, r, hb, bt, Dh), packed_block, memory_space=pltpu.VMEM,
                 )
             ],
             out_specs=pl.BlockSpec(
-                (1, bt * r, E), lambda b, i: (b, i, 0),
+                (None, rows, E), lambda b, d: (b, d, 0),
                 memory_space=pltpu.VMEM,
             ),
-            out_shape=jax.ShapeDtypeStruct((B, L, E), p6.dtype),
+            out_shape=dense,
             interpret=interpret,
-            name="dilated_unpack_direct",
+            name="dilated_unpack",
         )(p6)
-    bt = _pack_bt(Mp, r, E, p6.dtype.itemsize)
+    in_spec = pl.BlockSpec(
+        (1, 1, r, hb, bt, Dh), lambda b, s, i: (b, s, 0, 0, i, 0),
+        memory_space=pltpu.VMEM,
+    )
+    if windows == "element":
+        # the heights a window's valid part takes: whole, the last of a
+        # segment, the last of the last segment
+        valid = (min(g, L - s * g) - i * rows
+                 for s in range(S) for i in range(Mp // bt))
+        heights = tuple(sorted({min(h, rows) for h in valid if h > 0}))
+        return pl.pallas_call(
+            functools.partial(
+                _unpack_kernel_element, g=g, L=L, heights=heights, **kw
+            ),
+            grid=(B, S, Mp // bt),
+            in_specs=[in_spec],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            out_shape=dense,
+            scratch_shapes=[
+                pltpu.VMEM((rows, E), p6.dtype), pltpu.SemaphoreType.DMA((1,)),
+            ],
+            interpret=interpret,
+            name="dilated_unpack",
+        )(p6)
     x = pl.pallas_call(
-        functools.partial(_unpack_kernel, r=r, hb=hb, Dh=Dh, bt=bt),
+        functools.partial(_unpack_kernel_padded, **kw),
         grid=(B, S, Mp // bt),
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, r, hb, bt, Dh), lambda b, s, i: (b, s, 0, 0, i, 0),
-                memory_space=pltpu.VMEM,
-            )
-        ],
+        in_specs=[in_spec],
         out_specs=pl.BlockSpec(
             (1, 1, bt, r * E), lambda b, s, i: (b, s, i, 0),
             memory_space=pltpu.VMEM,
@@ -1445,9 +1608,9 @@ def _branch_packed_fwd_impl(q, k, v, vl_dyn, sl, r, H, real_len, causal,
     B, L, E = q.shape
     Dh = E // H
     g, S, gp, m, Mp, block = _plan_geometry(L, E, sl, r, flags)
-    q6 = _pack_phases(q, g, S, r, Mp, H, interpret, flags.pack_direct)
-    k6 = _pack_phases(k, g, S, r, Mp, H, interpret, flags.pack_direct)
-    v6 = _pack_phases(v, g, S, r, Mp, H, interpret, flags.pack_direct)
+    q6 = _pack_phases(q, g, S, r, Mp, H, interpret)
+    k6 = _pack_phases(k, g, S, r, Mp, H, interpret)
+    v6 = _pack_phases(v, g, S, r, Mp, H, interpret)
     kvlen = _branch_kvlen(B, S, g, r, m, real_len, vl_dyn)
     hb = H // r
     pipe_fwd, _ = _branch_pipelined(flags, sl, r)
@@ -1473,7 +1636,7 @@ def _dilated_branch_fwd_impl(q, k, v, vl_dyn, sl, r, H, real_len, causal,
     )
     # off-band lanes come back as exact zeros from the unpack kernel — the
     # branch's cover pattern needs no separate select
-    out = _unpack_phases(out6, L, E, g, S, r, interpret, flags.pack_direct)
+    out = _unpack_phases(out6, L, E, g, S, r, interpret)
     lse = _scatter_lse(lse5, B, L, H, g, S, r, m)
     return out, lse, (out6, lse5)
 
@@ -1502,9 +1665,9 @@ def _branch_bwd_core(q, k, v, vl_dyn, do6, out6, lse5, sl, r, H, real_len,
     Dh = E // H
     hb = H // r
     g, S, gp, m, Mp, block = _plan_geometry(L, E, sl, r, flags)
-    q6 = _pack_phases(q, g, S, r, Mp, H, interpret, flags.pack_direct)
-    k6 = _pack_phases(k, g, S, r, Mp, H, interpret, flags.pack_direct)
-    v6 = _pack_phases(v, g, S, r, Mp, H, interpret, flags.pack_direct)
+    q6 = _pack_phases(q, g, S, r, Mp, H, interpret)
+    k6 = _pack_phases(k, g, S, r, Mp, H, interpret)
+    v6 = _pack_phases(v, g, S, r, Mp, H, interpret)
     # delta = rowsum(do * out) per (token, head), in the kernel's lse
     # layout [B, S, r, Mp, LANES] — the packed arrays ARE the diagonal
     delta = (do6.astype(jnp.float32) * out6.astype(jnp.float32)).sum(axis=-1)
@@ -1527,7 +1690,7 @@ def _branch_bwd_core(q, k, v, vl_dyn, do6, out6, lse5, sl, r, H, real_len,
     def undo(x6):
         # off-band lanes are exact zeros from the unpack kernel — which IS
         # the correct gradient there (the branch never reads those slots)
-        return _unpack_phases(x6, L, E, g, S, r, interpret, flags.pack_direct)
+        return _unpack_phases(x6, L, E, g, S, r, interpret)
 
     vl_ct = (
         None if vl_dyn is None
@@ -1541,7 +1704,7 @@ def _dilated_branch_bwd(sl, r, H, real_len, causal, interpret, flags, saved,
     (q, k, v, vl_dyn, out6, lse5), (B, L, E) = saved
     do, _dlse = cotangents  # no gradient flows through the lse output
     g, S, gp, m, Mp, block = _plan_geometry(L, E, sl, r, flags)
-    do6 = _pack_phases(do, g, S, r, Mp, H, interpret, flags.pack_direct)
+    do6 = _pack_phases(do, g, S, r, Mp, H, interpret)
     return _branch_bwd_core(
         q, k, v, vl_dyn, do6, out6, lse5, sl, r, H, real_len, causal,
         interpret, flags,

@@ -79,7 +79,6 @@ class ExecutionPlan(NamedTuple):
     pipelined_bwd: Optional[bool] = None
     pipe_block_k: Optional[int] = None
     pipe_bwd_block_k: Optional[int] = None
-    pack_direct: Optional[bool] = None
     ring_attn: Optional[bool] = None
     chunked_prefill: Optional[bool] = None
     quant_tile: Optional[str] = None
@@ -152,8 +151,8 @@ class ExecutionPlan(NamedTuple):
 
 _SCALAR_PLAN_FIELDS = (
     "pipelined_fwd", "pipelined_bwd", "pipe_block_k", "pipe_bwd_block_k",
-    "pack_direct", "ring_attn", "chunked_prefill", "quant_tile",
-    "quant_pallas", "fold_pallas", "fold_block_q", "fold_block_k",
+    "ring_attn", "chunked_prefill", "quant_tile", "quant_pallas",
+    "fold_pallas", "fold_block_q", "fold_block_k",
 )
 
 

@@ -7,7 +7,8 @@ flagship slide encoder dispatches to — flash, head-major (bhld), phase-major
 picks at the bench geometry, the streaming ``pair_partial`` fold — and the
 ViT tile encoder's attention core over packed qkv, compared
 with the jnp tier on float32 inputs under
-``jax.default_matmul_precision("highest")``.
+``jax.default_matmul_precision("highest")``; and the dilated branches' pack /
+unpack copy kernels against a plain jnp pack / unpack, exactly.
 
 Callers decide what a failure costs; this module only measures. It never
 asks which backend it is on: the caller runs it on a TPU (or, for the CPU
@@ -272,11 +273,65 @@ def run_kernel_checks(
     check(f"vit packed-qkv attention fwd (N={Nv}, {Hv}x{Dv})",
           pva.packed_qkv_attention(packed, Hv), ref, 3e-2)
 
+    _copy_kernel_checks(geom, rng, check)
+
     if flagged_variants:
         _flagged_variant_checks(
             da, SEGS, RATIOS, N, (qb, kb, vb), loss_f, grads_f, check, rel_check
         )
     return rows
+
+
+def _jnp_pack(x, g, S, r, Mp, H):
+    """Plain jnp [B, L, E] -> [B, S, r, hb, Mp, Dh]: packed row j of
+    (segment s, phase p) is token s*g + j*r + p, heads p*hb .. (p+1)*hb - 1,
+    zeros past the segment's or the sequence's end."""
+    B, L, E = x.shape
+    hb, Dh = H // r, E // H
+    x = jnp.pad(x, ((0, 0), (0, S * g - L), (0, 0))).reshape(B, S, g, E)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, Mp * r - g), (0, 0)))
+    x = x.reshape(B, S, Mp, r, r, hb, Dh)  # [.., row, phase, band, head, :]
+    diag = jnp.stack([x[:, :, :, p, p] for p in range(r)], axis=2)
+    return diag.transpose(0, 1, 2, 4, 3, 5)
+
+
+def _jnp_unpack(p6, L, E, g, S, r):
+    """Plain jnp inverse of :func:`_jnp_pack`; off-band lanes are zeros."""
+    B, _, _, hb, Mp, Dh = p6.shape
+    x = jnp.zeros((B, S, Mp, r, r, hb, Dh), p6.dtype)
+    for p in range(r):
+        x = x.at[:, :, :, p, p].set(p6[:, :, p].transpose(0, 1, 3, 2, 4))
+    x = x.reshape(B, S, Mp * r, E)[:, :, :g]
+    return x.reshape(B, S * g, E)[:, :L]
+
+
+def _copy_kernel_checks(geom: Geometry, rng, check) -> None:
+    """The pack / unpack copy kernels, compiled, against a plain jnp pack /
+    unpack, exact equality: the schedule's every branch at the
+    bench length as the slide encoder pads it (bfloat16), and the wider
+    encoders' heads of 64 and 96 at the two branches whose windows differ
+    most (r = 2: element-offset windows; the last: the largest r * E)."""
+    from gigapath_tpu.ops import pallas_dilated as pd
+
+    H = geom.heads
+    L = -(-geom.bench_len // 128) * 128
+    branches = list(zip(geom.segment_lengths, geom.dilated_ratios))
+    cases = [(geom.head_dim, sl, r) for sl, r in branches]
+    cases += [(Dh, *branches[i]) for Dh in (64, 96) for i in (1, -1)]
+    cases = list(dict.fromkeys(cases))  # a two-branch schedule names one twice
+    for Dh, sl, r in cases:
+        E = H * Dh
+        g, S, _, _, Mp, _ = pd._branch_geometry(L, E, sl, r)
+        x = jnp.asarray(rng.normal(size=(2, L, E)), jnp.bfloat16)
+        p6 = jnp.asarray(rng.normal(size=(2, S, r, H // r, Mp, Dh)), jnp.bfloat16)
+        pack = jax.jit(lambda a: pd._pack_phases(a, g, S, r, Mp, H, False))
+        unpack = jax.jit(lambda a: pd._unpack_phases(a, L, E, g, S, r, False))
+        tag = f"sl={sl} r={r} Dh={Dh} L={L}"
+        # the number compared is the count of elements that differ: one fails
+        check(f"copy pack {tag}: elements that differ",
+              jnp.sum(pack(x) != _jnp_pack(x, g, S, r, Mp, H)), 0, 0.5)
+        check(f"copy unpack {tag}: elements that differ",
+              jnp.sum(unpack(p6) != _jnp_unpack(p6, L, E, g, S, r)), 0, 0.5)
 
 
 def _flagged_variant_checks(da, SEGS, RATIOS, N, qkv_b, loss_f, grads_f,
@@ -294,11 +349,9 @@ def _flagged_variant_checks(da, SEGS, RATIOS, N, qkv_b, loss_f, grads_f,
 
         return f
 
-    both = {"GIGAPATH_PIPELINED_ATTN": "1", "GIGAPATH_PACK_DIRECT": "1",
-            "GIGAPATH_PIPELINED_BWD": "1"}
+    both = {"GIGAPATH_PIPELINED_ATTN": "1", "GIGAPATH_PIPELINED_BWD": "1"}
     combos = [
         ("pipe", {"GIGAPATH_PIPELINED_ATTN": "1"}, 1e-3),
-        ("direct", {"GIGAPATH_PACK_DIRECT": "1"}, 1e-6),  # bit-identical path
         ("pipebwd", {"GIGAPATH_PIPELINED_BWD": "1"}, 1e-6),  # fwd unchanged
         ("all", both, 1e-3),
     ]
@@ -324,5 +377,4 @@ def _flagged_variant_checks(da, SEGS, RATIOS, N, qkv_b, loss_f, grads_f,
         check(f"flagged[{tag}] bench-geom fwd", loss_v, loss_f, tol)
         check(f"flagged[{tag}] traced vl == static", loss_tv, loss_v, 1e-6)
         for name, a, b in zip("qkv", grads_v, grads_f):
-            rel_check(f"flagged[{tag}] d{name}", a, b,
-                      1e-6 if tag == "direct" else 1e-2)
+            rel_check(f"flagged[{tag}] d{name}", a, b, 1e-2)

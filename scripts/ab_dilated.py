@@ -51,10 +51,6 @@ def main():
         help="comma list of pipelined k-block sizes (with 'pipe' variant)",
     )
     ap.add_argument(
-        "--direct", action="store_true",
-        help="also run a GIGAPATH_PACK_DIRECT twin of each fused variant",
-    )
-    ap.add_argument(
         "--grad", action="store_true",
         help="measure the grad step (fwd+bwd wrt q/k/v) instead of forward",
     )
@@ -196,12 +192,6 @@ def main():
             variants[f"pipe{bk}"] = with_env(
                 fused, GIGAPATH_PIPELINED_ATTN=1, GIGAPATH_PIPE_BLOCK_K=bk
             )
-    if args.direct:
-        # _direct twin of every fused-path variant (GIGAPATH_PACK_DIRECT:
-        # single-segment branches read/write dense [B, L, E] in-kernel)
-        for name, fn in list(variants.items()):
-            if name != "bhld":
-                variants[f"{name}_direct"] = with_env(fn, GIGAPATH_PACK_DIRECT=1)
     if args.grad and args.pipebwd:
         for name, fn in list(variants.items()):
             if name != "bhld":

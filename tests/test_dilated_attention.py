@@ -590,69 +590,6 @@ def test_pipelined_fwd_matches_serial(rng, monkeypatch, L, sl, r, rl):
 @pytest.mark.parametrize(
     "L,sl,r,rl",
     [
-        (300, 512, 2, 277),   # tail block straddles L; ragged real_len
-        (523, 1024, 4, 523),  # L far from a bt*r multiple
-        (260, 4096, 8, 201),  # hb == 1 band
-    ],
-)
-def test_pack_direct_matches_padded(rng, monkeypatch, L, sl, r, rl):
-    """GIGAPATH_PACK_DIRECT (single-segment branches read/write dense
-    [B, L, E] directly, re-tiling in VMEM) must be bit-identical to the
-    padded-view path, forward and backward."""
-    from gigapath_tpu.ops.pallas_dilated import dilated_branch_attention
-
-    H, Dh = 8, 16
-    E = H * Dh
-    q, k, v = (
-        jnp.asarray(rng.normal(size=(2, L, E)), jnp.float32) for _ in range(3)
-    )
-
-    def loss(q_, k_, v_):
-        o, _ = dilated_branch_attention(
-            q_, k_, v_, sl, r, H, real_len=rl, interpret=True
-        )
-        return (o * o).sum()
-
-    monkeypatch.delenv("GIGAPATH_PACK_DIRECT", raising=False)
-    o0, l0 = dilated_branch_attention(q, k, v, sl, r, H, real_len=rl, interpret=True)
-    g0 = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    monkeypatch.setenv("GIGAPATH_PACK_DIRECT", "1")
-    o1, l1 = dilated_branch_attention(q, k, v, sl, r, H, real_len=rl, interpret=True)
-    g1 = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    np.testing.assert_array_equal(np.asarray(o1), np.asarray(o0))
-    fin = np.asarray(l0) > -1e19
-    np.testing.assert_array_equal(np.asarray(l1)[fin], np.asarray(l0)[fin])
-    for a, b in zip(g1, g0):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-@pytest.mark.slow
-def test_pack_direct_fully_oob_tail_block(rng, monkeypatch):
-    """Regression: at the flagship-like fp32 r=16 geometry the VMEM budget
-    drops the copy-kernel row block to bt=64, and m=129 pads to Mp=256 —
-    so the direct unpack's naive grid would contain a block STARTING past
-    L (2064 < 3*1024 < 4*1024 = Mp*r). Pallas clamps such a block
-    backward (dynamic-slice semantics), overwriting the last valid rows
-    with padded-row garbage; the grid must exclude it."""
-    from gigapath_tpu.ops.pallas_dilated import _pack_bt, dilated_branch_attention
-
-    H, Dh, r, L, sl = 16, 48, 16, 2064, 4096
-    E = H * Dh
-    assert _pack_bt(256, r, E, 4) == 64  # the geometry the test relies on
-    q, k, v = (
-        jnp.asarray(rng.normal(size=(1, L, E)), jnp.float32) for _ in range(3)
-    )
-    monkeypatch.delenv("GIGAPATH_PACK_DIRECT", raising=False)
-    o0, _ = dilated_branch_attention(q, k, v, sl, r, H, interpret=True)
-    monkeypatch.setenv("GIGAPATH_PACK_DIRECT", "1")
-    o1, _ = dilated_branch_attention(q, k, v, sl, r, H, interpret=True)
-    np.testing.assert_array_equal(np.asarray(o1), np.asarray(o0))
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize(
-    "L,sl,r,rl",
-    [
         (300, 64, 2, 277),      # multi-segment, phases, ragged tail
         (1280, 1280, 1, 1280),  # bwd pipe block_k 512 -> nk=3
         (1280, 1280, 2, 1100),
@@ -747,27 +684,6 @@ def test_pipelined_bwd_fast_small_geometry(rng, monkeypatch):
         np.testing.assert_allclose(
             np.asarray(a) / scale, np.asarray(b) / scale, atol=2e-6
         )
-
-
-def test_pack_direct_fast_small_geometry(rng, monkeypatch):
-    """Fast default-tier sibling of test_pack_direct_matches_padded
-    (GIGAPATH_PACK_DIRECT): single-segment branch with a straddling tail
-    block, forward bit-identity only (the slow tier covers gradients)."""
-    from gigapath_tpu.ops.pallas_dilated import dilated_branch_attention
-
-    L, sl, r, rl = 300, 512, 2, 277
-    H, Dh = 8, 16
-    E = H * Dh
-    q, k, v = (
-        jnp.asarray(rng.normal(size=(1, L, E)), jnp.float32) for _ in range(3)
-    )
-    monkeypatch.delenv("GIGAPATH_PACK_DIRECT", raising=False)
-    o0, l0 = dilated_branch_attention(q, k, v, sl, r, H, real_len=rl, interpret=True)
-    monkeypatch.setenv("GIGAPATH_PACK_DIRECT", "1")
-    o1, l1 = dilated_branch_attention(q, k, v, sl, r, H, real_len=rl, interpret=True)
-    np.testing.assert_array_equal(np.asarray(o1), np.asarray(o0))
-    fin = np.asarray(l0) > -1e19
-    np.testing.assert_array_equal(np.asarray(l1)[fin], np.asarray(l0)[fin])
 
 
 def test_seq_parallel_fused_routing_fast(rng, monkeypatch):

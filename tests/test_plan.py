@@ -156,7 +156,7 @@ class TestPrecedence:
         assert resolved.pipelined_fwd
         assert resolved.pipe_block_k == 256
         # fields the plan has no opinion on keep their defaults
-        assert not resolved.pack_direct and resolved.quant_tile == ""
+        assert not resolved.ring_attn and resolved.quant_tile == ""
 
     def test_present_env_flag_beats_plan(self, clean_env, qkv, monkeypatch):
         key = geometry_key("dilated_fused", qkv)

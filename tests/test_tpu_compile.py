@@ -127,6 +127,27 @@ def test_fused_grad_ragged_bucket_traced_valid_len(topo, one_chip, schedule):
     )
 
 
+@pytest.mark.parametrize("head_dim,branch", [
+    (DH, 0), (DH, 1), (DH, 2), (DH, 3), (DH, 4), (64, 1), (64, 3), (96, 1), (96, 4)])
+def test_copy_kernels_at_the_padded_bench_length(topo, one_chip, schedule, head_dim, branch):
+    """The pack / unpack copy kernels on the dense [B, L, E] array at the
+    length the slide encoder hands them (10,241 padded to 10,368): blocked
+    windows for four branches, hand-copied ones for r = 2 (g = 5,792), and
+    the wider encoders' heads of 64 and 96 (E = 1,024 / 1,536: other row
+    blocks under the same VMEM budget; 64 at r = 8 was over it at 4 MiB)."""
+    from gigapath_tpu.ops import pallas_dilated as pd
+
+    L = -(-N_BENCH // 128) * 128
+    E = H * head_dim
+    sl, r = schedule[0][branch], schedule[1][branch]
+    g, S, _, _, Mp, _ = pd._branch_geometry(L, E, sl, r)
+    assert pd._copy_plan(L, g, S, r, Mp, E, 2)[0] == ("element" if r == 2 else "grid")
+    _compile(lambda x: pd._pack_phases(x, g, S, r, Mp, H, False), one_chip,
+             jax.ShapeDtypeStruct((2, L, E), jnp.bfloat16))
+    _compile(lambda p6: pd._unpack_phases(p6, L, E, g, S, r, False), one_chip,
+             jax.ShapeDtypeStruct((2, S, r, H // r, Mp, head_dim), jnp.bfloat16))
+
+
 @pytest.mark.parametrize("branch", range(5))
 def test_pair_partial_flagship_pairs(topo, one_chip, schedule, branch):
     """The streaming fold kernel at chunk 2,048, forward and backward, for

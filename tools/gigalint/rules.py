@@ -1264,7 +1264,7 @@ def check_lowprec_casts(project: Project) -> List[Finding]:
 _GL017_FLAGS = frozenset({
     "GIGAPATH_PIPELINED_ATTN", "GIGAPATH_PIPELINED_BWD",
     "GIGAPATH_PIPE_BLOCK_K", "GIGAPATH_PIPE_BWD_BLOCK_K",
-    "GIGAPATH_PACK_DIRECT", "GIGAPATH_STREAM_FUSION",
+    "GIGAPATH_STREAM_FUSION",
     "GIGAPATH_STREAMING_FUSION", "GIGAPATH_RING_ATTN",
     "GIGAPATH_CHUNKED_PREFILL", "GIGAPATH_QUANT_TILE",
     "GIGAPATH_QUANT_PALLAS", "GIGAPATH_PLAN", "GIGAPATH_PLAN_REGISTRY",

@@ -39,7 +39,7 @@ def snapshot_flags():
     # negative control by FUNCTION NAME: the one sanctioned flag-VALUE
     # read point (the fixture twin of pallas_dilated.snapshot_flags)
     return {
-        "pack_direct": os.environ.get("GIGAPATH_PACK_DIRECT", "") == "1",
+        "ring_attn": os.environ.get("GIGAPATH_RING_ATTN", "") == "1",
     }
 
 
