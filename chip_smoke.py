@@ -146,7 +146,6 @@ def env_line(args, devices, cache_dir: str, out_dir: str) -> dict:
     import jaxlib
 
     from gigapath_tpu import native
-    from gigapath_tpu.plan.registry import registry_path
 
     try:
         from importlib.metadata import version
@@ -154,7 +153,6 @@ def env_line(args, devices, cache_dir: str, out_dir: str) -> dict:
         libtpu = version("libtpu")
     except Exception:
         libtpu = None
-    registry = registry_path()
     return {
         "phase": "env",
         "mode": "tiny-rehearsal" if args.tiny else f"chips={args.chips}",
@@ -169,8 +167,6 @@ def env_line(args, devices, cache_dir: str, out_dir: str) -> dict:
         "seed": args.seed,
         # host-side tile ops: the C++ build or its exact numpy fallback
         "native_tile_ops": "built" if native.available() else "numpy-fallback",
-        "plan_registry_path": registry,
-        "plan_registry_present": os.path.exists(registry),
     }
 
 

@@ -246,7 +246,7 @@ def run_slide_consumer(root: str, *, runlog=None,
     """Assemble one slide from the channel, recovering from worker loss.
 
     ``streaming`` (default: the plan's ``chunked_prefill`` field, else
-    the ``GIGAPATH_CHUNKED_PREFILL`` snapshot) switches the consumer to
+    ``GIGAPATH_CHUNKED_PREFILL``) switches the consumer to
     chunked prefill: each acked ``EmbeddingChunk`` folds into a
     :class:`~gigapath_tpu.models.streaming_encoder.StreamingEncoderSession`
     the moment the fold frontier reaches it — arrival order, retransmits
@@ -297,15 +297,16 @@ def run_slide_consumer(root: str, *, runlog=None,
         )
     chaos = get_chaos(runlog)
     if streaming is None:
-        # one host-side read, the PipelineFlags convention: the plan
-        # document wins (every process sees the same mode), the env
-        # snapshot is the single-process default
+        # the plan document wins (every process sees the same mode),
+        # the environment is the single-process default
         if "chunked_prefill" in plan:
             streaming = bool(plan["chunked_prefill"])
         else:
-            from gigapath_tpu.ops.pallas_dilated import snapshot_flags
+            from gigapath_tpu.models.streaming_encoder import (
+                chunked_prefill_default,
+            )
 
-            streaming = snapshot_flags().chunked_prefill
+            streaming = chunked_prefill_default()
     if ckpt_every is None:
         ckpt_every = plan.get("consumer_ckpt_every")
     if ckpt_every is None:

@@ -537,11 +537,10 @@ def main(argv=None):
     model, params = load_model(
         args.model_path, n_classes=args.num_classes, model_arch=args.model_arch
     )
-    # GIGAPATH_CHUNKED_PREFILL makes streaming the default route (one
-    # host-side snapshot, the PipelineFlags convention)
-    from gigapath_tpu.ops.pallas_dilated import snapshot_flags
+    # GIGAPATH_CHUNKED_PREFILL makes streaming the default route
+    from gigapath_tpu.models.streaming_encoder import chunked_prefill_default
 
-    stream = bool(args.stream or snapshot_flags().chunked_prefill)
+    stream = bool(args.stream or chunked_prefill_default())
     return run_inference(
         model, params, args.feature_dir, args.output_file,
         use_buckets=not args.no_buckets, batch_size=args.batch_size,

@@ -61,6 +61,17 @@ def prefill_chunk_tiles(default: int = DEFAULT_CHUNK_TILES) -> int:
     return int(env_number("GIGAPATH_PREFILL_CHUNK", default))
 
 
+def chunked_prefill_default() -> bool:
+    """The ``GIGAPATH_CHUNKED_PREFILL`` host flag: whether a driver that
+    was told nothing else (``inference.py`` without ``--stream``, a dist
+    consumer whose plan document is silent) routes slides through the
+    streaming session instead of assemble-then-encode. Read by the
+    driver, once, before it picks its loop — never at trace time."""
+    from gigapath_tpu.ops.common import env_flag
+
+    return env_flag("GIGAPATH_CHUNKED_PREFILL")
+
+
 def encoder_config(model):
     """The EncoderConfig the dense path would build for ``model`` —
     derived through the same factory so the two paths can never read
@@ -246,20 +257,11 @@ class StreamingEncoderSession:
         self.eps = float(cfg.layernorm_eps)
         self.subln = bool(cfg.subln)
         self.depth = int(cfg.encoder_layers)
-        # THE fold plan resolution — once per session, never per chunk
-        # or per fold (the registry stat test pins lookups == 1). The
-        # geometry key is one fold pair's q/k/v block avals, so every
-        # session sharing a chunk geometry shares the blessed entry;
-        # the resolved PipelineFlags ride every fold call as a static
-        # arg. Empty registry -> snapshot_flags() -> flags-default
-        # dispatch, byte-identical to the pre-plan jnp fold.
-        from gigapath_tpu.plan.executionplan import resolve_plan
+        # ONE read of the environment per session, never per chunk or
+        # per fold: the PipelineFlags ride every fold call as a static arg
+        from gigapath_tpu.ops.pallas_dilated import snapshot_flags
 
-        head_dim = int(self.model.embed_dim) // self.num_heads
-        blk = jax.ShapeDtypeStruct(
-            (1, self.chunk_tiles, self.num_heads, head_dim), self.dtype
-        )
-        self.fold_flags = resolve_plan("stream_fold", (blk, blk, blk))
+        self.fold_flags = snapshot_flags()
 
         self._embed_fn = jax.jit(
             _embed_block,
@@ -286,10 +288,10 @@ class StreamingEncoderSession:
                 "stream.post", runlog).wrap(self._post_fn)
 
             def fold_key(*args, **kwargs):
-                # the fold's branch geometry AND resolved flags are
-                # STATIC kwargs: without them in the key, the second
-                # branch's (or the plan-on path's) legitimate compile
-                # would be flagged as a retrace of the first's
+                # the fold's branch geometry AND flags are STATIC
+                # kwargs: without them in the key, the second branch's
+                # legitimate compile would be flagged as a retrace of
+                # the first's
                 return tuple(
                     (tuple(a.shape), str(a.dtype))
                     for a in args if hasattr(a, "shape")

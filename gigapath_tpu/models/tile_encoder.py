@@ -102,8 +102,8 @@ def _dense(features: int, *, quant: str, quant_pallas: bool, dtype,
     (the f32/bf16 fallback and parity oracle — byte-identical trace to
     the pre-quant program), else the ``QuantDense`` twin (same param
     names/shapes, so checkpoints and the sharding-rule name lists are
-    oblivious). ``quant``/``quant_pallas`` come from the caller's
-    ``PipelineFlags`` snapshot — never from the environment here."""
+    oblivious). ``quant``/``quant_pallas`` come from the module's
+    fields — never from the environment here."""
     if not quant:
         return nn.Dense(
             features, dtype=dtype, param_dtype=param_dtype, name=name
@@ -299,10 +299,10 @@ class VisionTransformer(nn.Module):
     norm_eps: float = 1e-6
     global_pool: str = "token"
     # quantized-weight tier ('' = off — the f32/bf16 fallback and parity
-    # oracle; 'int8' / 'fp8_e4m3', optionally '+attn'): the value of the
-    # caller's PipelineFlags.quant_tile snapshot (GIGAPATH_QUANT_TILE),
-    # passed at construction so the traced program — and therefore the
-    # jit cache key — is distinct per tier
+    # oracle; 'int8' / 'fp8_e4m3', optionally '+attn'): what
+    # create_tile_encoder read from GIGAPATH_QUANT_TILE, passed at
+    # construction so the traced program — and therefore the jit cache
+    # key — is distinct per tier
     quant: str = ""
     quant_pallas: bool = False
     dtype: Any = None
@@ -479,7 +479,6 @@ def create_tile_encoder(
     model_arch: str = "gigapath_tile_enc",
     *,
     rng: Optional[jax.Array] = None,
-    flags=None,
     **kwargs,
 ):
     """Build the tile encoder and optionally load a timm torch checkpoint.
@@ -488,31 +487,25 @@ def create_tile_encoder(
     reporting, matching the slide-encoder factory and the reference's timm
     ``checkpoint_path`` loading (``gigapath/pipeline.py:126``).
 
-    Quant-tier routing rides the plan seam: when the caller passes no
-    explicit ``quant``/``quant_pallas`` kwargs, the tier is resolved
-    ONCE through :func:`gigapath_tpu.plan.resolve_plan` at the arch's
-    canonical image geometry — ``GIGAPATH_QUANT_TILE`` /
-    ``GIGAPATH_QUANT_PALLAS`` where set, the registry's blessed
-    ``tile_encoder.<arch>`` plan where not. An explicit kwarg (or a
-    caller-held ``flags`` snapshot) pins the tier regardless; with no
-    env, no plan and no kwarg the result is the byte-identical f32/bf16
-    program, exactly as before the plan refactor.
+    Quant tier: when the caller passes neither a ``quant`` nor a
+    ``quant_pallas`` kwarg, the factory reads ``GIGAPATH_QUANT_TILE`` /
+    ``GIGAPATH_QUANT_PALLAS`` here, once, host side. An explicit kwarg
+    pins the tier regardless; with no env and no kwarg the result is the
+    f32/bf16 program (the parity oracle).
     """
     model = create_model_from_registry(model_arch, **kwargs)
     if "quant" not in kwargs and "quant_pallas" not in kwargs:
-        from gigapath_tpu.plan import resolve_plan
+        from gigapath_tpu.ops.common import env_flag
+        from gigapath_tpu.quant.qtensor import normalize_mode
 
-        shape = jax.ShapeDtypeStruct(
-            (1, model.img_size, model.img_size, 3), jnp.float32
-        )
-        resolved = resolve_plan(f"tile_encoder.{model_arch}", (shape,), flags)
-        if resolved.quant_tile:
-            # rebuild with the resolved tier (module construction is a
-            # frozen dataclass — params are untouched); the common
-            # no-tier path keeps the one construction above
+        quant = normalize_mode(os.environ.get("GIGAPATH_QUANT_TILE", ""))
+        if quant:
+            # rebuild with the tier (module construction is a frozen
+            # dataclass — params are untouched); the common no-tier
+            # path keeps the one construction above
             model = create_model_from_registry(
-                model_arch, quant=resolved.quant_tile,
-                quant_pallas=resolved.quant_pallas, **kwargs,
+                model_arch, quant=quant,
+                quant_pallas=env_flag("GIGAPATH_QUANT_PALLAS"), **kwargs,
             )
     params = init_params(model, rng=rng)
     if pretrained and os.path.isdir(pretrained) and os.path.exists(

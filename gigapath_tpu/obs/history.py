@@ -20,7 +20,7 @@ point is judged against the best (or previous) non-stale point in the
 entry's history, with per-metric regression directions from
 :func:`metric_direction`. Exit-code consumers read ``decision.ok`` —
 the CI-gateable successor of eyeballing round tables, and the trend
-surface a serving stack or geometry autotuner can read.
+surface a serving stack can read.
 
 Pure stdlib — no jax import — shared by ``scripts/perf_history.py`` and
 anything else that wants the trend (it must load on a workstation far
@@ -64,12 +64,6 @@ _DIRECTION_RULES: Tuple[Tuple[str, str], ...] = (
     # oracle and the downstream probe delta are down-good
     ("cosine_drift", "down"),
     ("probe_delta_pt", "down"),
-    # execution-plan autotuner (plan|autotune entry, scripts/autotune.py
-    # via perf_history ingest --plan): the best blessed variant's
-    # walltime rides the wall_s rule below; registry coverage of the
-    # resolved geometries is up-good (a DROP means dispatch silently
-    # fell back to flag/defaults on geometries that used to be planned)
-    ("plan_hit_rate", "up"),
     # fleet-trace critical-path shares (dist|trace entry,
     # scripts/dist_smoke.py --fleet-json): time the slide spent on the
     # wire or blocked on credits is the regression; encode/fold shares
@@ -416,52 +410,6 @@ def fold_tile(doc: dict, snapshot: dict, label: str,
     return _fold_serve_snapshot(
         doc, snapshot, label, key="tile|quant",
         metric_keys=_TILE_METRICS, source=source, force=force,
-    )
-
-
-# autotune payload fields worth trending (scripts/autotune.py's JSON):
-# the best variant's walltime next to the default's (the A/B the sweep
-# exists for), registry hit rate over the geometries the sweep resolved,
-# and the sweep's own coverage counters
-_PLAN_METRICS = (
-    "best_wall_s", "default_wall_s", "plan_hit_rate",
-    "candidates", "gates_passed", "blessed",
-)
-
-
-def fold_plan(doc: dict, snapshot: dict, label: str,
-              source: Optional[str] = None, force: bool = False) -> dict:
-    """One ``autotune`` JSON -> one point under ``plan|autotune`` (the
-    execution-plan autotuner's trend entry — same shared
-    CPU-stale-with-keys policy as the serve/dist/prefill/tile entries:
-    a CPU sweep carries the metric KEYS — and may bless memory-motivated
-    plans — but only an on-chip sweep's walltimes move the trend)."""
-    return _fold_serve_snapshot(
-        doc, snapshot, label, key="plan|autotune",
-        metric_keys=_PLAN_METRICS, source=source, force=force,
-    )
-
-
-# fold-surface sweep payload fields worth trending
-# (scripts/autotune.py --surface fold): the blessed fold step's wall
-# next to the jnp default's (the per-pair A/B), plus the same registry
-# hit-rate / coverage counters as the dilated sweep
-_FOLD_SWEEP_METRICS = (
-    "best_wall_s", "default_wall_s", "plan_hit_rate",
-    "candidates", "gates_passed", "blessed",
-)
-
-
-def fold_autotune(doc: dict, snapshot: dict, label: str,
-                  source: Optional[str] = None, force: bool = False) -> dict:
-    """One fold-surface ``autotune`` JSON (``--surface fold``) -> one
-    point under ``plan|sweep``. Same shared CPU-stale-with-keys policy:
-    a CPU sweep lands STALE carrying the metric keys (and may bless
-    memory-motivated fold plans); only an on-chip sweep's fold-step
-    walltimes (``*wall_s`` — down-good) move the trend."""
-    return _fold_serve_snapshot(
-        doc, snapshot, label, key="plan|sweep",
-        metric_keys=_FOLD_SWEEP_METRICS, source=source, force=force,
     )
 
 

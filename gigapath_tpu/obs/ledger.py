@@ -10,9 +10,9 @@ round-6 table tabulates by hand). This module captures those as
 ``compile_profile`` obs events and folds every profile of a run into one
 canonical per-run ledger JSON, keyed by ``name|shape-signature``, that
 ``scripts/ledger_diff.py`` can diff across commits with per-metric
-thresholds. Golden ledgers for the flagship shapes live in
-``tests/goldens/`` and are pinned by a tier-1 test — the standing,
-trace-level perf regression gate.
+thresholds. The flagship shapes' ledger is built by
+``scripts/refresh_ledger.py`` (no golden of it is committed);
+``tests/test_ledger.py`` reads its signals.
 
 Capture paths:
 

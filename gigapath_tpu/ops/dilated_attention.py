@@ -517,10 +517,8 @@ def dilated_attention_fused(
     [B, L, E] activations (see :mod:`gigapath_tpu.ops.pallas_dilated`).
 
     ``flags``: one :class:`~gigapath_tpu.ops.pallas_dilated.PipelineFlags`
-    snapshot shared by every branch of this op (None: resolve the
-    dispatch here, once, through the plan seam —
-    :func:`gigapath_tpu.plan.resolve_plan` — env flags where set, this
-    geometry's blessed registry plan where not). ``flags.stream_fusion``
+    snapshot shared by every branch of this op (None: snapshot the
+    environment here, once). ``flags.stream_fusion``
     (``GIGAPATH_STREAM_FUSION``) routes the whole op through the
     streaming fusion epilogue: branch results stay in the packed
     phase-major layout end to end and one epilogue kernel chain emits the
@@ -530,7 +528,7 @@ def dilated_attention_fused(
 
     ``streaming_fusion``: fold each branch's (out, lse) into running
     (acc, m, l) instead of stacking all branch outputs (None — the
-    default — inherits the resolved ``flags.streaming_fusion``; an
+    default — inherits ``flags.streaming_fusion``; an
     explicit bool pins the choice) — each branch's
     packed temporaries AND its dense output die before the next branch
     computes, the peak-memory requirement for long-context forwards. All
@@ -549,14 +547,13 @@ def dilated_attention_fused(
         dilated_attention_stream_fused,
         dilated_branch_attention,
         plan_stream_fusion,
+        snapshot_flags,
     )
 
     B, L, H, Dh = q.shape
     E = H * Dh
     if flags is None:
-        from gigapath_tpu.plan import resolve_plan
-
-        flags = resolve_plan("dilated_fused", (q, k, v))
+        flags = snapshot_flags()
     if streaming_fusion is None:
         streaming_fusion = flags.streaming_fusion
     qE, kE, vE = (x.reshape(B, L, E) for x in (q, k, v))
@@ -565,7 +562,6 @@ def dilated_attention_fused(
     if flags.stream_fusion and len(segment_lengths) > 1:
         plan = plan_stream_fusion(
             L, E, H, segment_lengths, dilated_ratios, interpret=interpret,
-            flags=flags,
         )
         if plan is not None:
             out = dilated_attention_stream_fused(
@@ -1079,15 +1075,13 @@ def dilated_attention(
         )
     B, L, H, Dh = q.shape
 
-    # ONE dispatch resolution per public call (the plan seam): env flags
-    # where set, this geometry's blessed registry plan where not. Every
-    # branch of this op — fused, head-major, gathered, ring — shares the
-    # resolved snapshot, so branches can never observe different
-    # dispatch decisions (the same invariant the flag snapshot held).
+    # ONE read of the environment per public call. Every branch of this
+    # op — fused, head-major, gathered, ring — shares the snapshot, so
+    # branches can never observe different dispatch decisions.
     if flags is None:
-        from gigapath_tpu.plan import resolve_plan
+        from gigapath_tpu.ops.pallas_dilated import snapshot_flags
 
-        flags = resolve_plan("dilated_attention", (q, k, v))
+        flags = snapshot_flags()
 
     # ONE eligibility gate for the compiled-kernel paths (the single-device
     # fast path below and the seq-parallel fused-local routing further
@@ -1133,8 +1127,8 @@ def dilated_attention(
             # 1M-token operating point. flags.stream_fusion
             # (GIGAPATH_STREAM_FUSION) engages the packed streaming
             # fusion epilogue inside dilated_attention_fused. Both ride
-            # the ONE resolved snapshot taken at the top of this call
-            # (plan seam) — no env read happens here (gigalint GL017).
+            # the ONE snapshot taken at the top of this call — no env
+            # read happens here (gigalint GL017).
             streaming = flags.streaming_fusion
             fused_ok = all(
                 H % int(rr) == 0 and (H * Dh) % int(rr) == 0
@@ -1192,11 +1186,6 @@ def dilated_attention(
     # exactly as on a single device, and gathered branches combine the
     # all-gathered per-rank counts below (_dilated_branch).
     seq_active = seq_axis_name is not None and seq_axis_size > 1
-    # the resolved snapshot from the top of this call serves the
-    # fused-local routing AND the ring dispatch below (same invariant as
-    # the single-device dispatch above: branches of one op must never
-    # observe different dispatch decisions)
-    sp_flags = flags
     fused_local = (
         kernels_eligible
         and seq_active
@@ -1213,10 +1202,7 @@ def dilated_attention(
     # softmax-attention math and cannot honor an arbitrary attn_fn.
     # Causal gathered branches keep the gather path (its rank-bias
     # construction has no ring counterpart yet); _dilated_branch warns.
-    ring_attn = bool(
-        seq_active and kernels_eligible and sp_flags is not None
-        and sp_flags.ring_attn
-    )
+    ring_attn = bool(seq_active and kernels_eligible and flags.ring_attn)
     ring_allow_pallas = False
     if ring_attn:
         # flash_attention's Pallas tier for the per-step partials is only
@@ -1270,7 +1256,7 @@ def dilated_attention(
                         q.reshape(B, L, H * Dh), k.reshape(B, L, H * Dh),
                         v.reshape(B, L, H * Dh), sl_i, r_i, H,
                         real_len=sp_real_len, valid_len_dyn=sp_valid_dyn,
-                        is_causal=is_causal, flags=sp_flags,
+                        is_causal=is_causal, flags=flags,
                     )
                     o = oE.reshape(B, L, H, Dh)
                 else:

@@ -832,15 +832,16 @@ def _bwd_impl_pipe(q6, k6, v6, do6, lse, delta, kvlen, scale,
 
 
 class PipelineFlags(NamedTuple):
-    """One trace-stable snapshot of the kernel-dispatch env flags.
+    """One trace-stable snapshot of the attention kernels' dispatch switches.
 
-    Read ONCE per public ``dilated_branch_attention`` call (host side, at
-    dispatch) and threaded through the custom_vjp as a static argument, so
-    the forward and backward of one call can never observe different flag
-    values, and no traced code reads the environment (gigalint GL001).
-    Toggling a flag still only affects future traces — the jit cache keys
-    on the traced program, not the environment; see the README flag table
-    for the fresh-function-identity workaround.
+    Read ONCE per public call (host side, at dispatch: an explicit
+    ``flags=`` argument, else :func:`snapshot_flags`) and threaded through
+    the custom_vjp as a static argument, so the forward and backward of one
+    call can never observe different flag values, and no traced code reads
+    the environment (gigalint GL001). Toggling a flag still only affects
+    future traces — the jit cache keys on the traced program, not the
+    environment; see the README flag table for the
+    fresh-function-identity workaround.
     """
 
     pipelined_fwd: bool = False
@@ -848,40 +849,15 @@ class PipelineFlags(NamedTuple):
     pipe_block_k: Optional[int] = None  # None: VMEM-budget auto choice
     pipe_bwd_block_k: Optional[int] = None
     stream_fusion: bool = False
+    # online dense branch fold (GIGAPATH_STREAMING_FUSION — the
+    # memory-motivated near-namesake of stream_fusion above): fold
+    # dilated branches into running (acc, m, l) instead of stacking all
+    # branch outputs
+    streaming_fusion: bool = False
     # ring-scheduled K/V exchange for gathered sequence-parallel branches
     # (ops/dilated_attention.py): per-shard memory O(local chunk) instead
     # of O(full segment), ppermute overlapped with partial attention
     ring_attn: bool = False
-    # streaming chunked prefill (ops/streaming_prefill.py): drivers that
-    # hold a snapshot route slide forwards through the chunk-fold path
-    # (dist consumer, inference --stream default) instead of
-    # assemble-then-encode; the dense path stays the fallback/oracle
-    chunked_prefill: bool = False
-    # quantized tile-encoder tier (gigapath_tpu/quant/): '' = off (the
-    # f32/bf16 fallback and parity oracle), 'int8' / 'fp8_e4m3' =
-    # quantized Dense kernels, '+attn' rider = int8 attention logits
-    # too. Drivers holding a snapshot pass this into the tile-encoder
-    # factory; the quant ops themselves never read the environment
-    quant_tile: str = ""
-    # Pallas tier for the quantized matmul/attention kernels (the jnp
-    # reference formulation is the default tier)
-    quant_pallas: bool = False
-    # online dense branch fold (GIGAPATH_STREAMING_FUSION — the
-    # memory-motivated near-namesake of stream_fusion above): fold
-    # dilated branches into running (acc, m, l) instead of stacking all
-    # branch outputs. Lives in the snapshot since the plan refactor so
-    # the dispatcher reads it from ONE resolved carrier, never the
-    # environment (gigalint GL017)
-    streaming_fusion: bool = False
-    # per-branch-class plan entries from a blessed ExecutionPlan
-    # (gigapath_tpu/plan/): (segment_length, ratio, variant, block) —
-    # variant "" inherits the global pipelined flags, "serial"/
-    # "pipelined" pin the branch's forward kernel family, block (a
-    # 128-multiple in [128, 1024]; 0 = auto) overrides the phase-major
-    # q/k block of _branch_geometry. Never set from the environment:
-    # only resolve_plan fills it, so an empty tuple keeps dispatch
-    # byte-identical to the flag-only behavior
-    branch_plans: Tuple[Tuple[int, int, str, int], ...] = ()
     # Pallas tier for the streaming-fold pair partial
     # (ops/pallas_streaming.py): in-kernel iota masks instead of the jnp
     # oracle's dense [H, cq, ck] mask tensors. False keeps the fold
@@ -890,17 +866,9 @@ class PipelineFlags(NamedTuple):
     # global fold block overrides (None: DEFAULT_FOLD_BLOCK auto choice)
     fold_block_q: Optional[int] = None
     fold_block_k: Optional[int] = None
-    # per-fold-branch-class plan entries: (segment_length, ratio,
-    # block_q, block_k), 0 = auto. Plan-only data like branch_plans:
-    # only resolve_plan fills it
-    fold_branches: Tuple[Tuple[int, int, int, int], ...] = ()
 
 
-# field -> environment twin: the one mapping the plan resolver
-# (gigapath_tpu/plan/executionplan.py) uses to decide which fields the
-# environment has pinned (env wins) and which a blessed plan may fill.
-# branch_plans has no env twin on purpose — per-branch entries are
-# plan-only data.
+# field -> environment twin, in field order
 FLAG_ENV = {
     "pipelined_fwd": "GIGAPATH_PIPELINED_ATTN",
     "pipelined_bwd": "GIGAPATH_PIPELINED_BWD",
@@ -909,9 +877,6 @@ FLAG_ENV = {
     "stream_fusion": "GIGAPATH_STREAM_FUSION",
     "streaming_fusion": "GIGAPATH_STREAMING_FUSION",
     "ring_attn": "GIGAPATH_RING_ATTN",
-    "chunked_prefill": "GIGAPATH_CHUNKED_PREFILL",
-    "quant_tile": "GIGAPATH_QUANT_TILE",
-    "quant_pallas": "GIGAPATH_QUANT_PALLAS",
     "fold_pallas": "GIGAPATH_FOLD_PALLAS",
     "fold_block_q": "GIGAPATH_FOLD_BLOCK_Q",
     "fold_block_k": "GIGAPATH_FOLD_BLOCK_K",
@@ -921,20 +886,17 @@ FLAG_ENV = {
 def snapshot_flags() -> PipelineFlags:
     """Read GIGAPATH_PIPELINED_ATTN/_BWD, GIGAPATH_PIPE(_BWD)_BLOCK_K,
     GIGAPATH_STREAM_FUSION, GIGAPATH_STREAMING_FUSION, GIGAPATH_RING_ATTN,
-    GIGAPATH_CHUNKED_PREFILL, GIGAPATH_QUANT_TILE,
-    GIGAPATH_QUANT_PALLAS, GIGAPATH_FOLD_PALLAS and
-    GIGAPATH_FOLD_BLOCK_Q/_K from the environment, once."""
+    GIGAPATH_FOLD_PALLAS and GIGAPATH_FOLD_BLOCK_Q/_K from the
+    environment, once."""
     import os
 
     from gigapath_tpu.ops.common import env_flag
-    from gigapath_tpu.quant.qtensor import normalize_mode
 
     def _int(name: str) -> Optional[int]:
+        # unset, empty and 0 all mean the auto choice, as every reader
+        # of these fields takes them
         raw = os.environ.get(name, "").strip()
-        return int(raw) if raw else None
-
-    def _str(name: str) -> str:
-        return os.environ.get(name, "").strip()
+        return (int(raw) or None) if raw else None
 
     return PipelineFlags(
         pipelined_fwd=env_flag("GIGAPATH_PIPELINED_ATTN"),
@@ -942,58 +904,12 @@ def snapshot_flags() -> PipelineFlags:
         pipe_block_k=_int("GIGAPATH_PIPE_BLOCK_K"),
         pipe_bwd_block_k=_int("GIGAPATH_PIPE_BWD_BLOCK_K"),
         stream_fusion=env_flag("GIGAPATH_STREAM_FUSION"),
-        ring_attn=env_flag("GIGAPATH_RING_ATTN"),
-        chunked_prefill=env_flag("GIGAPATH_CHUNKED_PREFILL"),
-        quant_tile=normalize_mode(_str("GIGAPATH_QUANT_TILE")),
-        quant_pallas=env_flag("GIGAPATH_QUANT_PALLAS"),
         streaming_fusion=env_flag("GIGAPATH_STREAMING_FUSION"),
+        ring_attn=env_flag("GIGAPATH_RING_ATTN"),
         fold_pallas=env_flag("GIGAPATH_FOLD_PALLAS"),
         fold_block_q=_int("GIGAPATH_FOLD_BLOCK_Q"),
         fold_block_k=_int("GIGAPATH_FOLD_BLOCK_K"),
     )
-
-
-def _branch_plan_entry(flags, sl: int, r: int):
-    """The (sl, r, variant, block) plan entry for one branch class, or
-    None — matched on the branch's OWN (segment_length, ratio), so one
-    geometry's plan covers every branch of the schedule."""
-    if flags is None:
-        return None
-    for entry in getattr(flags, "branch_plans", ()) or ():
-        if int(entry[0]) == int(sl) and int(entry[1]) == int(r):
-            return entry
-    return None
-
-
-def _plan_block(flags, sl: int, r: int) -> int:
-    """Blessed block override for one branch class (0 = auto)."""
-    entry = _branch_plan_entry(flags, sl, r)
-    return int(entry[3]) if entry is not None else 0
-
-
-def _plan_variant(flags, sl: int, r: int) -> str:
-    """Blessed kernel-family variant for one branch class ("" = the
-    global pipelined flags stand)."""
-    entry = _branch_plan_entry(flags, sl, r)
-    return str(entry[2]) if entry is not None else ""
-
-
-def _branch_pipelined(flags, sl: int, r: int) -> Tuple[bool, bool]:
-    """(forward pipelined?, backward pipelined?) for one branch. The
-    per-branch plan variant refines the FORWARD kernel family only
-    ("serial"/"pipelined" pin it; "" inherits the global flag); the
-    backward always rides the global ``pipelined_bwd`` field — which
-    keeps the env-precedence contract intact: an explicitly set
-    GIGAPATH_PIPELINED_BWD survives resolution in that field, and a
-    per-branch variant can never override it. Plans that want a serial
-    backward set the global ``pipelined_bwd: false`` opinion, which the
-    env flag correctly beats."""
-    variant = _plan_variant(flags, sl, r)
-    if variant == "serial":
-        return False, bool(flags.pipelined_bwd)
-    if variant == "pipelined":
-        return True, bool(flags.pipelined_bwd)
-    return bool(flags.pipelined_fwd), bool(flags.pipelined_bwd)
 
 
 def _bwd_impl(q6, k6, v6, do6, lse, delta, kvlen, causal, scale,
@@ -1077,8 +993,7 @@ def _bwd_impl(q6, k6, v6, do6, lse, delta, kvlen, causal, scale,
 # ---------------------------------------------------------------------------
 
 
-def _branch_geometry(L: int, E: int, sl: int, r: int,
-                     block_override: int = 0) -> Tuple[int, int, int, int, int, int]:
+def _branch_geometry(L: int, E: int, sl: int, r: int) -> Tuple[int, int, int, int, int, int]:
     """(g, S, gp, m, Mp, block): segment length/count, r-padded segment,
     sparse length, block-padded sparse length, block size.
 
@@ -1086,14 +1001,7 @@ def _branch_geometry(L: int, E: int, sl: int, r: int,
     budget; otherwise the candidate (multiple of 128) minimizing q-row
     padding — padded key blocks are skipped by the kernel, padded q rows are
     not. The cap keeps q/k/v/out double-buffered blocks plus the fp32 logits
-    tile inside VMEM (W = E/r lanes per block row).
-
-    ``block_override`` (a blessed ExecutionPlan's per-branch block): a
-    legal value — 128-multiple in [LANES, 1024] — replaces the auto
-    choice; anything else is ignored so a stale registry can change
-    performance but never legality. Callers that hold a flags snapshot
-    use :func:`_plan_geometry`, which keeps the forward, backward and
-    epilogue planner on ONE consistent Mp per branch."""
+    tile inside VMEM (W = E/r lanes per block row)."""
     g = min(sl, L)
     S = _round_up(L, g) // g
     gp = _round_up(g, r)
@@ -1104,10 +1012,7 @@ def _branch_geometry(L: int, E: int, sl: int, r: int,
     # candidates below trade q-row padding against cell count
     cap = 1024
     single = _round_up(m, LANES)
-    if block_override and block_override % LANES == 0 \
-            and LANES <= block_override <= cap:
-        block = block_override
-    elif single <= cap:
+    if single <= cap:
         block = single
     else:
         block = min(
@@ -1116,12 +1021,6 @@ def _branch_geometry(L: int, E: int, sl: int, r: int,
         )
     Mp = _round_up(m, block)
     return g, S, gp, m, Mp, block
-
-
-def _plan_geometry(L: int, E: int, sl: int, r: int, flags):
-    """:func:`_branch_geometry` with the branch's blessed block override
-    applied — the one geometry call every flags-holding site uses."""
-    return _branch_geometry(L, E, sl, r, _plan_block(flags, sl, r))
 
 
 _COPY_WINDOW_BYTES = 3 * 2 ** 20
@@ -1607,14 +1506,13 @@ def _branch_packed_fwd_impl(q, k, v, vl_dyn, sl, r, H, real_len, causal,
     epilogue (which never materializes the dense per-branch tensors)."""
     B, L, E = q.shape
     Dh = E // H
-    g, S, gp, m, Mp, block = _plan_geometry(L, E, sl, r, flags)
+    g, S, gp, m, Mp, block = _branch_geometry(L, E, sl, r)
     q6 = _pack_phases(q, g, S, r, Mp, H, interpret)
     k6 = _pack_phases(k, g, S, r, Mp, H, interpret)
     v6 = _pack_phases(v, g, S, r, Mp, H, interpret)
     kvlen = _branch_kvlen(B, S, g, r, m, real_len, vl_dyn)
     hb = H // r
-    pipe_fwd, _ = _branch_pipelined(flags, sl, r)
-    if not causal and pipe_fwd:
+    if not causal and flags.pipelined_fwd:
         out6, lse5 = _fwd_impl_pipe(
             q6, k6, v6, kvlen, Dh ** -0.5, hb, Dh,
             block, _pipe_block_k(block, flags.pipe_block_k), interpret,
@@ -1630,7 +1528,7 @@ def _branch_packed_fwd_impl(q, k, v, vl_dyn, sl, r, H, real_len, causal,
 def _dilated_branch_fwd_impl(q, k, v, vl_dyn, sl, r, H, real_len, causal,
                              interpret, flags):
     B, L, E = q.shape
-    g, S, gp, m, Mp, block = _plan_geometry(L, E, sl, r, flags)
+    g, S, gp, m, Mp, block = _branch_geometry(L, E, sl, r)
     out6, lse5 = _branch_packed_fwd_impl(
         q, k, v, vl_dyn, sl, r, H, real_len, causal, interpret, flags
     )
@@ -1664,7 +1562,7 @@ def _branch_bwd_core(q, k, v, vl_dyn, do6, out6, lse5, sl, r, H, real_len,
     B, L, E = q.shape
     Dh = E // H
     hb = H // r
-    g, S, gp, m, Mp, block = _plan_geometry(L, E, sl, r, flags)
+    g, S, gp, m, Mp, block = _branch_geometry(L, E, sl, r)
     q6 = _pack_phases(q, g, S, r, Mp, H, interpret)
     k6 = _pack_phases(k, g, S, r, Mp, H, interpret)
     v6 = _pack_phases(v, g, S, r, Mp, H, interpret)
@@ -1674,8 +1572,7 @@ def _branch_bwd_core(q, k, v, vl_dyn, do6, out6, lse5, sl, r, H, real_len,
     delta = delta.transpose(0, 1, 2, 4, 3)  # [B, S, r, Mp, hb]
     delta = jnp.pad(delta, ((0, 0),) * 4 + ((0, LANES - hb),))
     kvlen = _branch_kvlen(B, S, g, r, m, real_len, vl_dyn)
-    _, pipe_bwd = _branch_pipelined(flags, sl, r)
-    if not causal and pipe_bwd:
+    if not causal and flags.pipelined_bwd:
         dq6, dk6, dv6 = _bwd_impl_pipe(
             q6, k6, v6, do6, lse5, delta, kvlen, Dh ** -0.5,
             hb, Dh, block,
@@ -1703,7 +1600,7 @@ def _dilated_branch_bwd(sl, r, H, real_len, causal, interpret, flags, saved,
                         cotangents):
     (q, k, v, vl_dyn, out6, lse5), (B, L, E) = saved
     do, _dlse = cotangents  # no gradient flows through the lse output
-    g, S, gp, m, Mp, block = _plan_geometry(L, E, sl, r, flags)
+    g, S, gp, m, Mp, block = _branch_geometry(L, E, sl, r)
     do6 = _pack_phases(do, g, S, r, Mp, H, interpret)
     return _branch_bwd_core(
         q, k, v, vl_dyn, do6, out6, lse5, sl, r, H, real_len, causal,
@@ -1737,21 +1634,17 @@ def dilated_branch_attention(
     ``valid_len_dyn``: optional TRACED [B] suffix valid lengths (collate
     pad masks) — combined with the static masks in the kernels' SMEM
     valid-count tables at runtime.
-    ``flags``: kernel-dispatch flag snapshot; by default the call's
-    dispatch is resolved ONCE through the plan seam
-    (:func:`gigapath_tpu.plan.resolve_plan`: env flags where set, the
-    geometry's blessed registry plan where not — see the README
-    "Execution plans" section). Pass an explicit :class:`PipelineFlags`
-    to pin the dispatch independently of environment and registry.
+    ``flags``: kernel-dispatch flag snapshot; by default the call reads
+    the environment ONCE (:func:`snapshot_flags`). Pass an explicit
+    :class:`PipelineFlags` to pin the dispatch independently of the
+    environment.
     """
     B, L, E = q.shape
     assert E % num_heads == 0
     assert num_heads % r == 0 and E % r == 0, (num_heads, E, r)
     rl = L if real_len is None else min(int(real_len), L)
     if flags is None:
-        from gigapath_tpu.plan import resolve_plan
-
-        flags = resolve_plan("dilated_branch", (q, k, v))
+        flags = snapshot_flags()
     return _dilated_branch(
         q, k, v, valid_len_dyn, int(sl), int(r), num_heads, rl, is_causal,
         interpret, flags,
@@ -1818,15 +1711,13 @@ def dilated_branch_attention_packed(
     """One dilated branch returning the PACKED phase-major results
     ``(out6 [B, S, r, hb, Mp, Dh], lse5 [B, S, r, Mp, LANES])`` — the
     streaming fusion epilogue's input contract. Same eligibility rules
-    and plan-seam resolution as :func:`dilated_branch_attention`."""
+    and ``flags`` default as :func:`dilated_branch_attention`."""
     B, L, E = q.shape
     assert E % num_heads == 0
     assert num_heads % r == 0 and E % r == 0, (num_heads, E, r)
     rl = L if real_len is None else min(int(real_len), L)
     if flags is None:
-        from gigapath_tpu.plan import resolve_plan
-
-        flags = resolve_plan("dilated_branch", (q, k, v))
+        flags = snapshot_flags()
     return _dilated_branch_packed(
         q, k, v, valid_len_dyn, int(sl), int(r), num_heads, rl, is_causal,
         interpret, flags,
@@ -1896,14 +1787,10 @@ def plan_stream_fusion(
     L: int, E: int, H: int,
     segment_lengths, dilated_ratios,
     interpret: bool = False,
-    flags=None,
 ) -> Optional[EpiloguePlan]:
     """Build the epilogue's static plan, or None when the schedule's
     geometry admits no legal blocking (callers fall back to the dense
-    scatter + stacked fusion path, which stays the parity oracle).
-    ``flags``: the caller's resolved snapshot — its per-branch blessed
-    block overrides change each branch's packed Mp, and the epilogue's
-    blocking must agree with the branch kernels' layout exactly."""
+    scatter + stacked fusion path, which stays the parity oracle)."""
     n = len(segment_lengths)
     if n < 2:
         return None
@@ -1913,7 +1800,7 @@ def plan_stream_fusion(
         sl, r = int(sl), int(r)
         if H % r != 0 or E % r != 0:
             return None
-        g, S, gp, m, Mp, block = _plan_geometry(L, E, sl, r, flags)
+        g, S, gp, m, Mp, block = _branch_geometry(L, E, sl, r)
         branches.append((r, H // r, S, g, Mp))
 
     def feasible(bi: int, BT: int) -> bool:
@@ -2285,21 +2172,13 @@ def dilated_attention_stream_fused(
     feasibility (pass the plan in to avoid recomputing it)."""
     B, L, E = q.shape
     if flags is None:
-        from gigapath_tpu.plan import resolve_plan
-
-        flags = resolve_plan("dilated_stream", (q, k, v))
-    if plan is None or plan.interpret != bool(interpret) \
-            or getattr(flags, "branch_plans", ()):
-        # a caller-built plan must agree with this call's interpret mode
-        # AND with the resolved flags' per-branch block overrides — a
-        # blessed block changes each branch's packed Mp, and an epilogue
-        # plan built without the flags would read the packed arrays at
-        # the wrong layout. Rebuilding is pure cheap Python; when the
-        # caller already built it with these flags the rebuild is
-        # identical (plan_stream_fusion is deterministic).
+        flags = snapshot_flags()
+    if plan is None or plan.interpret != bool(interpret):
+        # a caller-built plan must agree with this call's interpret mode;
+        # rebuilding is pure cheap Python
         plan = plan_stream_fusion(
             L, E, num_heads, segment_lengths, dilated_ratios,
-            interpret=interpret, flags=flags,
+            interpret=interpret,
         )
     assert plan is not None, "caller must gate on plan_stream_fusion"
     outs, lses = [], []

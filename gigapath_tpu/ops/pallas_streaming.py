@@ -64,7 +64,7 @@ from gigapath_tpu.ops.pallas_flash import (
 # Chunk blocks are small next to the dense path's sequences (the 16k
 # smoke geometry folds 2048-token chunks), so the flash default of
 # 1024x1024 — fp32 logits tile 4 MB, well under the 16 MB VMEM budget —
-# is also the fold's default; blessed plans override per branch class.
+# is also the fold's default.
 DEFAULT_FOLD_BLOCK = 1024
 
 # layout of the dynamic int32 SMEM info array (ONE executable serves
@@ -74,23 +74,11 @@ _INFO_Q0, _INFO_K0, _INFO_VALID, _INFO_CQ, _INFO_CK = range(5)
 _NO_VALID = np.int32(2**31 - 1)
 
 
-def fold_blocks(flags, segment_len: int, ratio: int) -> Tuple[int, int]:
-    """(block_q, block_k) for one fold branch class from a resolved
-    flags carrier: a ``fold_branches`` plan entry matched on the
-    branch's own (segment_len, ratio) wins, then the global
-    ``fold_block_q``/``fold_block_k`` fields, then the default."""
-    bq = bk = None
-    if flags is not None:
-        for entry in getattr(flags, "fold_branches", ()) or ():
-            if int(entry[0]) == int(segment_len) and int(entry[1]) == int(ratio):
-                bq = int(entry[2]) or None
-                bk = int(entry[3]) or None
-                break
-        if bq is None:
-            bq = getattr(flags, "fold_block_q", None)
-        if bk is None:
-            bk = getattr(flags, "fold_block_k", None)
-    return int(bq or DEFAULT_FOLD_BLOCK), int(bk or DEFAULT_FOLD_BLOCK)
+def fold_blocks(flags) -> Tuple[int, int]:
+    """(block_q, block_k) of the fold kernel: the carrier's
+    ``fold_block_q``/``fold_block_k`` where set, else the default."""
+    return (int(flags.fold_block_q or DEFAULT_FOLD_BLOCK),
+            int(flags.fold_block_k or DEFAULT_FOLD_BLOCK))
 
 
 # ---------------------------------------------------------------------------

@@ -159,13 +159,12 @@ def pair_partial_attention(
     ``valid_len`` (optional dynamic scalar) masks keys at global
     positions >= it — the ragged/padded tail.
 
-    ``flags``: a resolved ``PipelineFlags`` carrier (or None). With
+    ``flags``: a ``PipelineFlags`` carrier (or None). With
     ``flags.fold_pallas`` the pair runs the Pallas tier
     (:mod:`gigapath_tpu.ops.pallas_streaming` — masks computed in-kernel
     from iota comparisons, no dense ``[H, cq, ck]`` mask tensor ever
     materialized); otherwise this jnp formulation below IS the dispatch
-    — byte-identical to the pre-plan behavior and the parity oracle the
-    Pallas tier is tested against. Fully-masked rows carry a
+    — the parity oracle the Pallas tier is tested against. Fully-masked rows carry a
     large-negative lse SENTINEL in both tiers (~ -1e8 here, ~ -7e19 in
     the kernel's underflow discipline); downstream combines weight
     either to exactly 0.
@@ -176,7 +175,7 @@ def pair_partial_attention(
             pallas_pair_partial,
         )
 
-        bq, bk = fold_blocks(flags, segment_len, ratio)
+        bq, bk = fold_blocks(flags)
         return pallas_pair_partial(
             q_blk, k_blk, v_blk, q0, k0,
             segment_len=segment_len, ratio=ratio, valid_len=valid_len,
@@ -231,9 +230,9 @@ def fold_pair(
     whole per-chunk streaming executable — its arguments and
     temporaries are all O(chunk), never O(L), which is what the XLA
     memory-analysis pins and the jaxpr guard assert. ``flags`` (a
-    resolved ``PipelineFlags`` carrier or None, static under jit —
-    NamedTuples hash, so plan on-vs-off lands distinct jit cache
-    entries) selects the pair tier; None is the plain jnp path."""
+    ``PipelineFlags`` carrier or None, static under jit — NamedTuples
+    hash, so distinct carriers land distinct jit cache entries) selects
+    the pair tier; None is the plain jnp path."""
     with jax.named_scope("fold"):
         o, l = pair_partial_attention(
             q_blk, k_blk, v_blk, q0, k0,
@@ -313,9 +312,9 @@ class StreamingPrefillState:
         (signature of :func:`fold_pair`) — how callers instrument the
         fold executable (e.g. a ``CompileWatchdog.wrap`` so retraces
         land on the obs bus); default is the plain jitted fold.
-        ``flags``: resolved ``PipelineFlags`` (or None) threaded into
-        every fold call as a static arg — callers resolve the plan ONCE
-        (per session/geometry), never per chunk."""
+        ``flags``: ``PipelineFlags`` (or None) threaded into every fold
+        call as a static arg — callers snapshot ONCE per session, never
+        per chunk."""
         self.bounds = tuple((int(a), int(b)) for a, b in bounds)
         assert self.bounds and all(a < b for a, b in self.bounds)
         self.total_len = int(total_len or self.bounds[-1][1])

@@ -95,13 +95,11 @@ def load_tile_slide_encoder(
     """Load both encoders; returns ``((tile_model, tile_params),
     (slide_model, slide_params))`` (reference ``pipeline.py:118-137``).
 
-    The tile encoder's quant tier resolves through the plan seam inside
-    the factory (``GIGAPATH_QUANT_TILE`` where set, the plan registry's
-    blessed ``tile_encoder.<arch>`` entry where not — one host-side
-    resolution, the convention every kernel flag follows): quant off
-    builds the byte-identical f32/bf16 program, quant on builds the
-    quantized-Dense tier — a distinct traced program, so the jit cache
-    can never serve the wrong tier."""
+    The tile encoder's quant tier is read inside the factory
+    (``GIGAPATH_QUANT_TILE``, one host-side read): quant off builds the
+    f32/bf16 program, quant on builds the quantized-Dense tier — a
+    distinct traced program, so the jit cache can never serve the wrong
+    tier."""
     tile_model, tile_params = tile_encoder_lib.create_tile_encoder(
         pretrained=local_tile_encoder_path, model_arch=tile_arch,
         dtype=jnp.bfloat16,

@@ -14,8 +14,8 @@
 - :mod:`gigapath_tpu.quant.parity` — the drift-vs-oracle harness behind
   ``scripts/ab_tile.py``'s ``adopt_quant_tile`` decision table.
 
-Routing: ``GIGAPATH_QUANT_TILE`` (snapshotted into ``PipelineFlags``
-like every kernel flag) selects the tier inside
+Routing: ``GIGAPATH_QUANT_TILE`` (read once, host side, by
+``models/tile_encoder.create_tile_encoder``) selects the tier inside
 ``models/tile_encoder.py``'s ``ViTAttention``/``SwiGLUPacked``/``Mlp``;
 the f32 path stays the fallback and parity oracle.
 """

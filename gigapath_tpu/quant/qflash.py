@@ -19,11 +19,10 @@ parity oracle.
 
 Tiers: jnp reference by default; a Pallas online-softmax kernel
 (base-2 hot loop, running-max floor — the pallas_flash.py numerics)
-behind the caller's ``PipelineFlags.quant_pallas`` snapshot when the
-sequence is block-aligned. The ViT tile sequence (197 = 1 cls + 196
-patches) is NOT 128-aligned, so the tile encoder rides the reference
-tier until the plan-based dispatch (ROADMAP item 5) pads sequences to
-kernel quanta.
+behind the caller's ``use_pallas`` (``GIGAPATH_QUANT_PALLAS``, read by
+the tile-encoder factory) when the sequence is block-aligned. The ViT
+tile sequence (197 = 1 cls + 196 patches) is NOT 128-aligned, so the
+tile encoder rides the reference tier.
 """
 
 from __future__ import annotations

@@ -14,10 +14,10 @@ mode empty.
 
 Tier dispatch follows the repo's kernel-flag discipline: the default
 tier is the jnp reference formulation (XLA fuses it well and it runs
-everywhere); the Pallas tier engages only when the caller's
-``PipelineFlags`` snapshot carries ``quant_pallas``
-(``GIGAPATH_QUANT_PALLAS``, read ONCE host-side at dispatch — never
-here) and the geometry is MXU-tileable (K and N multiples of 128).
+everywhere); the Pallas tier engages only when the caller passes
+``use_pallas`` (``GIGAPATH_QUANT_PALLAS``, read ONCE host-side by the
+tile-encoder factory — never here) and the geometry is MXU-tileable
+(K and N multiples of 128).
 Untileable geometries silently use the reference tier — same fallback
 shape as ``flash_attention``'s ``PALLAS_MIN_SEQ`` routing.
 """
@@ -143,8 +143,8 @@ def q_matmul(x: jnp.ndarray, qt: QTensor, *,
              interpret: bool = False) -> jnp.ndarray:
     """The quantized matmul entry: f32 out, tier per the module doc.
 
-    ``use_pallas`` is the caller's already-snapshotted flag value
-    (``PipelineFlags.quant_pallas``) — this function NEVER reads the
+    ``use_pallas`` is the caller's already-read flag value
+    (``GIGAPATH_QUANT_PALLAS``) — this function NEVER reads the
     environment (gigalint GL001)."""
     if use_pallas is None:
         use_pallas = False
@@ -178,7 +178,7 @@ class QuantDense(nn.Module):
     features: int
     mode: str
     use_bias: bool = True
-    use_pallas: bool = False  # the PipelineFlags.quant_pallas snapshot
+    use_pallas: bool = False  # the factory's GIGAPATH_QUANT_PALLAS read
     dtype: Any = None
     param_dtype: Any = jnp.float32
 

@@ -9,16 +9,10 @@ restart loads the persisted artifact instead of retracing (ROADMAP item
 2. **artifact**: a persisted ``jax.experimental.serialize_executable``
    payload under ``artifact_dir``, keyed by an environment fingerprint
    (jax version, backend, input signature, caller identity, the
-   kernel-tier ``PipelineFlags`` snapshot — quant tier included — AND
-   the ACTIVE plan-registry state — the verified entries digest plus
-   the bucket's resolved plan, ``gigapath_tpu/plan/``) so a stale
-   artifact from another jax build, model shape, kernel tier or
-   registry state can never be executed — any mismatch or load failure
-   falls through to a fresh compile, and any plan-registry edit
-   re-fingerprints every bucket (the compiled forward bakes in plans
-   for every geometry key its trace resolved, which no bucket-level
-   check can enumerate — over-invalidation is a recompile, staleness
-   would be wrong dispatch);
+   attention kernels' ``PipelineFlags`` snapshot) so a stale artifact
+   from another jax build, model shape or kernel tier can never be
+   executed — any mismatch or load failure falls through to a fresh
+   compile;
 3. **compile**: ``jit(forward, donate_argnums=(1, 2)).lower(...).compile()``
    over ``jax.ShapeDtypeStruct`` inputs (no dummy arrays are ever
    materialized), then persisted best-effort for the next process.
@@ -98,10 +92,10 @@ class AotExecutableCache:
             forward, donate_argnums=(1, 2) if donate else ()
         )
         self._param_sig = _param_signature(params)
-        # the FULL kernel-tier flag snapshot participates in the
-        # artifact identity: a forward built under one tier (quant,
-        # ring, stream fusion, ...) must never be satisfied by a
-        # persisted executable of another. The code signature usually
+        # the FULL attention-kernel flag snapshot participates in the
+        # artifact identity: a forward built under one tier (ring,
+        # stream fusion, ...) must never be satisfied by a persisted
+        # executable of another. The code signature usually
         # catches this too, but an untraceable forward degrades to
         # shapes-only — the flag fingerprint is the belt under that
         # suspender, and a NamedTuple repr covers every current and
@@ -190,37 +184,6 @@ class AotExecutableCache:
                 self._code_sig = "no-code-sig"
         return self._code_sig
 
-    def _plan_signature(self, capacity: int, bucket_n: int) -> str:
-        """The ACTIVE execution-plan state, as it stands right now: the
-        verified registry's entries digest combined with this bucket's
-        own resolved plan (:func:`gigapath_tpu.plan.resolve_plan`, which
-        re-stats the registry file, so an edit is seen immediately).
-        The WHOLE registry digest — not just this bucket's key — because
-        the compiled forward resolves plans for every geometry key its
-        trace encounters (the model's inner ``dilated_attention`` calls
-        resolve their own q/k/v-shaped keys, which no bucket-level
-        caller can enumerate). Folding this into the fingerprint means a
-        registry edit can never load a stale-plan executable: every
-        artifact of the old registry state stops matching and the bucket
-        recompiles under the new one — over-invalidation costs a
-        recompile, staleness would cost wrong dispatch. Resolution
-        failure degrades to a constant (shapes/flags still protect the
-        artifact)."""
-        try:
-            from gigapath_tpu.plan import plan_registry_signature, resolve_plan
-
-            resolved = resolve_plan(
-                self.name, self._abstract_inputs(capacity, bucket_n)
-            )
-            return f"{plan_registry_signature()}|{resolved!r}"
-        except Exception as e:
-            self.runlog.echo(
-                f"[serve] plan resolution failed for bucket "
-                f"{capacity}x{bucket_n} ({type(e).__name__}: {e}); "
-                "artifact identity falls back to the flag snapshot"
-            )
-            return "no-plan-sig"
-
     def _fingerprint(self, capacity: int, bucket_n: int) -> str:
         import jax
 
@@ -229,7 +192,6 @@ class AotExecutableCache:
             str(ARTIFACT_SCHEMA_VERSION), jax.__version__,
             jax.default_backend(), self.identity, self._param_sig,
             self._code_signature(), self._flags_sig,
-            self._plan_signature(capacity, bucket_n),
             f"{capacity}x{bucket_n}x{self.feature_dim}",
         ):
             h.update(part.encode())

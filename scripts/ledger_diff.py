@@ -3,7 +3,7 @@
 thresholds and emit a machine-checkable regression verdict.
 
     python scripts/ledger_diff.py BASELINE.json CANDIDATE.json
-    python scripts/ledger_diff.py tests/goldens/LEDGER_flagship.json /tmp/fresh.json --json verdict.json
+    python scripts/ledger_diff.py A.json B.json --json verdict.json
     python scripts/ledger_diff.py --selftest
 
 Entries are keyed ``name|shape-signature``; per entry the compared

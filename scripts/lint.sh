@@ -35,11 +35,7 @@
 #               reaches the fleet's merged timeline);
 #   - GL023     the seeded running-moments fixture must fire (by-hand
 #               Welford triple in library code instead of the obs
-#               accumulators);
-#   - autotune  (scripts/autotune.py --selftest): blessed-plan dispatch,
-#               env precedence, corrupt-registry refusal, and the fold
-#               surface (--surface fold): candidates ranked, mask-eqn
-#               A/B, bless round-trip, second resolve hits the entry.
+#               accumulators).
 #
 # Default mode fails fast on the first broken selftest. --json mode runs
 # EVERYTHING, then emits a single {"metric": "lint", ..., "decision":
@@ -112,12 +108,6 @@ run_selftest GL020 1 python -m tools.gigalint --no-waivers --select GL020 \
     tools/gigarace/selftest/fixture/sigpath.py
 run_selftest GL021 1 python -m tools.gigalint --no-waivers --select GL021 \
     tools/gigarace/selftest/fixture/joinwait.py
-
-# autotune selftest: blessed-plan dispatch, env precedence, corrupt
-# registry refusal — plus the fold-surface sweep (candidates ranked,
-# decision table, bless round-trip, second resolve hits the blessed
-# entry) — the plan half of the dispatch machinery
-run_selftest autotune 0 env JAX_PLATFORMS=cpu python scripts/autotune.py --selftest
 
 if [ "$JSON" -eq 1 ]; then
     LINT_OUT="$(mktemp)"

@@ -37,8 +37,6 @@ sys.path.insert(0, REPO_ROOT)
 from gigapath_tpu.obs import history  # noqa: E402
 
 DEFAULT_HISTORY = os.path.join(REPO_ROOT, "PERF_HISTORY.json")
-GOLDEN_LEDGER = os.path.join(REPO_ROOT, "tests", "goldens",
-                             "LEDGER_flagship.json")
 
 
 def _load_json(path: str) -> dict:
@@ -101,15 +99,6 @@ def cmd_ingest(args) -> int:
             history.fold_tile(doc, _load_json(args.tile), args.label,
                               source=os.path.basename(args.tile),
                               force=args.force)
-        if args.plan:
-            history.fold_plan(doc, _load_json(args.plan), args.label,
-                              source=os.path.basename(args.plan),
-                              force=args.force)
-        if args.autotune:
-            history.fold_autotune(doc, _load_json(args.autotune),
-                                  args.label,
-                                  source=os.path.basename(args.autotune),
-                                  force=args.force)
         for path in args.ledger or []:
             history.fold_ledger(doc, _load_json(path), args.label,
                                 source=os.path.basename(path),
@@ -480,77 +469,6 @@ def selftest() -> int:
               "counted as a regression", file=sys.stderr)
         return 1
 
-    # plan|autotune folding: same shared staleness policy (a CPU sweep =
-    # stale with keys), a best-variant walltime regression flips the
-    # gate, and a plan-hit-rate DROP (registry coverage lost) flips too
-    history.fold_plan(
-        serve_doc,
-        {"rc": 0, "parsed": {"backend": "cpu", "best_wall_s": 0.5,
-                             "plan_hit_rate": 1.0}}, "r01")
-    plan_points = serve_doc["entries"]["plan|autotune"]["points"]
-    if not plan_points[0].get("stale") or "best_wall_s" not in \
-            plan_points[0]["metrics"]:
-        print("perf_history selftest FAILED: CPU plan point must be "
-              "stale WITH metric keys", file=sys.stderr)
-        return 1
-    history.fold_plan(
-        serve_doc,
-        {"rc": 0, "parsed": {"backend": "tpu", "best_wall_s": 0.4,
-                             "default_wall_s": 0.5,
-                             "plan_hit_rate": 1.0}}, "r02")
-    history.fold_plan(
-        serve_doc,
-        {"rc": 0, "parsed": {"backend": "tpu", "best_wall_s": 0.6,
-                             "default_wall_s": 0.5,
-                             "plan_hit_rate": 0.5}}, "r03")
-    plv = history.trend_verdict(serve_doc)
-    missing_plan = [
-        needle for needle in
-        ("plan|autotune: best_wall_s 0.4", "plan|autotune: plan_hit_rate 1.0")
-        if not any(needle in line for line in plv["decision"]["regressed"])
-    ]
-    if plv["decision"]["ok"] or missing_plan:
-        print(f"perf_history selftest FAILED: plan|autotune regressions "
-              f"undetected: {missing_plan}", file=sys.stderr)
-        render(plv, out=sys.stderr)
-        return 1
-
-    # plan|sweep folding (the fold-surface autotuner): same policy —
-    # CPU rounds land STALE with keys, an on-chip fold-step walltime
-    # regression or a hit-rate drop flips the gate
-    sweep_doc = history.new_history()
-    history.fold_autotune(
-        sweep_doc,
-        {"rc": 0, "parsed": {"backend": "cpu", "best_wall_s": 0.02,
-                             "plan_hit_rate": 1.0}}, "r01")
-    sweep_points = sweep_doc["entries"]["plan|sweep"]["points"]
-    if not sweep_points[0].get("stale") or "best_wall_s" not in \
-            sweep_points[0]["metrics"]:
-        print("perf_history selftest FAILED: CPU fold-sweep point must "
-              "be stale WITH metric keys", file=sys.stderr)
-        return 1
-    history.fold_autotune(
-        sweep_doc,
-        {"rc": 0, "parsed": {"backend": "tpu", "best_wall_s": 0.010,
-                             "default_wall_s": 0.015,
-                             "plan_hit_rate": 1.0}}, "r02")
-    history.fold_autotune(
-        sweep_doc,
-        {"rc": 0, "parsed": {"backend": "tpu", "best_wall_s": 0.014,
-                             "default_wall_s": 0.015,
-                             "plan_hit_rate": 0.5}}, "r03")
-    swv = history.trend_verdict(sweep_doc)
-    missing_sweep = [
-        needle for needle in
-        ("plan|sweep: best_wall_s 0.01", "plan|sweep: plan_hit_rate 1.0")
-        if not any(needle in line for line in swv["decision"]["regressed"])
-    ]
-    if swv["decision"]["ok"] or missing_sweep:
-        print(f"perf_history selftest FAILED: plan|sweep regressions "
-              f"undetected: {missing_sweep}", file=sys.stderr)
-        render(swv, out=sys.stderr)
-        return 1
-
     # append-only: reusing a label without force must refuse
     try:
         history.fold_bench(
@@ -633,14 +551,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                        help="ab_tile snapshot JSON (scripts/ab_tile.py "
                        "--json output) -> the tile|quant trend entry "
                        "(quantized tile tier: throughput + drift)")
-    p_ing.add_argument("--plan", default=None,
-                       help="autotune snapshot JSON (scripts/autotune.py "
-                       "--json output) -> the plan|autotune trend entry "
-                       "(best-variant walltime + plan hit rate)")
-    p_ing.add_argument("--autotune", default=None,
-                       help="fold-surface sweep JSON (scripts/autotune.py "
-                       "--surface fold --json output) -> the plan|sweep "
-                       "trend entry (fold-step walltime A/B + hit rate)")
     p_ing.add_argument("--ledger", action="append", default=None,
                        help="per-run ledger JSON (repeatable)")
     p_ing.add_argument("--force", action="store_true",

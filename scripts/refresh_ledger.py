@@ -1,5 +1,12 @@
 #!/usr/bin/env python
-"""Regenerate the golden flagship perf ledger (tests/goldens/).
+"""Build the flagship perf ledger, and keep a local golden of it.
+
+No golden is committed: one that pins jaxpr primitive counts is broken by
+every PR on the dilated path and so guards nothing (ROADMAP D5, D14).
+``build_golden_ledger`` is what ``tests/test_ledger.py`` reads the
+round-6 / ring / fold signals from; ``regenerate`` writes, checks or
+refuses to overwrite a golden a builder keeps for themselves
+(``tests/goldens/`` is ignored by git).
 
     JAX_PLATFORMS=cpu python scripts/refresh_ledger.py            # refuse on regressions
     JAX_PLATFORMS=cpu python scripts/refresh_ledger.py --force    # overwrite anyway
@@ -21,9 +28,9 @@ the perf subsystem captures (``gigapath_tpu.obs.ledger``) —
   N=256: full profile including XLA cost/memory analysis.
 
 Everything is captured deterministically on CPU (``JAX_PLATFORMS=cpu``,
-same virtual-device flags as tests/conftest.py), so the tier-1 test
-``tests/test_ledger.py`` can regenerate it and pin drift with
-``scripts/ledger_diff.py`` on any machine without a chip.
+same virtual-device flags as tests/conftest.py), so
+``tests/test_ledger.py`` can build it, and ``scripts/ledger_diff.py``
+diff two of them, on any machine without a chip.
 
 Refusal contract: if regenerating would REGRESS any golden metric
 (``ledger_diff`` verdict not ok), the script refuses to overwrite and
@@ -38,8 +45,8 @@ import os
 import sys
 from typing import List, Optional
 
-# Mirror tests/conftest.py exactly: goldens must be regenerable from the
-# test environment byte-for-byte.
+# Mirror tests/conftest.py exactly: the ledger must be regenerable from
+# the test environment byte-for-byte.
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -50,6 +57,7 @@ if "xla_force_host_platform_device_count" not in _flags:
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
 
+# where a builder's own golden goes; none is committed
 GOLDEN_PATH = os.path.join(REPO_ROOT, "tests", "goldens", "LEDGER_flagship.json")
 
 # flagship LongNet schedule (models/longnet_config.py flagship_geometry)
@@ -273,14 +281,16 @@ def regenerate(golden_path: str = GOLDEN_PATH, *, force: bool = False,
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python scripts/refresh_ledger.py",
-        description="Regenerate tests/goldens/LEDGER_flagship.json",
+        description="Write or check a local golden of the flagship ledger "
+                    "(none is committed)",
     )
     ap.add_argument("--force", action="store_true",
                     help="overwrite even when metrics regressed")
     ap.add_argument("--check", action="store_true",
                     help="diff against the golden, write nothing")
     ap.add_argument("--out", default=GOLDEN_PATH,
-                    help="golden path (default: tests/goldens/LEDGER_flagship.json)")
+                    help="golden path (default: tests/goldens/"
+                    "LEDGER_flagship.json, ignored by git)")
     args = ap.parse_args(argv)
     return regenerate(args.out, force=args.force, check=args.check)
 

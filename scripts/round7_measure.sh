@@ -103,34 +103,6 @@ timeout 2400 python scripts/ab_tile.py --variants bf16,int8 \
   --json AB_TILE.json > /tmp/r7_tile.log 2>&1
 tail -4 /tmp/r7_tile.log
 
-# 11. geometry autotuner (ROADMAP item 5): sweep dispatch variants x
-#     Pallas block sizes at the flagship geometry on the chip — the
-#     eqn/temp/peak-bytes gates run as always, and these are the
-#     MEASURED rows the walltime adopt gate (>= 3% over default) exists
-#     for. --bless writes the winner into PLAN_REGISTRY.json as the
-#     geometry's blessed ExecutionPlan under the 'dilated_attention'
-#     key (autotune's default --name: the PRODUCTION dispatcher's
-#     resolution name — the model path resolves once there and threads
-#     the flags down, so a plan blessed under any other name would
-#     never be consulted). The adopt_plan decision table lands in
-#     AUTOTUNE.json; the ingest below folds the plan|autotune trend
-#     entry: best-variant walltime down-good, hit-rate up-good.
-timeout 2400 python scripts/autotune.py --n 10241 --iters 12 \
-  --label r07 --bless --json AUTOTUNE.json > /tmp/r7_autotune.log 2>&1
-tail -6 /tmp/r7_autotune.log
-
-# 11b. fold-surface autotuner (streaming-fold Pallas tier): A/B the jnp
-#     fold against the Pallas pair_partial kernels x fold block sizes
-#     at the 16k smoke chunk geometry. Same gate discipline; the
-#     winner lands under the streaming session's 'stream_fold' resolve
-#     key (resolved ONCE per session construction). The decision table
-#     lands in AUTOTUNE_FOLD.json; the ingest folds the plan|sweep
-#     trend entry: fold-step walltime down-good, hit-rate up-good.
-timeout 2400 python scripts/autotune.py --surface fold --chunk 2048 \
-  --valid 16384 --segments 2048,16384 --ratios 1,2 --iters 12 \
-  --label r07 --bless --json AUTOTUNE_FOLD.json > /tmp/r7_fold.log 2>&1
-tail -6 /tmp/r7_fold.log
-
 # 12. model-health loop (drift sentinel + anytime confidence): baseline
 #     sketch off the streaming path, clean re-serve (zero embedding_drift
 #     anomalies), chaos-shifted serve (EXACTLY ONE, with flight dump) —
@@ -145,5 +117,5 @@ tail -3 /tmp/r7_drift.log
 python scripts/perf_history.py ingest --label r07 --serve SERVE_SMOKE.json \
   --dist DIST_SMOKE.json --fleet FLEET_SMOKE.json \
   --prefill PREFILL_SMOKE.json \
-  --tile AB_TILE.json --plan AUTOTUNE.json --autotune AUTOTUNE_FOLD.json \
+  --tile AB_TILE.json \
   --drift DRIFT_SMOKE.json || true

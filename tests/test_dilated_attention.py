@@ -1622,3 +1622,135 @@ def test_combine_partials_matches_joint_softmax(rng):
     o_c, l_c = combine_partials(o_a.astype(jnp.float32), l_a, o_b, l_b)
     np.testing.assert_allclose(np.asarray(o_c), np.asarray(o_full), atol=1e-5)
     np.testing.assert_allclose(np.asarray(l_c), np.asarray(l_full), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the dispatch switches: an explicit flags= argument, else ONE read of the
+# environment per public call (ops/pallas_dilated.snapshot_flags)
+# ---------------------------------------------------------------------------
+
+from gigapath_tpu.ops.pallas_dilated import (  # noqa: E402
+    FLAG_ENV,
+    PipelineFlags,
+    snapshot_flags,
+)
+
+_SEGS, _RATIOS = [16, 32], [1, 2]
+
+
+@pytest.fixture
+def no_switches(monkeypatch):
+    for name in FLAG_ENV.values():
+        monkeypatch.delenv(name, raising=False)
+
+
+@pytest.fixture
+def snapshots(monkeypatch):
+    """The calls of ``snapshot_flags`` made while the fixture is live."""
+    import gigapath_tpu.ops.pallas_dilated as pd
+
+    calls = []
+    real = pd.snapshot_flags
+    monkeypatch.setattr(pd, "snapshot_flags", lambda: calls.append(1) or real())
+    return calls
+
+
+def test_no_environment_gives_the_default_carrier(no_switches):
+    assert snapshot_flags() == PipelineFlags()
+
+
+@pytest.mark.parametrize("field", list(FLAG_ENV))
+def test_env_twin_sets_its_field_and_no_other(no_switches, monkeypatch, field):
+    default = PipelineFlags()
+    for off in ("", "0"):
+        monkeypatch.setenv(FLAG_ENV[field], off)
+        assert snapshot_flags() == default, off
+    raw, value = ("256", 256) if "block" in field else ("1", True)
+    monkeypatch.setenv(FLAG_ENV[field], raw)
+    assert snapshot_flags() == default._replace(**{field: value})
+
+
+def test_explicit_flags_pin_dispatch(no_switches, monkeypatch, rng):
+    """An explicit ``flags=`` wins over the environment: the trace it gives
+    is the one the same carrier gives with no environment at all."""
+    from gigapath_tpu.ops.dilated_attention import dilated_attention_fused
+
+    q = jnp.asarray(rng.normal(size=(1, 64, 4, 8)), jnp.float32)
+
+    def trace(flags):
+        return str(jax.make_jaxpr(lambda a: dilated_attention_fused(
+            a, a, a, _SEGS, _RATIOS, interpret=True, flags=flags))(q))
+
+    pinned = trace(PipelineFlags())
+    from_env_off = trace(None)
+    monkeypatch.setenv(FLAG_ENV["stream_fusion"], "1")
+    assert trace(PipelineFlags()) == pinned
+    assert trace(None) != from_env_off  # the environment does reach flags=None
+
+
+def test_resolution_determinism_zero_retraces(no_switches, rng):
+    """Same environment -> equal carriers -> one jit cache entry across a
+    loop that snapshots once per call."""
+    import functools
+
+    from gigapath_tpu.ops.dilated_attention import dilated_attention_fused
+
+    q = jnp.asarray(rng.normal(size=(1, 64, 4, 8)), jnp.float32)
+
+    @functools.partial(jax.jit, static_argnums=(1,))
+    def step(a, flags):
+        return dilated_attention_fused(
+            a, a, a, _SEGS, _RATIOS, interpret=True, flags=flags)
+
+    for _ in range(2):
+        step(q, snapshot_flags()).block_until_ready()
+    assert step._cache_size() == 1
+
+
+def _call_dilated_attention(q, flags):
+    return dilated_attention(q, q, q, _SEGS, _RATIOS, flags=flags)
+
+
+def _call_fused(q, flags):
+    from gigapath_tpu.ops.dilated_attention import dilated_attention_fused
+
+    return dilated_attention_fused(
+        q, q, q, _SEGS, _RATIOS, interpret=True, flags=flags)
+
+
+def _call_branch(q, flags):
+    from gigapath_tpu.ops.pallas_dilated import dilated_branch_attention
+
+    B, L, H, Dh = q.shape
+    x = q.reshape(B, L, H * Dh)
+    return dilated_branch_attention(x, x, x, 32, 2, H, interpret=True, flags=flags)
+
+
+def _call_stream_fused(q, flags):
+    from gigapath_tpu.ops.pallas_dilated import dilated_attention_stream_fused
+
+    B, L, H, Dh = q.shape
+    x = q.reshape(B, L, H * Dh)
+    return dilated_attention_stream_fused(
+        x, x, x, _SEGS, _RATIOS, H, interpret=True, flags=flags)
+
+
+@pytest.mark.parametrize("call", [
+    _call_dilated_attention, _call_fused, _call_branch, _call_stream_fused,
+], ids=["dilated_attention", "dilated_attention_fused",
+        "dilated_branch_attention", "dilated_attention_stream_fused"])
+def test_one_snapshot_per_public_call(no_switches, monkeypatch, snapshots, call):
+    """``flags=None`` reads the environment exactly once, however many
+    branches and inner public ops the call goes through; an explicit
+    ``flags=`` never reads it. The device gate answers "TPU" (interpret mode)
+    so that ``dilated_attention`` walks dispatcher -> fused -> branches."""
+    import gigapath_tpu.ops.flash_attention as fa
+    from jax.experimental.pallas import tpu as pltpu
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    q = jax.ShapeDtypeStruct((1, 64, 4, 8), jnp.float32)
+    with pltpu.force_tpu_interpret_mode():
+        jax.eval_shape(lambda a: call(a, None), q)
+        assert len(snapshots) == 1
+        jax.eval_shape(lambda a: call(a, PipelineFlags()), q)
+        assert len(snapshots) == 1

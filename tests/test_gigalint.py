@@ -147,11 +147,11 @@ def test_fixture_specific_findings():
         ("GL016", "lowprec.py", "pack_activations"),
         ("GL016", "lowprec.py", "fp8_by_hand"),
         ("GL016", "lowprec.py", "stage_buffer"),
-        # kernel-dispatch flag reads outside snapshot_flags / the plan
-        # package (the fixture's own plan/resolve.py twin is the
-        # path-segment negative control; dispatch.py::snapshot_flags is
-        # the function-name negative control; host flags + dynamic
-        # names stay out of scope)
+        # kernel-dispatch flag reads outside snapshot_flags
+        # (dispatch.py::snapshot_flags is the function-name negative
+        # control; host flags — models/host_flags.py holds the quant
+        # tier and the chunked-prefill default — and dynamic names
+        # stay out of scope)
         ("GL017", "dispatch.py", "read_variant_flag_by_hand"),
         ("GL017", "dispatch.py", "block_override_by_hand"),
         ("GL017", "dispatch.py", "helper_env_flag_read"),

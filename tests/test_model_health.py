@@ -22,7 +22,6 @@ Four surfaces, each pinned both ways:
 """
 
 import json
-import re
 
 import jax
 import jax.numpy as jnp
@@ -95,9 +94,9 @@ class TestNumericsFlag:
 
     def test_flag_off_hlo_byte_identical(self):
         """numerics_on=False must lower to the same PROGRAM as a build
-        without the branch at all. Only op source locations may differ
-        (`metadata={...}` spans) — the ops, layouts and schedule must
-        be byte-equal."""
+        without the branch at all: the lowered text without debug info
+        (no source locations, no enclosing-function names) is
+        byte-equal."""
 
         def loss_fn(params, x):
             h = x @ params["encoder"]["w"] + params["encoder"]["b"]
@@ -113,8 +112,7 @@ class TestNumericsFlag:
         args = (_toy_params(), jnp.ones((3, 4)))
 
         def hlo(fn):
-            text = fn.lower(*args).compile().as_text()
-            return re.sub(r", metadata={[^}]*}", "", text)
+            return fn.lower(*args).as_text()
 
         reference = hlo(step)
         assert hlo(_make_step(False)) == reference

@@ -1,5 +1,5 @@
-"""Pallas streaming-fold tier (ops/pallas_streaming.py) + its plan
-integration.
+"""Pallas streaming-fold tier (ops/pallas_streaming.py) + its dispatch
+switches.
 
 The contracts this file pins (ISSUE 20 acceptance):
 
@@ -12,11 +12,11 @@ The contracts this file pins (ISSUE 20 acceptance):
   underflow — so row comparisons gate on coverage);
 - out-of-order chunk delivery is BIT-exact vs in-order under the Pallas
   path, including the bf16 fused result (deterministic fold sequence);
-- flag/plan on-vs-off produce DISTINCT jit cache keys (flags ride the
+- flags on-vs-off produce DISTINCT jit cache keys (flags ride the
   fold executable as a static arg);
-- empty plan registry + zero env flags -> the plan-resolved fold traces
-  the byte-identical program the pre-plan jnp path traces;
-- the streaming session resolves its fold plan ONCE at construction —
+- zero env flags -> the snapshot's fold traces the byte-identical
+  program the plain jnp path (``flags=None``) traces;
+- the streaming session reads the environment ONCE at construction —
   never per chunk or per fold.
 """
 
@@ -45,14 +45,6 @@ from gigapath_tpu.ops.streaming_prefill import (
     pair_partial_attention,
     streaming_dilated_attention,
 )
-from gigapath_tpu.plan import (
-    ExecutionPlan,
-    bless_plan,
-    plan_stats,
-    reset_plan_state,
-    resolve_plan,
-)
-
 PALLAS = PipelineFlags(fold_pallas=True)
 
 # covered-row threshold: a real lse is O(logits) ~ O(10); both tiers'
@@ -73,17 +65,11 @@ def _pallas_interpret_mode():
 
 
 @pytest.fixture
-def clean_env(monkeypatch, tmp_path):
-    """Zero kernel env flags + a private registry path (mirrors
-    tests/test_plan.py — the fold plan tests must never see a real
-    registry or a user's env flags)."""
-    for name in list(FLAG_ENV.values()) + ["GIGAPATH_PLAN"]:
+def clean_env(monkeypatch):
+    """Zero kernel env flags (the dispatch tests must never see a
+    user's)."""
+    for name in FLAG_ENV.values():
         monkeypatch.delenv(name, raising=False)
-    registry = str(tmp_path / "PLAN_REGISTRY.json")
-    monkeypatch.setenv("GIGAPATH_PLAN_REGISTRY", registry)
-    reset_plan_state()
-    yield registry
-    reset_plan_state()
 
 
 def _blk(rng, B, c, H, Dh, dtype=jnp.float32):
@@ -237,21 +223,23 @@ class TestDeterminism:
             assert np.array_equal(a, b), f"chunk {i} not bit-exact"
 
 
-class TestPlanIntegration:
+class TestDispatchSwitches:
     def test_fold_blocks_precedence(self):
-        # per-branch-class entry > scalar flag > module default
-        flags = PipelineFlags(
-            fold_pallas=True, fold_block_q=512,
-            fold_branches=((2048, 2, 256, 128), (1024, 1, 0, 384)),
-        )
-        assert fold_blocks(flags, 2048, 2) == (256, 128)
-        # zero entry fields fall through to the scalar flag / default
-        assert fold_blocks(flags, 1024, 1) == (512, 384)
-        # no matching entry: scalar flag, then default
-        assert fold_blocks(flags, 4096, 4) == (512, DEFAULT_FOLD_BLOCK)
-        assert fold_blocks(PipelineFlags(), 64, 1) == (
+        # the carrier's field where set, else the module default
+        flags = PipelineFlags(fold_pallas=True, fold_block_q=512)
+        assert fold_blocks(flags) == (512, DEFAULT_FOLD_BLOCK)
+        assert fold_blocks(flags._replace(fold_block_k=384)) == (512, 384)
+        assert fold_blocks(PipelineFlags()) == (
             DEFAULT_FOLD_BLOCK, DEFAULT_FOLD_BLOCK,
         )
+
+    def test_env_twin_sets_the_fold_block(self, clean_env, monkeypatch):
+        # an explicit =0 keeps the tier off; the block twin is honoured
+        monkeypatch.setenv(FLAG_ENV["fold_pallas"], "0")
+        monkeypatch.setenv(FLAG_ENV["fold_block_q"], "64")
+        snap = snapshot_flags()
+        assert not snap.fold_pallas
+        assert fold_blocks(snap) == (64, DEFAULT_FOLD_BLOCK)
 
     def test_flag_on_vs_off_distinct_jit_keys(self, clean_env):
         rng = np.random.default_rng(4)
@@ -266,6 +254,7 @@ class TestPlanIntegration:
         jfold(*args, segment_len=64, ratio=1, flags=None)
         jfold(*args, segment_len=64, ratio=1, flags=None)
         base = jfold._cache_size()
+        # an explicit flags= re-keys
         jfold(*args, segment_len=64, ratio=1, flags=PALLAS)
         assert jfold._cache_size() > base  # the DISTINCT key
         grown = jfold._cache_size()
@@ -274,32 +263,16 @@ class TestPlanIntegration:
         jfold(*args, segment_len=64, ratio=1, flags=PALLAS)
         assert jfold._cache_size() == grown
 
-        # ... and a BLESSED plan alone (zero env flags) re-keys too
-        bless_plan(
-            "stream_fold|x", ExecutionPlan(fold_pallas=True).as_dict(),
-            path=clean_env,
-        )
-        reset_plan_state()
-        resolved = resolve_plan(
-            "stream_fold",
-            (jax.ShapeDtypeStruct(q.shape, q.dtype),) * 3,
-        )
-        # wrong geometry key on purpose -> no hit -> default flags
-        assert resolved == snapshot_flags()
-
-    def test_empty_registry_is_byte_identical_to_jnp_path(self, clean_env):
-        """The parity-oracle guarantee: with an empty registry and no
-        env flags, plan-resolved dispatch traces the very program the
-        pre-plan jnp fold traces — compared as jaxpr text, not
-        numerics."""
+    def test_no_environment_traces_the_jnp_fold(self, clean_env):
+        """The parity-oracle guarantee: with no env flags, the
+        snapshot's dispatch traces the very program the plain jnp fold
+        traces — compared as jaxpr text, not numerics."""
         rng = np.random.default_rng(5)
         q = _blk(rng, 1, 64, 4, 8)
         acc_o = jnp.zeros((1, 64, 4, 8), jnp.float32)
         acc_l = jnp.full((1, 4, 64), NEG_INF, jnp.float32)
-        resolved = resolve_plan(
-            "stream_fold", (jax.ShapeDtypeStruct(q.shape, q.dtype),) * 3
-        )
-        assert resolved == PipelineFlags()
+        snap = snapshot_flags()
+        assert snap == PipelineFlags()
 
         def trace(flags):
             return str(jax.make_jaxpr(
@@ -308,11 +281,22 @@ class TestPlanIntegration:
             )(acc_o, acc_l, q, q, q,
               jnp.int32(0), jnp.int32(0), jnp.int32(64)))
 
-        assert trace(None) == trace(resolved)
+        assert trace(None) == trace(snap)
 
-    def test_session_resolves_plan_once(self, clean_env):
-        """The satellite pin: ONE resolve_plan per session construction
-        — feeding every chunk and finalizing adds zero lookups."""
+    @pytest.mark.parametrize("twin,carrier", [
+        (None, PipelineFlags()),
+        ("fold_block_q", PipelineFlags(fold_block_q=64)),
+    ], ids=["no_environment", "fold_block_q_64"])
+    def test_session_snapshots_once(self, clean_env, monkeypatch, twin,
+                                    carrier):
+        """ONE snapshot_flags() per session construction, whatever the
+        environment holds — feeding every chunk and finalizing adds
+        zero reads."""
+        import gigapath_tpu.ops.pallas_dilated as pd
+
+        if twin:
+            monkeypatch.setenv(FLAG_ENV[twin], "64")
+
         rng = np.random.default_rng(6)
         model = LongNetViT(
             in_chans=16, embed_dim=32, depth=1, slide_ngrids=100,
@@ -329,13 +313,16 @@ class TestPlanIntegration:
             rng.uniform(0, 100 * 256, (1, n, 2)), jnp.float32
         )
         params = model.init(jax.random.PRNGKey(0), x, coords)["params"]
-        reset_plan_state()  # init ran the dense path's own resolves
+        reads = []
+        real = pd.snapshot_flags
+        monkeypatch.setattr(
+            pd, "snapshot_flags", lambda: reads.append(1) or real()
+        )  # after init: the dense path took its own snapshots there
         session = StreamingEncoderSession(model, params, n, chunk_tiles=8)
-        stats = plan_stats()
-        assert stats["lookups"] == 1, stats
-        assert session.fold_flags == PipelineFlags()
+        assert len(reads) == 1
+        assert session.fold_flags == carrier
         xn, cn = np.asarray(x[0]), np.asarray(coords[0])
         for i, (a, b) in enumerate(session.tile_bounds):
             session.feed(i, xn[a:b], cn[a:b])
         session.finalize()
-        assert plan_stats()["lookups"] == 1  # still the ONE resolve
+        assert len(reads) == 1  # still the ONE read

@@ -301,13 +301,10 @@ class TestNonFiniteGuard:
 
     def test_guard_off_hlo_byte_identical(self):
         """The guard is a host-side CONSTRUCTION choice: guard=False
-        compiles to byte-identical HLO vs the pre-guard step. The one
-        normalization: ``metadata={...}`` spans (op source_file/line —
-        the step body physically moved into ``_make_train_step``, so
-        location metadata necessarily differs while the PROGRAM — ops,
-        layouts, schedule — must not)."""
-        import re
-
+        lowers to the byte-identical program vs the pre-guard step.
+        Compared without debug info: the step body physically moved
+        into ``_make_train_step``, so source locations and enclosing
+        function names necessarily differ while the PROGRAM must not."""
         import optax
 
         from gigapath_tpu.models.classification_head import get_model
@@ -321,8 +318,8 @@ class TestNonFiniteGuard:
         tx = optax.adamw(1e-3)
         opt_state = tx.init(params)
 
-        # the pre-PR step body, verbatim (named `step` so even the HLO
-        # metadata matches — the comparison is BYTE equality)
+        # the pre-PR step body, verbatim (named `step`: the module is
+        # named after it — the comparison is BYTE equality)
         @jax.jit
         def step(params, opt_state, x, c, y, rng):
             def loss_fn(p):
@@ -344,8 +341,7 @@ class TestNonFiniteGuard:
         )
 
         def hlo(fn):
-            text = fn.lower(*args).compile().as_text()
-            return re.sub(r", metadata={[^}]*}", "", text)
+            return fn.lower(*args).as_text()
 
         reference = hlo(step)
         assert hlo(_make_train_step(model, tx, guard=False)) == reference

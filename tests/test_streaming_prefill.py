@@ -368,6 +368,64 @@ class TestServingSurface:
             )
 
 
+class TestChunkedPrefillDefault:
+    """``GIGAPATH_CHUNKED_PREFILL`` is read by the two drivers that pick
+    between the streaming loop and assemble-then-encode, and by nothing
+    below them."""
+
+    def test_inference_stream_default_follows_the_environment(self, monkeypatch):
+        from gigapath_tpu import inference
+        from gigapath_tpu.utils import compile_cache
+
+        seen = []
+        monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: None)
+        monkeypatch.setattr(inference, "load_model", lambda *a, **k: (None, None))
+        monkeypatch.setattr(inference, "run_inference",
+                            lambda *a, **k: seen.append(k["stream"]))
+        argv = ["--model_path", "m", "--feature_dir", "f", "--output_file", "o"]
+        monkeypatch.delenv("GIGAPATH_CHUNKED_PREFILL", raising=False)
+        inference.main(argv)
+        inference.main(argv + ["--stream"])
+        monkeypatch.setenv("GIGAPATH_CHUNKED_PREFILL", "1")
+        inference.main(argv)
+        monkeypatch.setenv("GIGAPATH_CHUNKED_PREFILL", "0")
+        inference.main(argv)
+        assert seen == [False, True, True, False]
+
+    def test_dist_consumer_default_follows_it_and_the_plan_wins(self, tmp_path,
+                                                                monkeypatch):
+        from gigapath_tpu.dist.pipeline import default_plan, run_slide_consumer
+        from gigapath_tpu.dist.worker import write_plan
+
+        class Reached(Exception):
+            pass
+
+        def streaming_builder(dim_out):
+            raise Reached  # the consumer chose the streaming loop
+
+        def consume(name, **plan_fields):
+            """Run a consumer nobody feeds: the streaming loop is refused at
+            its first step, the dense one times out waiting for chunks."""
+            plan = default_plan(n_tiles=16, chunk_tiles=8)
+            del plan["chunked_prefill"]
+            root = str(tmp_path / name)
+            write_plan(root, dict(plan, **plan_fields))
+            run_slide_consumer(root, streaming_builder=streaming_builder,
+                               deadline_s=0.2)
+
+        monkeypatch.delenv("GIGAPATH_CHUNKED_PREFILL", raising=False)
+        with pytest.raises(TimeoutError):
+            consume("unset")
+        monkeypatch.setenv("GIGAPATH_CHUNKED_PREFILL", "1")
+        with pytest.raises(Reached):
+            consume("env")
+        with pytest.raises(TimeoutError):  # the plan document wins
+            consume("plan_off", chunked_prefill=False)
+        monkeypatch.delenv("GIGAPATH_CHUNKED_PREFILL")
+        with pytest.raises(Reached):
+            consume("plan_on", chunked_prefill=True)
+
+
 @pytest.mark.slow
 def test_hundred_k_token_stream_smoke():
     """10^5-token ingest through the fold state (reduced width, like the

@@ -12,6 +12,7 @@ atol 1e-2 vs ``images/prov_normal_000_1.pt``) additionally needs the real
 environment — it runs whenever ``GIGAPATH_TILE_ENCODER_CKPT`` points at one.
 """
 
+import collections.abc
 import math
 import os
 
@@ -233,6 +234,71 @@ def test_vendored_timm_key_schema_maps_bijectively():
     )
     for path, shape in converted.items():
         assert tuple(flat[path]) == tuple(shape), (path, flat[path], shape)
+
+
+class _RecordingEnviron(collections.abc.MutableMapping):
+    """``os.environ`` with the names read through it written down."""
+
+    def __init__(self, real):
+        self.real, self.reads = real, []
+
+    def __getitem__(self, name):
+        self.reads.append(name)
+        return self.real[name]
+
+    def __setitem__(self, name, value):
+        self.real[name] = value
+
+    def __delitem__(self, name):
+        del self.real[name]
+
+    def __iter__(self):
+        return iter(self.real)
+
+    def __len__(self):
+        return len(self.real)
+
+
+class TestQuantTierRead:
+    """``create_tile_encoder`` is the one reader of ``GIGAPATH_QUANT_TILE`` /
+    ``GIGAPATH_QUANT_PALLAS``: once, host side, when neither kwarg is given."""
+
+    @pytest.fixture
+    def quant_reads(self, monkeypatch):
+        for name in ("GIGAPATH_QUANT_TILE", "GIGAPATH_QUANT_PALLAS"):
+            monkeypatch.delenv(name, raising=False)
+        env = _RecordingEnviron(os.environ)
+        monkeypatch.setattr(os, "environ", env)
+
+        def during(**kwargs):
+            """The quant names read while one factory call runs."""
+            start = len(env.reads)
+            create_tile_encoder("", "vit_tile_enc_test", **kwargs)
+            return [n for n in env.reads[start:] if n.startswith("GIGAPATH_QUANT")]
+
+        return during
+
+    def test_env_picks_the_tier(self, quant_reads, monkeypatch):
+        monkeypatch.setenv("GIGAPATH_QUANT_TILE", "1")  # an alias of int8
+        monkeypatch.setenv("GIGAPATH_QUANT_PALLAS", "1")
+        model, _ = create_tile_encoder("", "vit_tile_enc_test")
+        assert model.quant == "int8" and model.quant_pallas
+
+    def test_explicit_kwarg_pins_tier(self, quant_reads, monkeypatch):
+        monkeypatch.setenv("GIGAPATH_QUANT_TILE", "int8")
+        model, _ = create_tile_encoder("", "vit_tile_enc_test", quant="")
+        assert model.quant == ""
+
+    def test_no_env_no_kwarg_is_f32_oracle(self, quant_reads):
+        model, _ = create_tile_encoder("", "vit_tile_enc_test")
+        assert model.quant == "" and not model.quant_pallas
+
+    def test_one_read_per_construction_none_with_a_kwarg(self, quant_reads,
+                                                         monkeypatch):
+        assert quant_reads() == ["GIGAPATH_QUANT_TILE"]
+        monkeypatch.setenv("GIGAPATH_QUANT_TILE", "int8")
+        assert quant_reads() == ["GIGAPATH_QUANT_TILE", "GIGAPATH_QUANT_PALLAS"]
+        assert quant_reads(quant_pallas=False) == []  # an explicit kwarg: no read
 
 
 GOLDEN_CKPT = os.environ.get("GIGAPATH_TILE_ENCODER_CKPT", "")

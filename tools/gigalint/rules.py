@@ -1246,31 +1246,30 @@ def check_lowprec_casts(project: Project) -> List[Finding]:
 
 
 # ---------------------------------------------------------------------------
-# GL017 — kernel-dispatch env reads outside the plan-resolution seam
+# GL017 — kernel-dispatch env reads outside snapshot_flags
 # ---------------------------------------------------------------------------
 
-# A GIGAPATH_* variant/block flag read anywhere else in library code is
-# a second, unaudited dispatch decision: it bypasses the ONE resolution
-# the plan refactor established (env flags where set, the geometry's
-# blessed registry plan where not), so a blessed plan silently loses to
-# a stray read nobody sees — exactly the hand-rolled A/B matrix the
-# ExecutionPlan registry replaced. Reads are sanctioned only inside
-# ``snapshot_flags`` (the one flag-VALUE read, threaded everywhere as a
-# PipelineFlags snapshot) and the ``plan/`` package (the resolution
-# module itself — matched by path SEGMENT so the fixture tree can carry
-# its own plan/ twin as a negative control). Host-side flags
-# (GIGAPATH_OBS, GIGAPATH_SERVE_*, ...) are not this rule's business —
-# only the kernel-dispatch set below.
+# A read of one of the attention kernels' dispatch switches anywhere
+# else in library code is a second, unaudited dispatch decision: the
+# forward and backward of one call, or two branches of one op, could
+# see different values, and an explicit ``flags=`` argument would
+# silently lose to it. Reads are sanctioned only inside
+# ``snapshot_flags`` (the one read, threaded everywhere as a
+# PipelineFlags snapshot). Host-side flags (GIGAPATH_OBS,
+# GIGAPATH_SERVE_*, the tile encoder's GIGAPATH_QUANT_TILE, a driver's
+# GIGAPATH_CHUNKED_PREFILL, ...) are not this rule's business — only
+# the set below, the environment twins of PipelineFlags' fields
+# (ops/pallas_dilated.FLAG_ENV; tests/test_layering.py holds the two
+# equal).
 _GL017_FLAGS = frozenset({
     "GIGAPATH_PIPELINED_ATTN", "GIGAPATH_PIPELINED_BWD",
     "GIGAPATH_PIPE_BLOCK_K", "GIGAPATH_PIPE_BWD_BLOCK_K",
     "GIGAPATH_STREAM_FUSION",
     "GIGAPATH_STREAMING_FUSION", "GIGAPATH_RING_ATTN",
-    "GIGAPATH_CHUNKED_PREFILL", "GIGAPATH_QUANT_TILE",
-    "GIGAPATH_QUANT_PALLAS", "GIGAPATH_PLAN", "GIGAPATH_PLAN_REGISTRY",
+    "GIGAPATH_FOLD_PALLAS", "GIGAPATH_FOLD_BLOCK_Q",
+    "GIGAPATH_FOLD_BLOCK_K",
 })
 _GL017_SANCTIONED_FUNC = "snapshot_flags"
-_GL017_SANCTIONED_SEGMENT = "plan"
 _GL017_EXEMPT_SEGMENTS = frozenset({"scripts", "tests", "demo"})
 
 
@@ -1297,10 +1296,9 @@ def _gl017_read_flag(node: ast.Call) -> Optional[str]:
 @register(
     "GL017",
     "kernel-dispatch GIGAPATH_* variant/block flag read in library code "
-    "outside snapshot_flags / the plan-resolution module — dispatch is "
-    "resolved ONCE per call through gigapath_tpu/plan/resolve_plan (env "
-    "flags where set, the blessed registry plan where not); a stray read "
-    "silently bypasses blessed plans; scripts, tests and demos exempt",
+    "outside snapshot_flags — dispatch is an explicit flags= argument or "
+    "ONE snapshot of the environment per public call; a stray read lets "
+    "two halves of one call disagree; scripts, tests and demos exempt",
 )
 def check_dispatch_env_reads(project: Project) -> List[Finding]:
     findings: List[Finding] = []
@@ -1310,8 +1308,6 @@ def check_dispatch_env_reads(project: Project) -> List[Finding]:
             s in _GL017_EXEMPT_SEGMENTS for s in segments
         ):
             continue
-        if _GL017_SANCTIONED_SEGMENT in segments:
-            continue  # the plan-resolution package may read its flags
         spans = sorted(
             (
                 (fn.lineno, getattr(fn.node, "end_lineno", fn.lineno), fn)
@@ -1353,11 +1349,9 @@ def check_dispatch_env_reads(project: Project) -> List[Finding]:
             findings.append(Finding(
                 "GL017", mod.path, node.lineno, symbol,
                 f"kernel-dispatch env read {how} in library code: this "
-                "flag is resolved ONCE per public call through "
-                "gigapath_tpu/plan/resolve_plan (env where set, the "
-                "blessed registry plan where not) — take a PipelineFlags "
-                "snapshot / resolved plan from the caller instead of "
-                "re-reading the environment",
+                "flag is read ONCE per public call by snapshot_flags — "
+                "take the PipelineFlags snapshot from the caller instead "
+                "of re-reading the environment",
             ))
     return findings
 

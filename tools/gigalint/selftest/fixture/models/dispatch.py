@@ -1,7 +1,7 @@
 """Seeded GL017 violations: kernel-dispatch GIGAPATH_* flag reads in
-library code outside ``snapshot_flags`` / the plan-resolution module
-(the fixture's own plan/resolve.py twin is the negative control).
-Never 'fix' these — each is load-bearing for a self-test."""
+library code outside ``snapshot_flags`` (the fixture's own
+models/host_flags.py holds the out-of-scope host flags as the negative
+control). Never 'fix' these — each is load-bearing for a self-test."""
 
 import os
 
@@ -14,8 +14,8 @@ def env_flag(name):
 
 
 def read_variant_flag_by_hand():
-    # GL017: a variant flag read that bypasses the plan resolution —
-    # a blessed plan for this geometry silently loses to this read
+    # GL017: a variant flag read that bypasses the caller's snapshot —
+    # an explicit flags= argument silently loses to this read
     return os.environ.get("GIGAPATH_PIPELINED_ATTN", "") == "1"
 
 
@@ -31,8 +31,8 @@ def helper_env_flag_read():
 
 
 def subscript_read():
-    # GL017: a raw environ subscript on the quant-tier flag
-    return os.environ["GIGAPATH_QUANT_TILE"]
+    # GL017: a raw environ subscript on a fold-kernel flag
+    return os.environ["GIGAPATH_FOLD_PALLAS"]
 
 
 def snapshot_flags():
