@@ -39,7 +39,8 @@ def attention_with_lse(
     dropout_rate: float = 0.0,
     dropout_rng: Optional[jax.Array] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Softmax attention returning ``(out [B,Lq,H,D], lse [B,H,Lq])``.
+    """Softmax attention returning ``(out [B,Lq,H,Dv], lse [B,H,Lq])``;
+    ``Dv`` is ``v``'s last width, which need not be ``q`` and ``k``'s ``D``.
 
     Softmax statistics are accumulated in fp32 regardless of input dtype
     (bf16-safe); the output is cast back to the input dtype.

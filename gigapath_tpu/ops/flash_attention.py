@@ -40,10 +40,13 @@ def flash_attention(
     use_pallas: Optional[bool] = None,
     scale: Optional[float] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Attention on [B, L, H, D] returning ``(out [B,L,H,D], lse [B,H,L])``.
+    """Attention on [B, L, H, D] returning ``(out [B,L,H,Dv], lse [B,H,L])``.
 
     ``k`` / ``v`` may carry fewer heads than ``q`` (grouped KV heads: ``H_kv``
-    divides ``H``, query head ``h`` reads KV head ``h // (H / H_kv)``).
+    divides ``H``, query head ``h`` reads KV head ``h // (H / H_kv)``). ``v``
+    may have a width ``Dv`` other than ``q`` and ``k``'s ``D`` (latent
+    attention's 192-wide keys beside 128-wide values); ``out`` takes ``v``'s,
+    on both tiers, and the Pallas tier is then forward only.
     ``scale`` multiplies the logits; ``None`` is ``D ** -0.5``.
 
     ``kv_valid_len``: [B, H] valid-key counts (ragged tail masking). Static

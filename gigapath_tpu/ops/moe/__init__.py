@@ -1,4 +1,5 @@
 from gigapath_tpu.ops.moe.routing import (  # noqa: F401
+    GroupLimitedSigmoidGate,
     Top1Gate,
     Top2Gate,
     top1_gating,

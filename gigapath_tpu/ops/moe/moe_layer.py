@@ -23,7 +23,7 @@ combine, ``moe_layer.py:229-262``) and the same (output, l_aux) contract
 
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -210,11 +210,17 @@ class MOELayer(nn.Module):
 
 class DroplessMoE(nn.Module):
     """Dropless top-k expert layer over ``[S, M]`` tokens, for the experts
-    held here: ``g = u W_r`` in float32 over all ``num_experts``; the ``top_k``
-    largest; ``w = softmax`` over those values; ``sum_i w_i W2_e(silu(a) * b)``
-    with ``[a | b] = W1_e u``, summed over the choices whose expert is one of
-    ``[expert_offset, expert_offset + experts_held)``. The other choices are
-    another chip's part of the sum and are left out.
+    held here: ``g = u W_r`` in float32 over all ``num_experts``; the gate
+    picks ``top_k`` experts a token and weighs them; ``sum_i w_i W2_e(silu(a)
+    * b)`` with ``[a | b] = W1_e u``, summed over the choices whose expert is
+    one of ``[expert_offset, expert_offset + experts_held)``. The other
+    choices are another chip's part of the sum and are left out.
+
+    ``gate`` is the layer's to be given: ``gate(logits [S, E], top_k) ->
+    (weights [S, top_k] float32, experts [S, top_k] int32)``, hashable (a
+    function, or a value such as :class:`~gigapath_tpu.ops.moe.routing.
+    GroupLimitedSigmoidGate`). ``None`` is the ``top_k`` largest logits and a
+    softmax over them (:func:`~gigapath_tpu.ops.moe.routing.topk_softmax_gating`).
 
     Shapes are static, so the sorted buffer is sized for every choice landing
     here (``S * top_k`` rows), but only the rows a held expert owns are moved:
@@ -240,6 +246,7 @@ class DroplessMoE(nn.Module):
     top_k: int
     expert_offset: int = 0
     experts_held: Optional[int] = None
+    gate: Optional[Callable] = None
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
 
@@ -259,7 +266,7 @@ class DroplessMoE(nn.Module):
         w2 = self.param("w2", init, (held, self.ffn_dim, M), self.param_dtype)
 
         with jax.named_scope("router"):
-            weights, experts = topk_softmax_gating(logits, self.top_k)
+            weights, experts = (self.gate or topk_softmax_gating)(logits, self.top_k)
         with jax.named_scope("dispatch"):
             order, position, group_sizes = dispatch_to_held(
                 experts, expert_offset=self.expert_offset, experts_held=held)

@@ -20,6 +20,7 @@ cosine's own normalization and becomes a plain normalized matmul here.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -321,3 +322,32 @@ def topk_softmax_gating(logits: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.n
     [S, k] float32, experts [S, k] int32)``."""
     values, experts = jax.lax.top_k(logits.astype(jnp.float32), k)
     return jax.nn.softmax(values, axis=-1), experts.astype(jnp.int32)
+
+
+@dataclasses.dataclass(frozen=True)
+class GroupLimitedSigmoidGate:
+    """Dropless routing as DeepSeek-V3's ``Gate`` does it without a selection
+    bias: sigmoid scores ``[S, E]``; the experts lie in ``n_group`` equal
+    groups, a group scores what its best expert scores, and only the
+    ``topk_group`` best groups stay eligible; the ``k`` largest scores among
+    those; weights are the chosen *scores* over their sum, times ``scale``.
+    Called as :func:`topk_softmax_gating` is: ``gate(logits, k) -> (weights
+    [S, k] float32, experts [S, k] int32)``. Ties go to the lower index, in
+    the groups and in the experts. A value, so that two modules built alike
+    compare equal."""
+
+    n_group: int
+    topk_group: int
+    scale: float = 1.0
+
+    def __call__(self, logits: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        S, E = logits.shape
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        group_scores = scores.reshape(S, self.n_group, E // self.n_group).max(-1)
+        _, kept = jax.lax.top_k(group_scores, self.topk_group)
+        eligible = (kept[:, :, None] == jnp.arange(self.n_group)).any(1)        # [S, n_group]
+        eligible = jnp.repeat(eligible, E // self.n_group, axis=1)
+        # a sigmoid is positive, so -1 ranks below every eligible expert
+        values, experts = jax.lax.top_k(jnp.where(eligible, scores, -1.0), k)
+        weights = values / values.sum(-1, keepdims=True) * self.scale
+        return weights, experts.astype(jnp.int32)

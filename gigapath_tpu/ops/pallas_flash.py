@@ -355,10 +355,13 @@ def _fwd_impl(q, k, v, kv_lens, causal, scale, block_q, block_k, interpret):
     dilated attention need no batch-axis reshuffling. ``k`` / ``v`` may carry
     ``H / group`` heads (grouped KV heads): the K/V index map sends query head
     ``h`` to KV head ``h // group``, so a KV head is read from where it lies
-    and never repeated in memory.
+    and never repeated in memory. ``v`` may be narrower or wider than ``q`` and
+    ``k`` (latent attention: keys of 192 beside values of 128): the value
+    block, the output and the accumulator then take ``v``'s width, and nothing
+    is padded to the keys'.
     """
     B, H, S, Mq, D = q.shape
-    Mk = k.shape[3]
+    Mk, Dv = k.shape[3], v.shape[4]
     group = H // k.shape[1]
     block_q = min(block_q, _round_up(Mq, LANES))
     block_k = min(block_k, _round_up(Mk, LANES))
@@ -379,24 +382,29 @@ def _fwd_impl(q, k, v, kv_lens, causal, scale, block_q, block_k, interpret):
 
     q_spec = pl.BlockSpec((1, 1, 1, block_q, D), lambda b, h, s, i, j: (b, h, s, i, 0), memory_space=pltpu.VMEM)
     k_spec = pl.BlockSpec((1, 1, 1, block_k, D), kv_index, memory_space=pltpu.VMEM)
+    # the same spec objects where v is as wide as k: that program lowers to the text it always had
+    v_spec = k_spec if Dv == D else pl.BlockSpec(
+        (1, 1, 1, block_k, Dv), kv_index, memory_space=pltpu.VMEM)
+    o_spec = q_spec if Dv == D else pl.BlockSpec(
+        (1, 1, 1, block_q, Dv), lambda b, h, s, i, j: (b, h, s, i, 0), memory_space=pltpu.VMEM)
     kvlen_spec = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole (B,H,S) array; indexed by program_id
     with jax.named_scope("kernel_fwd"):
         out, lse = pl.pallas_call(
             kernel,
             grid=(B, H, S, nq, nk),
-            in_specs=[q_spec, k_spec, k_spec, kvlen_spec],
+            in_specs=[q_spec, k_spec, v_spec, kvlen_spec],
             out_specs=[
-                q_spec,
+                o_spec,
                 pl.BlockSpec((1, 1, 1, block_q, LANES), lambda b, h, s, i, j: (b, h, s, i, 0), memory_space=pltpu.VMEM),
             ],
             out_shape=[
-                jax.ShapeDtypeStruct((B, H, S, Mqp, D), q.dtype),
+                jax.ShapeDtypeStruct((B, H, S, Mqp, Dv), q.dtype),
                 jax.ShapeDtypeStruct((B, H, S, Mqp, LANES), jnp.float32),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_q, LANES), jnp.float32),
                 pltpu.VMEM((block_q, LANES), jnp.float32),
-                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, Dv), jnp.float32),
             ],
             interpret=interpret,
             name="flash_fwd",
@@ -677,6 +685,11 @@ def _flash_fwd_rule(kv_lens, causal, interpret, block_q, block_k, scale, q, k, v
 def _flash_bwd_rule(kv_lens, causal, interpret, block_q, block_k, scale, res, cotangents):
     q, k, v, out, lse = res
     do, _dlse = cotangents  # no gradient flows through the lse output
+    if v.shape[-1] != q.shape[-1]:
+        raise NotImplementedError(
+            "pallas flash attention: the backward kernels take one width for q, k and v; "
+            f"values of {v.shape[-1]} beside keys of {q.shape[-1]} are forward only "
+            "(differentiate the jnp tier, flash_attention(..., use_pallas=False))")
     delta = jnp.sum(
         do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     )  # [B, H, S, Mq]
@@ -723,7 +736,9 @@ def pallas_flash_attention(
     """Flash attention on [B, L, H, D] -> (out [B,L,H,D], lse [B,H,L]).
 
     ``k`` / ``v`` may be ``[B, L, H_kv, D]`` with ``H_kv`` dividing ``H``
-    (grouped KV heads). ``scale`` multiplies the logits (``None``: ``D ** -0.5``).
+    (grouped KV heads). ``v`` may be ``[B, L, H_kv, Dv]`` with a width of its
+    own, which is then ``out``'s (forward only). ``scale`` multiplies the
+    logits (``None``: ``D ** -0.5``, ``D`` the keys' width).
 
     ``kv_len``: optional static [B, H] array-like of per-(batch, head)
     valid key counts (trace-time constants — this wrapper's custom VJP

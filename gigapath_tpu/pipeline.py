@@ -148,8 +148,8 @@ def slide_forward_fn(slide_encoder_model):
 def lm_forward_fn(lm):
     """The jitted scoring forward ``(params, ids [B, L] int32, positions [B,
     P] int32) -> (logits [B, P, vocab] float32, tokens each held expert
-    received [layers, experts_held] int32)`` that
-    :func:`run_inference_with_lm` runs. The head runs on the rows
+    received [expert layers, experts_held] int32)`` that
+    :func:`run_inference_with_lm` runs, for any LM of the registry. The head runs on the rows
     ``positions`` names and on no other: all 16,384 rows of a long document
     would be 3.3 GB of logits a request. One function a model (flax modules
     hash by their fields), so a second call of the entry traces nothing."""
@@ -280,12 +280,13 @@ def run_inference_with_lm(
     lm_params=None,
 ) -> dict:
     """Score token ids with a causal LM of the registry (``granite_4_0_h_small``
-    is the one there is): ``token_ids [L]`` or ``[B, L]`` int, ``positions
-    [P]`` or ``[B, P]`` the rows whose next-token logits are wanted (the last
-    row where none is given). ``lm`` may be the ``(model, params)`` pair
-    ``models.granite_hybrid.create_lm`` returns. Returns ``{'logits' [B, P,
-    vocab] float32, 'positions' [B, P], 'expert_tokens' [layers,
-    experts_held]}``."""
+    of ``models/granite_hybrid.py``, ``axk1`` of ``models/axk1.py``; any module
+    with their contract, nothing here asks which): ``token_ids [L]`` or ``[B,
+    L]`` int, ``positions [P]`` or ``[B, P]`` the rows whose next-token logits
+    are wanted (the last row where none is given). ``lm`` may be the ``(model,
+    params)`` pair ``models.granite_hybrid.create_lm`` returns. Returns
+    ``{'logits' [B, P, vocab] float32, 'positions' [B, P], 'expert_tokens'
+    [expert layers, experts_held]}``."""
     if lm_params is None:
         lm, lm_params = lm
     ids = np.atleast_2d(np.asarray(token_ids)).astype(np.int32)

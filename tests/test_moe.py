@@ -349,3 +349,63 @@ class TestMoEEncoder:
         _, _, loss1 = step1(params, opt.init(params), batch, jax.random.PRNGKey(1))
         # no MoE layers in this model: weights agree (aux sum is 0)
         np.testing.assert_allclose(float(loss0), float(loss1), rtol=1e-6)
+
+
+class TestDroplessGate:
+    """``DroplessMoE`` is given its gate; left alone it is what it was."""
+
+    def _layer(self, **kw):
+        from gigapath_tpu.ops.moe import DroplessMoE
+
+        return DroplessMoE(64, 32, 8, 4, expert_offset=2, experts_held=4, **kw)
+
+    def test_softmax_gate_lowers_to_the_parents_text(self):
+        """No gate given: the top-k-then-softmax layer lowers to the text it
+        lowered to before it took one (sha256 from commit f77107b's tree)."""
+        import hashlib
+
+        layer = self._layer()
+        x = jax.ShapeDtypeStruct((40, 64), jnp.bfloat16)
+        params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+        text = jax.jit(layer.apply).lower(params, x).as_text()
+        assert hashlib.sha256(text.encode()).hexdigest() == (
+            "994ca85cc26e3a4d20a0d1a8b7f8ecb3fc57862e8a6853d36a5de9cbe4d4aae8")
+
+    @pytest.mark.parametrize("given", ["none", "the_function"])
+    def test_softmax_gate_gives_what_it_gave(self, rng, given):
+        from gigapath_tpu.ops.moe import topk_softmax_gating
+
+        layer = self._layer(dtype=jnp.float32, param_dtype=jnp.float32,
+                            **({} if given == "none" else {"gate": topk_softmax_gating}))
+        x = jnp.asarray(rng.normal(size=(40, 64)), jnp.float32)
+        params = layer.init(jax.random.PRNGKey(1), x)["params"]
+        got, received = layer.apply({"params": params}, x)
+        logits = np.asarray(x, np.float64) @ np.asarray(params["router"]["kernel"], np.float64)
+        want = np.zeros((40, 64))
+        for t in range(40):
+            top = np.argsort(-logits[t], kind="stable")[:4]
+            gates = np.exp(logits[t][top] - logits[t][top].max())
+            for e, g in zip(top, gates / gates.sum()):
+                if 2 <= e < 6:
+                    a, b = np.split(np.asarray(x[t], np.float64) @ np.asarray(params["w1"][e - 2], np.float64), 2)
+                    want[t] += g * ((a / (1 + np.exp(-a)) * b) @ np.asarray(params["w2"][e - 2], np.float64))
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+        assert int(received.sum()) == int(((np.argsort(-logits, kind="stable")[:, :4] >= 2)
+                                           & (np.argsort(-logits, kind="stable")[:, :4] < 6)).sum())
+
+    def test_a_given_gate_decides_choices_and_weights(self, rng):
+        from gigapath_tpu.ops.moe import GroupLimitedSigmoidGate
+
+        gate = GroupLimitedSigmoidGate(4, 2, 2.5)
+        layer = self._layer(gate=gate, dtype=jnp.float32, param_dtype=jnp.float32)
+        x = jnp.asarray(rng.normal(size=(40, 64)), jnp.float32)
+        params = layer.init(jax.random.PRNGKey(1), x)["params"]
+        (got, received), state = layer.apply({"params": params}, x, mutable=["intermediates"])
+        logits = x @ params["router"]["kernel"]
+        weights, experts = gate(logits, 4)
+        sowed = state["intermediates"]["moe_metadata"][0]
+        assert np.array_equal(sowed["experts"], experts)
+        assert received.tolist() == [int((np.asarray(experts) == e).sum()) for e in range(2, 6)]
+        assert float(sowed["held_rows_share"]) == pytest.approx(int(received.sum()) / 160)
+        plain = self._layer(dtype=jnp.float32, param_dtype=jnp.float32).apply({"params": params}, x)[0]
+        assert not np.allclose(got, plain, rtol=1e-2)

@@ -289,3 +289,70 @@ def test_equal_heads_default_scale_lowers_to_the_parents_text():
     text = jax.jit(lambda q, k, v: flash(q, k, v)).lower(q, q, q).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == (
         "03973aa7e43ea55861e1e390559dab264c31b7d950e17740d78379dac376b52c")
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("heads,kv_heads,d,dv", [(4, 4, 24, 16), (4, 2, 16, 32), (2, 2, 192, 128)])
+def test_value_width_of_its_own_matches_reference(rng, causal, heads, kv_heads, d, dv):
+    """Latent attention's core: keys of ``d`` beside values of ``dv`` (192 /
+    128 at A.X-K1's widths). The value block, the output and the accumulator
+    take ``dv``; nothing is padded to ``d``. L = 300 with blocks of 128, so a
+    causal run skips and clamps as it does at equal widths."""
+    L, scale = 300, 0.13
+    q = jnp.asarray(rng.normal(size=(2, L, heads, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(2, L, kv_heads, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(2, L, kv_heads, dv)), jnp.float32)
+    from gigapath_tpu.ops import pallas_flash as pf
+
+    q5, k5, v5 = (x.transpose(0, 2, 1, 3)[:, :, None] for x in (q, k, v))
+    out, lse = pf._fwd_impl(q5, k5, v5, None, causal, scale, 128, 128, True)
+    ref_out, ref_lse = attention_with_lse(q, k, v, is_causal=causal, scale=scale)  # the jnp tier
+    assert out.shape == (2, heads, 1, L, dv) and ref_out.shape == (2, L, heads, dv)
+    np.testing.assert_allclose(out[:, :, 0].transpose(0, 2, 1, 3), ref_out, atol=2e-5, rtol=1e-4)
+    np.testing.assert_allclose(lse[:, :, 0], ref_lse, atol=2e-5, rtol=1e-4)
+    out2, _ = flash(q, k, v, is_causal=causal, scale=scale)  # the public wrapper, default blocks
+    np.testing.assert_allclose(out2, ref_out, atol=2e-5, rtol=1e-4)
+    # by hand, one head and one row: softmax over the allowed keys, then the values
+    b, h, t = 1, heads - 1, L - 7
+    kh = h // (heads // kv_heads)
+    keys = slice(0, t + 1) if causal else slice(0, L)
+    s = np.asarray(q[b, t, h]) @ np.asarray(k[b, keys, kh]).T * scale
+    w = np.exp(s - s.max())
+    np.testing.assert_allclose(out2[b, t, h], (w / w.sum()) @ np.asarray(v[b, keys, kh]),
+                               atol=2e-5, rtol=1e-4)
+
+
+def test_default_scale_is_the_keys_width_and_both_tiers_take_the_values(rng):
+    from gigapath_tpu.ops.flash_attention import flash_attention
+
+    q, k = (jnp.asarray(rng.normal(size=(1, 128, 2, 24)), jnp.float32) for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(1, 128, 2, 8)), jnp.float32)
+    want, _ = attention_with_lse(q, k, v, is_causal=True, scale=24 ** -0.5)
+    got, _ = flash(q, k, v, is_causal=True)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-4)
+    jnp_tier, lse = flash_attention(q, k, v, is_causal=True, use_pallas=False)
+    assert jnp_tier.shape == (1, 128, 2, 8) and lse.shape == (1, 2, 128)
+    np.testing.assert_allclose(jnp_tier, want, atol=2e-5, rtol=1e-4)
+
+
+def test_unequal_widths_are_forward_only_on_the_kernel_tier(rng):
+    q, k = (jnp.asarray(rng.normal(size=(1, 128, 2, 24)), jnp.float32) for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(1, 128, 2, 8)), jnp.float32)
+    with pytest.raises(NotImplementedError, match="forward only"):
+        jax.grad(lambda q: flash(q, k, v)[0].sum())(q)
+    g = jax.grad(lambda q: attention_with_lse(q, k, v)[0].sum())(q)  # the jnp tier differentiates
+    assert g.shape == q.shape and np.isfinite(g).all()
+
+
+def test_equal_widths_grouped_causal_lowers_to_the_parents_text():
+    """Granite's side of the shared kernel: grouped KV heads, a causal mask
+    and a scale, v as wide as k. The wrapper lowers to the text it lowered to
+    before the value block got a width of its own (sha256 taken from commit
+    f77107b's tree, same JAX)."""
+    import hashlib
+
+    q = jax.ShapeDtypeStruct((1, 256, 4, 64), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.bfloat16)
+    text = jax.jit(lambda q, k, v: flash(q, k, v, is_causal=True, scale=0.1)).lower(q, k, k).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "49c4274addcbe72f4303efbe204ee5e57350c867ac4cf57dda8ed365bfdd64cc")

@@ -20,6 +20,7 @@ from benchmarks.lib import tables
 _TILE = tables.load("configs", "gigapath_tile_enc")["tiny"]
 _SLIDE = tables.load("configs", "gigapath_slide_enc12l768d")["tiny"]
 _LM = tables.load("configs", "granite4h_small_ep2")["tiny"]
+_AXK1 = tables.load("configs", "axk1_ep16")["tiny"]
 _N_TOKENS = 40  # + class token = 41: three 16-token and two 32-token segments
 
 
@@ -77,6 +78,20 @@ def _lm_forward(length=40, **widths):
     return pipeline.lm_forward_fn.__wrapped__(model), (params, ids, rows)
 
 
+def _axk1_forward(length=40, **widths):
+    from gigapath_tpu import pipeline
+    from gigapath_tpu.utils.registry import create_model_from_registry
+    import gigapath_tpu.models.axk1  # noqa: F401
+
+    model = create_model_from_registry(
+        _AXK1["arch"], depth=_AXK1["depth"], vocab_size=_AXK1["vocab_size"],
+        experts_held=_AXK1["n_routed_experts"], expert_offset=_AXK1["expert_offset"], **widths)
+    ids = jax.ShapeDtypeStruct((2, length), jnp.int32)
+    rows = jax.ShapeDtypeStruct((2, 4), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids, rows)["params"]
+    return pipeline.lm_forward_fn.__wrapped__(model), (params, ids, rows)
+
+
 def _on_kernels(monkeypatch_context, build):
     """``build()`` with the device gate answering "TPU" and every
     ``pallas_call`` in interpret mode: the slide encoder then takes the fused
@@ -124,6 +139,11 @@ def _lowered(path: str) -> str:
             # 128-lane lines (ops/moe/pallas_rows.fits), so the hidden width is 256
             return _on_kernels(mp, lambda: _text(*_lm_forward(
                 length=512, hidden_size=256, intermediate_size=128)))
+        if path == "axk1_jnp":
+            return _text(*_axk1_forward())
+        if path == "axk1_kernels":  # widths the grouped product and the row kernels take, as lm_kernels
+            return _on_kernels(mp, lambda: _text(*_axk1_forward(
+                length=512, hidden_size=256, moe_intermediate_size=128)))
         if path == "fused_grad":
             return _text(_grad_of(functools.partial(
                 da.dilated_attention_fused, segment_lengths=[16, 32],
@@ -196,6 +216,13 @@ _NAMES = {
     "lm_kernels": ["jit_lm_forward", "ssd_scan", "moe", "dispatch", "moe_dispatch", "experts",
                    "kernel_fwd", "gmm", "combine", "moe_combine", "attn_core", "flash_fwd",
                    "lm_head"],
+    "axk1_jnp": ["jit_lm_forward", "self_attn", "q_a_proj", "q_a_layernorm", "q_b_proj",
+                 "kv_a_proj_with_mqa", "kv_a_layernorm", "kv_b_proj", "rope", "attn_core",
+                 "kernel_fwd", "o_proj", "mlp", "moe", "router", "dispatch", "experts",
+                 "combine", "shared_experts", "lm_head"],
+    "axk1_kernels": ["jit_lm_forward", "rope", "attn_core", "kernel_fwd", "flash_fwd", "moe",
+                     "router", "dispatch", "moe_dispatch", "experts", "gmm", "combine",
+                     "moe_combine", "shared_experts", "lm_head"],
     "fused_grad": ["dilated_attn", "branch_r2", "pack", "kernel_fwd", "kernel_dq",
                    "kernel_dkv", "unpack", "merge", "dilated_pack", "dilated_fwd",
                    "dilated_dq", "dilated_dkv", "dilated_unpack"],
@@ -262,6 +289,29 @@ def test_the_expert_layer_holds_its_steps_in_order_of_the_path():
         assert re.search(rf'"{kernel}/', text), kernel
     assert not re.search(r'"[^"]*/moe/experts/[^"]*moe_(dispatch|combine)', text)
     assert not re.search(r"gmm[\w]*(dispatch|combine)", text)
+
+
+def test_latent_attention_holds_its_steps_in_order_of_the_path():
+    """``.../layers_<i>/self_attn/<projection | rope | attn_core>/...`` and the
+    expert layer's steps under ``moe`` (benchmarks/scopes/axk1.json matches on
+    these); layer 0 has the dense ``mlp`` and no ``moe``, the later layers the
+    reverse; every layer's core is one jitted function, so six layers share a
+    lowering."""
+    text = _lowered("axk1_kernels")
+    for step in ("q_a_proj", "q_a_layernorm", "q_b_proj", "kv_a_proj_with_mqa", "kv_a_layernorm",
+                 "kv_b_proj", "rope", "o_proj"):
+        assert re.search(rf'"[^"]*/layers_2/self_attn/{step}[/"]', text), step
+    assert "/layers_2/self_attn/attn_core/jit(_causal_core)" in text
+    assert re.search(r'"kernel_fwd/flash_fwd/', text)
+    assert len(re.findall(r"func.func private @_causal_core", text)) == 1
+    assert re.search(r'"[^"]*/layers_0/mlp/', text) and not re.search(r'"[^"]*/layers_0/moe/', text)
+    assert re.search(r'"[^"]*/layers_1/moe/router/', text)
+    assert not re.search(r'"[^"]*/layers_1/mlp/', text)
+    for step, kernel in (("dispatch", "jit(_dispatch_call)"), ("experts", "jit(gmm)"),
+                         ("combine", "jit(_combine_call)")):
+        assert f"/layers_1/moe/{step}/kernel_fwd/{kernel}" in text, step
+    assert re.search(r'"[^"]*/layers_1/shared_experts/', text)
+    assert re.search(r'"[^"]*/lm_head/lm_head/', text)
 
 
 # Equation counts of the parent commit (7f80832), every nested jaxpr counted,

@@ -348,3 +348,60 @@ def test_granite_layers_at_published_widths_hold_their_kernels(topo, one_chip, m
 
         assert 4 * pallas_rows.DISPATCH_ROWS * 8192 == 8 << 20
         assert pallas_rows._combine_vmem(4096, 10, 2) == 18_350_080 < pallas_rows._VMEM_BUDGET
+
+
+@pytest.mark.parametrize("piece", ["attention", "experts"])
+def test_axk1_layers_at_published_widths_hold_their_kernels(topo, one_chip, monkeypatch, piece):
+    """A.X-K1's two kernel-bearing pieces at the cell's 16,384 tokens, the
+    device gate answering "TPU": latent attention is one ``flash_fwd`` call
+    over 64 heads whose keys are 192 wide and whose values and output are 128
+    (no value is padded to the keys' width, and no stride-2 gather is left of
+    the rotary pairing); the dropless expert layer, 12 of 192 experts held
+    behind the sigmoid group-limited gate, is one ``moe_dispatch``, two ``gmm``
+    and one ``moe_combine`` call at rows of 7,168. Each stands under the scope
+    the trace's reduction finds it by (benchmarks/scopes/axk1.json,
+    benchmarks/kernels/flash_fwd_by_name.json, expert_gmm_by_name.json)."""
+    import gigapath_tpu.ops.flash_attention as fa
+    from gigapath_tpu.models.axk1 import MLAttention
+    from gigapath_tpu.obs.ledger import custom_calls_of
+    from gigapath_tpu.ops.moe import DroplessMoE, GroupLimitedSigmoidGate, pallas_rows
+    from gigapath_tpu.utils.registry import create_model_from_registry
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    cfg = create_model_from_registry("axk1").cfg
+    if piece == "attention":
+        layer = MLAttention(cfg)
+        shapes = (jax.ShapeDtypeStruct((1, 16384, 7168), jnp.bfloat16),
+                  jax.ShapeDtypeStruct((16384, 32), jnp.float32),
+                  jax.ShapeDtypeStruct((16384, 32), jnp.float32))
+        kernels = {"flash_fwd": (1, r"attn_core/jit\(_causal_core\)/kernel_fwd/flash_fwd/")}
+    else:
+        assert pallas_rows.fits(16384, 7168, 8, jnp.bfloat16)
+        layer = DroplessMoE(7168, 2048, 192, 8, experts_held=12,
+                            gate=GroupLimitedSigmoidGate(8, 4, 2.5))
+        shapes = (jax.ShapeDtypeStruct((16384, 7168), jnp.bfloat16),)
+        kernels = {"moe_dispatch": (1, r"dispatch/kernel_fwd/jit\(_dispatch_call\)/moe_dispatch/"),
+                   "gmm": (2, r"experts/kernel_fwd/jit\(gmm\)/"),
+                   "moe_combine": (1, r"combine/kernel_fwd/jit\(_combine_call\)/moe_combine/")}
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), *shapes)
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), (params, *shapes))
+    compiled = jax.jit(layer.apply).lower(*avals).compile()
+    assert custom_calls_of(compiled) == sum(n for n, _ in kernels.values())
+    text = compiled.as_text()
+    for kernel, (calls, scope) in kernels.items():
+        assert re.search(rf'op_name="[^"]*/{scope}', text), kernel
+        # anchored at the instruction's own name, as kernels/expert_gmm_by_name.json is
+        assert len(re.findall(rf"\n\s*(?:ROOT )?%{kernel}(?:\.\d+)? = [^\n]* custom-call\(", text)) == calls
+    if piece == "attention":
+        call = re.search(r"%flash_fwd(?:\.\d+)? = \((\S+) [^\n]* custom-call\([^\n]*"
+                         r"operand_layout_constraints=\{([^}]*\}[^}]*\}[^}]*\}[^}]*\})", text)
+        assert call.group(1).startswith("bf16[1,64,1,16384,128]")   # out at the values' width
+        assert call.group(2).count("bf16[1,64,1,16384,192]") == 2   # q and k at the keys'
+        assert call.group(2).count("bf16[1,64,1,16384,128]") == 1   # v at its own
+        assert " gather(" not in text
+    else:
+        assert not re.search(r"bf16\[131072,7168\][^\n]* gather\(", text)
+        assert not re.search(r"(bf16|f32)\[16384,8,7168\]", text)
+        # the combine's buffers at rows of 7,168 and top-8, of the budget fits() holds them to
+        assert pallas_rows._combine_vmem(7168, 8, 2) <= pallas_rows._VMEM_BUDGET
