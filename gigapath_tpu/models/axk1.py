@@ -119,6 +119,42 @@ def _causal_core(q, k, v, *, scale):
     return flash_attention(q, k, v, is_causal=True, scale=scale)[0]
 
 
+def mla_projections(c: AXK1Config, u):
+    """Latent attention's four input projections and two latent norms, as
+    submodules of the module whose ``__call__`` is running (its parameters
+    keep the published names): ``u [B, L, hidden] -> (c_q [B, L, q_lora_rank],
+    q [B, L, H, nope + rope], k_r [B, L, rope] not yet rotated, kv [B, L, H,
+    nope + v])``."""
+    B, L, _ = u.shape
+    H, nope, rot, dv = (c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
+                        c.v_head_dim)
+    dense = dict(use_bias=False, dtype=c.dtype, param_dtype=c.param_dtype)
+    c_q = c.norm("q_a_layernorm", c.q_lora_rank)(
+        nn.Dense(c.q_lora_rank, name="q_a_proj", **dense)(u))
+    q = nn.Dense(H * (nope + rot), name="q_b_proj", **dense)(c_q).reshape(B, L, H, nope + rot)
+    c_kv, k_r = jnp.split(
+        nn.Dense(c.kv_lora_rank + rot, name="kv_a_proj_with_mqa", **dense)(u),
+        [c.kv_lora_rank], axis=-1)
+    kv = nn.Dense(H * (nope + dv), name="kv_b_proj", **dense)(
+        c.norm("kv_a_layernorm", c.kv_lora_rank)(c_kv)).reshape(B, L, H, nope + dv)
+    return c_q, q, k_r, kv
+
+
+def mla_rope_join(c: AXK1Config, q, k_r, kv, cos, sin):
+    """Scope ``rope``: the rotary features of every query head and of the one
+    shared key rotated, the shared key broadcast to the heads and joined to
+    their own part: ``(q, k) [B, L, H, nope + rope]`` for the core, whose
+    values are ``kv[..., nope:]``."""
+    B, L, H, _ = q.shape
+    nope, rot = c.qk_nope_head_dim, c.qk_rope_head_dim
+    with jax.named_scope("rope"):
+        q_r = rope.apply_rope_interleaved(q[..., nope:], cos, sin)
+        k_r = rope.apply_rope_interleaved(k_r[:, :, None, :], cos, sin)
+        q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
+        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_r, (B, L, H, rot))], axis=-1)
+    return q, k
+
+
 class MLAttention(nn.Module):
     """Multi-head latent attention, un-absorbed: ``u [B, L, hidden]`` and the
     rotary tables ``[L, rope / 2]`` -> ``[B, L, hidden]``. The shared rotary
@@ -132,25 +168,12 @@ class MLAttention(nn.Module):
     def __call__(self, u, cos, sin):
         c = self.cfg
         B, L, _ = u.shape
-        H, nope, rot, dv = (c.num_attention_heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
-                            c.v_head_dim)
-        dense = dict(use_bias=False, dtype=c.dtype, param_dtype=c.param_dtype)
-        c_q = c.norm("q_a_layernorm", c.q_lora_rank)(
-            nn.Dense(c.q_lora_rank, name="q_a_proj", **dense)(u))
-        q = nn.Dense(H * (nope + rot), name="q_b_proj", **dense)(c_q).reshape(B, L, H, nope + rot)
-        c_kv, k_r = jnp.split(
-            nn.Dense(c.kv_lora_rank + rot, name="kv_a_proj_with_mqa", **dense)(u),
-            [c.kv_lora_rank], axis=-1)
-        kv = nn.Dense(H * (nope + dv), name="kv_b_proj", **dense)(
-            c.norm("kv_a_layernorm", c.kv_lora_rank)(c_kv)).reshape(B, L, H, nope + dv)
-        with jax.named_scope("rope"):
-            q_r = rope.apply_rope_interleaved(q[..., nope:], cos, sin)
-            k_r = rope.apply_rope_interleaved(k_r[:, :, None, :], cos, sin)
-            q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
-            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_r, (B, L, H, rot))], axis=-1)
+        _, q, k_r, kv = mla_projections(c, u)
+        q, k = mla_rope_join(c, q, k_r, kv, cos, sin)
         with jax.named_scope("attn_core"):
-            out = _causal_core(q, k, kv[..., nope:], scale=c.softmax_scale)
-        return nn.Dense(c.hidden_size, name="o_proj", **dense)(out.reshape(B, L, H * dv))
+            out = _causal_core(q, k, kv[..., c.qk_nope_head_dim:], scale=c.softmax_scale)
+        return nn.Dense(c.hidden_size, use_bias=False, dtype=c.dtype, param_dtype=c.param_dtype,
+                        name="o_proj")(out.reshape(B, L, c.num_attention_heads * c.v_head_dim))
 
 
 class AXK1Layer(nn.Module):

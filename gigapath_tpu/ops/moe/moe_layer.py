@@ -221,6 +221,9 @@ class DroplessMoE(nn.Module):
     function, or a value such as :class:`~gigapath_tpu.ops.moe.routing.
     GroupLimitedSigmoidGate`). ``None`` is the ``top_k`` largest logits and a
     softmax over them (:func:`~gigapath_tpu.ops.moe.routing.topk_softmax_gating`).
+    A gate whose ``selection_bias`` is true gets a learned float32
+    ``e_score_correction_bias [num_experts]`` of this layer as its third
+    argument (the bias a router is balanced by without an auxiliary loss).
 
     Shapes are static, so the sorted buffer is sized for every choice landing
     here (``S * top_k`` rows), but only the rows a held expert owns are moved:
@@ -265,8 +268,12 @@ class DroplessMoE(nn.Module):
         w1 = self.param("w1", init, (held, M, 2 * self.ffn_dim), self.param_dtype)
         w2 = self.param("w2", init, (held, self.ffn_dim, M), self.param_dtype)
 
+        gate_args = (logits, self.top_k)
+        if getattr(self.gate, "selection_bias", False):
+            gate_args += (self.param("e_score_correction_bias", nn.initializers.zeros,
+                                     (self.num_experts,), jnp.float32),)
         with jax.named_scope("router"):
-            weights, experts = (self.gate or topk_softmax_gating)(logits, self.top_k)
+            weights, experts = (self.gate or topk_softmax_gating)(*gate_args)
         with jax.named_scope("dispatch"):
             order, position, group_sizes = dispatch_to_held(
                 experts, expert_offset=self.expert_offset, experts_held=held)

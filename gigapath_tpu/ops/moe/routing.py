@@ -326,28 +326,44 @@ def topk_softmax_gating(logits: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.n
 
 @dataclasses.dataclass(frozen=True)
 class GroupLimitedSigmoidGate:
-    """Dropless routing as DeepSeek-V3's ``Gate`` does it without a selection
-    bias: sigmoid scores ``[S, E]``; the experts lie in ``n_group`` equal
-    groups, a group scores what its best expert scores, and only the
-    ``topk_group`` best groups stay eligible; the ``k`` largest scores among
+    """Dropless routing as DeepSeek-V3's ``Gate`` does it: sigmoid scores
+    ``[S, E]``; the experts lie in ``n_group`` equal groups, a group scores the
+    sum of its ``group_top`` best experts (1: what its best expert scores), and
+    only the ``topk_group`` best groups stay eligible; the ``k`` largest among
     those; weights are the chosen *scores* over their sum, times ``scale``.
     Called as :func:`topk_softmax_gating` is: ``gate(logits, k) -> (weights
     [S, k] float32, experts [S, k] int32)``. Ties go to the lower index, in
     the groups and in the experts. A value, so that two modules built alike
-    compare equal."""
+    compare equal.
+
+    ``selection_bias`` (``topk_method: noaux_tc``) asks the layer that owns the
+    gate for a learned ``e_score_correction_bias [E]`` and hands it on as
+    ``gate(logits, k, bias)``: it is added to the scores for *choosing* only,
+    the groups and the experts alike; the weights stay the unbiased scores.
+    Without it (``bias`` None) the choice is made on the scores themselves."""
 
     n_group: int
     topk_group: int
     scale: float = 1.0
+    group_top: int = 1
+    selection_bias: bool = False
 
-    def __call__(self, logits: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    def __call__(self, logits: jnp.ndarray, k: int,
+                 bias: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
         S, E = logits.shape
         scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-        group_scores = scores.reshape(S, self.n_group, E // self.n_group).max(-1)
+        pick = scores if bias is None else scores + bias.astype(jnp.float32)
+        grouped = pick.reshape(S, self.n_group, E // self.n_group)
+        group_scores = grouped.max(-1) if self.group_top == 1 \
+            else jax.lax.top_k(grouped, self.group_top)[0].sum(-1)
         _, kept = jax.lax.top_k(group_scores, self.topk_group)
         eligible = (kept[:, :, None] == jnp.arange(self.n_group)).any(1)        # [S, n_group]
         eligible = jnp.repeat(eligible, E // self.n_group, axis=1)
-        # a sigmoid is positive, so -1 ranks below every eligible expert
-        values, experts = jax.lax.top_k(jnp.where(eligible, scores, -1.0), k)
+        if bias is None:
+            # a sigmoid is positive, so -1 ranks below every eligible expert
+            values, experts = jax.lax.top_k(jnp.where(eligible, scores, -1.0), k)
+        else:
+            _, experts = jax.lax.top_k(jnp.where(eligible, pick, -jnp.inf), k)
+            values = jnp.take_along_axis(scores, experts, axis=1)
         weights = values / values.sum(-1, keepdims=True) * self.scale
         return weights, experts.astype(jnp.int32)

@@ -20,6 +20,11 @@ them, and :func:`apply_rope_interleaved` leaves them where they lie: a pair's
 partner is fetched by a product with a signed ``[dim, dim]`` permutation (exact
 in any float type: one +-1 a column), not by a stride-2 slice along the lanes,
 which costs a TPU a dozen relayout passes over the array.
+
+:func:`apply_rope_halfsplit` is the other pairing, ``(x[i], x[i + dim / 2])``
+(the non-interleaved rotation DeepSeek-V3.2's lightning indexer gives its
+queries and its key): the two halves are contiguous slices, so plain slicing
+does it.
 """
 
 from __future__ import annotations
@@ -82,3 +87,13 @@ def apply_rope_interleaved(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -
                          preferred_element_type=jnp.float32)
     cos, sin = (jnp.repeat(t, 2, axis=-1)[:, None, :] for t in (cos, sin))
     return (x.astype(jnp.float32) * cos + partner * sin).astype(x.dtype)
+
+
+def apply_rope_halfsplit(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """Rotate the pairs ``(x[i], x[i + dim / 2])`` of ``x [B, L, H, dim]`` by
+    the tables ``[L, dim / 2]``: ``out[i] = x[i] cos_i - x[i + dim/2] sin_i``,
+    ``out[i + dim/2] = x[i] sin_i + x[i + dim/2] cos_i``. Float32 inside,
+    ``x``'s type out."""
+    lo, hi = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    cos, sin = cos[:, None, :], sin[:, None, :]
+    return jnp.concatenate([lo * cos - hi * sin, lo * sin + hi * cos], axis=-1).astype(x.dtype)

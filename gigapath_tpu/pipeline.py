@@ -151,8 +151,10 @@ def lm_forward_fn(lm):
     received [expert layers, experts_held] int32)`` that
     :func:`run_inference_with_lm` runs, for any LM of the registry. The head runs on the rows
     ``positions`` names and on no other: all 16,384 rows of a long document
-    would be 3.3 GB of logits a request. One function a model (flax modules
-    hash by their fields), so a second call of the entry traces nothing."""
+    would be 3.3 GB of logits a request. A model that counts or predicts more
+    returns a dict of named arrays as a third output, and it is handed on as
+    it is. One function a model (flax modules hash by their fields), so a
+    second call of the entry traces nothing."""
 
     @jax.jit
     def lm_forward(params, ids, positions):
@@ -280,13 +282,16 @@ def run_inference_with_lm(
     lm_params=None,
 ) -> dict:
     """Score token ids with a causal LM of the registry (``granite_4_0_h_small``
-    of ``models/granite_hybrid.py``, ``axk1`` of ``models/axk1.py``; any module
-    with their contract, nothing here asks which): ``token_ids [L]`` or ``[B,
+    of ``models/granite_hybrid.py``, ``axk1`` of ``models/axk1.py``,
+    ``deepseek_v32`` of ``models/deepseek_v32.py``; any module with their
+    contract, nothing here asks which): ``token_ids [L]`` or ``[B,
     L]`` int, ``positions [P]`` or ``[B, P]`` the rows whose next-token logits
     are wanted (the last row where none is given). ``lm`` may be the ``(model,
     params)`` pair ``models.granite_hybrid.create_lm`` returns. Returns
     ``{'logits' [B, P, vocab] float32, 'positions' [B, P], 'expert_tokens'
-    [expert layers, experts_held]}``."""
+    [expert layers, experts_held]}``, and beside them whatever the model's
+    third output names (``deepseek_v32``: ``'selected_pairs' [layers, B]``, and
+    ``'mtp_logits' [B, P, vocab]`` where its prediction module runs)."""
     if lm_params is None:
         lm, lm_params = lm
     ids = np.atleast_2d(np.asarray(token_ids)).astype(np.int32)
@@ -295,8 +300,10 @@ def run_inference_with_lm(
     positions = np.broadcast_to(
         np.atleast_2d(np.asarray(positions)), (ids.shape[0], np.shape(positions)[-1])
     ).astype(np.int32)
-    logits, received = lm_forward_fn(lm)(lm_params, jnp.asarray(ids), jnp.asarray(positions))
+    logits, received, *more = lm_forward_fn(lm)(
+        lm_params, jnp.asarray(ids), jnp.asarray(positions))
     return {
+        **{name: np.asarray(value) for extras in more for name, value in extras.items()},
         "logits": np.asarray(logits, np.float32),
         "positions": positions,
         "expert_tokens": np.asarray(received),

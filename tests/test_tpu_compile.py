@@ -484,3 +484,43 @@ def test_axk1_layers_at_published_widths_hold_their_kernels(topo, one_chip, monk
         assert not re.search(r"(bf16|f32)\[16384,8,7168\]", text)
         # the combine's buffers at rows of 7,168 and top-8, of the budget fits() holds them to
         assert pallas_rows._combine_vmem(7168, 8, 2) <= pallas_rows._VMEM_BUDGET
+
+
+def test_deepseek_v32_attention_at_published_widths_holds_its_kernels(topo, one_chip, monkeypatch):
+    """DeepSeek-V3.2's attention at the cell's 16,384 tokens, the device gate
+    answering "TPU": one ``index_score`` call over 64 index heads of 128 whose
+    result is the ``[L, L]`` float32 scores (no ``[64, L, L]`` anywhere), one
+    ``index_select`` call that turns them into the int8 selection, one
+    ``sparse_attn`` call over 128 heads whose keys are 192 wide and whose values
+    and output are 128, each under the scope the trace's reduction finds it by
+    (benchmarks/scopes/dsv32.json, benchmarks/kernels/*_by_name.json); no
+    ``flash_fwd``, no sort and no top-k is left in the program."""
+    import gigapath_tpu.ops.flash_attention as fa
+    from gigapath_tpu.models.deepseek_v32 import SparseMLAttention
+    from gigapath_tpu.obs.ledger import custom_calls_of
+    from gigapath_tpu.utils.registry import create_model_from_registry
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    layer = SparseMLAttention(create_model_from_registry("deepseek_v32").cfg)
+    shapes = (jax.ShapeDtypeStruct((1, 16384, 7168), jnp.bfloat16),
+              jax.ShapeDtypeStruct((16384, 32), jnp.float32),
+              jax.ShapeDtypeStruct((16384, 32), jnp.float32))
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), *shapes)
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), (params, *shapes))
+    compiled = jax.jit(layer.apply).lower(*avals).compile()
+    assert custom_calls_of(compiled) == 3
+    text = compiled.as_text()
+    for kernel, scope in (("index_score", r"indexer/score/kernel_fwd/index_score"),
+                          ("index_select", r"select/kernel_fwd/index_select"),
+                          ("sparse_attn", r"attn_core/kernel_fwd/sparse_attn")):
+        assert re.search(rf'op_name="[^"]*/{scope}', text), kernel
+        # anchored at the instruction's own name, as the kernel tables are
+        assert len(re.findall(rf"\n\s*(?:ROOT )?%{kernel}(?:\.\d+)? = [^\n]* custom-call\(", text)) == 1
+    assert re.search(r"%index_score(?:\.\d+)? = f32\[1,16384,16384\]", text)
+    assert re.search(r"%index_select(?:\.\d+)? = s8\[1,16384,16384\]", text)
+    assert re.search(r"%sparse_attn(?:\.\d+)? = bf16\[1,128,16384,128\]", text)
+    assert "[1,64,16384,16384]" not in text and "[64,16384,16384]" not in text
+    assert " sort(" not in text and " topk(" not in text and "%flash_fwd" not in text
+    # the temporaries of one layer's attention beside 6.45 GB of weights on a 16 GB chip
+    assert compiled.memory_analysis().temp_size_in_bytes < 6.5e9
