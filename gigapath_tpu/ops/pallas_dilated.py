@@ -20,10 +20,12 @@ the attention math. This kernel removes that glue by construction:
   (positions ``s*g + p + r*j``, ``dense_to_sparse`` in the reference) —
   the packed layout's index maps deliver that directly: dilation costs
   nothing inside the attention kernel.
-- One head per grid cell — grid ``(B, S, r, nq, hb, nk)`` with ``[block,
-  Dh]`` blocks whose lane range the head grid index picks via the packed
-  array's head dim. (Unrolling a band's heads over lane slices of a single
-  ``[block, E/r]`` tile was ~1.6x slower: Mosaic lane shuffles.)
+- The head is a leading dimension of the packed arrays, so a grid step
+  takes whole ``[block, Dh]`` tiles and the bodies never slice lanes. The
+  serial body (causal) holds one head a step, grid ``(B, S, r, nq, hb,
+  nk)``; the overlapped body (non-causal, :func:`plan_fwd_body`) holds two
+  heads of the band and each head's rows in two chunks: four independent
+  QK^T -> softmax -> PV chains a step, grid ``(B, S, r, nq, hb / 2, nk)``.
 - The unpack kernel writes off-band lanes of the dense result as exact
   zeros — the branch's cover pattern — so no separate cover-mask select
   exists anywhere, and the cross-branch fusion gives uncovered slots
@@ -71,10 +73,11 @@ from gigapath_tpu.ops.pallas_flash import (  # shared kernel numerics
 def _fwd_kernel(q_ref, k_ref, v_ref, kvlen_ref, o_ref, lse_ref,
                 m_ref, l_ref, acc_ref, *, scale, causal,
                 block_q, block_k):
-    # grid (B, S, r, nq, hb, nk): one head-band slice per cell — blocks are
-    # [block, Dh] lane slices picked by the head index in the BlockSpecs, so
-    # the body never slices lanes (Mosaic lane shuffles measured ~1.6x the
-    # whole kernel cost when heads were unrolled over an [block, W] tile)
+    # grid (B, S, r, nq, hb, nk): one head of the band a step — blocks are
+    # whole [block, Dh] tiles picked by the head index in the BlockSpecs, so
+    # the body never slices lanes. The serial body: one MXU -> VPU -> MXU
+    # chain a step. Causal branches take it; the non-causal dispatch takes
+    # _fwd_kernel_overlap (plan_fwd_body) and the tests hold the two equal
     b, s, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     i, t, j = pl.program_id(3), pl.program_id(4), pl.program_id(5)
 
@@ -164,188 +167,212 @@ def _fwd_kernel(q_ref, k_ref, v_ref, kvlen_ref, o_ref, lse_ref,
             lse_ref[0, 0, 0] = jnp.where(lane == t, val, lse_ref[0, 0, 0])
 
 
-def _fwd_kernel_pipe(q_ref, k_ref, v_ref, kvlen_ref, o_ref, lse_ref,
-                     m_ref, l_ref, acc_ref, s_bufs, *, scale,
-                     block_q, block_k, hb, nk):
-    """Software-pipelined forward: grid (B, S, r, nq, hb*nk + 1).
+# the lse a row with no valid key gets, as the serial body's finalize
+# computes it from untouched stats: (M_FLOOR + log2(1e-30)) * ln 2 in float32
+_EMPTY_LSE = float(
+    (np.float32(M_FLOOR) + np.log2(np.float32(1e-30))) * np.float32(LN2)
+)
 
-    The serial kernel's body is a strict MXU -> VPU -> MXU dependence
-    chain (QK^T, softmax, PV), so the VPU softmax serializes behind the
-    MXU and cells measure ~1.7-1.9x over the Dh=48 shape bound
-    (PERFORMANCE.md round-4 decomposition). This variant restructures the
-    chain across grid steps: step n computes cell n's logits (MXU, into a
-    parity scratch) and consumes cell n-1's logits (VPU softmax + PV) —
-    every body opens with a big MXU matmul that is data-independent of
-    the VPU chain that follows, which is the opportunity the serial body
-    never gives the Mosaic scheduler. Cells are the flattened (head,
-    k-block) steps of one q block; v/out index maps lag one step. The
-    round-3 in-cell k-split (memory: rejected, 2.83->3.05 ms) differs
-    materially: its two softmax chains shared the running (m, l) carry,
-    so the "independent" matmul was bracketed by dependent VPU work.
 
-    Non-causal only (the fused path's production use); the serial kernel
-    remains for causal and as the default until the on-chip A/B decides.
-    """
+def _fwd_kernel_overlap(q_ref, k_ref, v_ref, kvlen_ref, o_ref, lse_ref,
+                        *scratch, scale, block, heads, rows, nk):
+    """Overlapped non-causal forward: grid (B, S, r, nq, hb / heads, nk).
+
+    The serial body is one MXU -> VPU -> MXU chain a step (QK^T, softmax,
+    PV): PV cannot start before the row maximum of the whole tile is known
+    and the next step's QK^T lies in another grid step, so the MXU idles
+    through every softmax. This body holds ``heads * block / rows``
+    independent chains a step — ``heads`` heads of the band (the head is a
+    leading dimension of the packed layout, so a block of two heads is two
+    ``[block, Dh]`` tiles and no lane is sliced) times the row chunks of
+    each — and emits the next chain's QK^T before the current chain's
+    softmax, so every softmax has a product beside it that does not wait
+    for it. With one key block a row (``nk == 1``) there is no online carry:
+    no stats scratch, no init and no finalize pass, the result is written
+    where it is computed. Same arithmetic as :func:`_fwd_kernel`, to the
+    bit. One v5e, PR 35, the flagship's branch shapes at 16 x 10,240, ms a
+    call in the benchmark's cell, serial -> this body: r1 12.56 -> 9.62, r2
+    18.16 -> 15.87, r4 8.53 -> 6.42, r8 3.30 -> 2.33; the form this
+    replaces, one chain lagged by a grid step, read 0.4 to 4.4 ms *over*
+    the serial body at every shape (PERF.md §6)."""
     b, s, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    n = pl.program_id(4)
-    total = hb * nk
+    tp, j = pl.program_id(4), pl.program_id(5)
     kv = kvlen_ref[b, s, p]
-    j_p = jax.lax.rem(n, nk)
-    t_c = jax.lax.div(n - 1, nk)
-    j_c = jax.lax.rem(n - 1, nk)
+    chains = [(g, r0) for r0 in range(0, block, rows) for g in range(heads)]
+    if nk > 1:
+        m_ref, l_ref, acc_ref = scratch
 
-    # ---- produce: cell n's logits into the parity scratch (MXU) ----
-    @pl.when((n < total) & (j_p * block_k < kv))
-    def _produce():
-        qh = (q_ref[0, 0, 0, 0].astype(jnp.float32) * (scale * LOG2E)).astype(
-            q_ref.dtype
+    def logits(g, r0):
+        # log2(e) folded into the scale: exp2 instead of exp in the hot loop
+        qh = (
+            q_ref[0, 0, 0, g, pl.ds(r0, rows), :].astype(jnp.float32)
+            * (scale * LOG2E)
+        ).astype(q_ref.dtype)
+        return jax.lax.dot_general(
+            qh, k_ref[0, 0, 0, g], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [rows, bk], in log2 units
+
+    def deposit(g, r0, val):
+        # head t of the band on lane t of the shared [bq, LANES] block, which
+        # stays in VMEM across the (tp, j) steps of one i: lanes below t are
+        # earlier heads' (kept), lanes above are filled NEG_INF until their
+        # head arrives (past the band: sliced off outside)
+        t = tp * heads + g
+        rs = pl.ds(r0, rows)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
+        lse_ref[0, 0, 0, rs, :] = jnp.where(
+            lane < t, lse_ref[0, 0, 0, rs, :],
+            jnp.where(lane == t, val, NEG_INF),
         )
-        s_bufs[jax.lax.rem(n, 2)] = jax.lax.dot_general(
-            qh, k_ref[0, 0, 0, 0], (((1,), (1,)), ((), ())),
+
+    def pv(pp, g):
+        return jax.lax.dot_general(
+            pp.astype(v_ref.dtype), v_ref[0, 0, 0, g], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    # ---- consume: cell n-1's logits (VPU softmax + PV matmul) ----
-    @pl.when((n >= 1) & (j_c == 0))
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, M_FLOOR)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    def _consume(masked: bool):
-        s_ = s_bufs[jax.lax.rem(n - 1, 2)]
+    def consume(g, r0, s_, masked):
+        rs = pl.ds(r0, rows)
         if masked:
+            # select, not additive bias, masking BEFORE the running max
             col_ok = (
-                jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-                + j_c * block_k
-                < kv
+                jax.lax.broadcasted_iota(jnp.int32, (1, block), 1) + j * block < kv
             )
             s_ = jnp.where(col_ok, s_, NEG_INF)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s_, axis=-1, keepdims=True))
-        pp = jnp.exp2(s_ - m_new)
+        row_max = jnp.max(s_, axis=-1, keepdims=True)
         if nk == 1:
-            # single k block per head: no online carry (see _fwd_kernel)
-            l_new = jnp.sum(pp, axis=-1, keepdims=True)
-            acc_ref[:] = jax.lax.dot_general(
-                pp.astype(v_ref.dtype), v_ref[0, 0, 0, 0],
-                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-            )
-        else:
-            alpha = jnp.exp2(m_prev - m_new)
-            l_new = l_ref[:, :1] * alpha + jnp.sum(pp, axis=-1, keepdims=True)
-            acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-                pp.astype(v_ref.dtype), v_ref[0, 0, 0, 0],
-                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-            )
-        m_ref[:, :1] = m_new
-        l_ref[:, :1] = l_new
+            m_new = jnp.maximum(M_FLOOR, row_max)
+            pp = jnp.exp2(s_ - m_new)
+            safe_l = jnp.maximum(jnp.sum(pp, axis=-1, keepdims=True), 1e-30)
+            o_ref[0, 0, 0, g, rs, :] = (pv(pp, g) / safe_l).astype(o_ref.dtype)
+            deposit(g, r0, (m_new + jnp.log2(safe_l)) * LN2)
+            return
+        m_prev = m_ref[g, rs, :1]
+        m_new = jnp.maximum(m_prev, row_max)
+        pp = jnp.exp2(s_ - m_new)
+        alpha = jnp.exp2(m_prev - m_new)
+        l_new = l_ref[g, rs, :1] * alpha + jnp.sum(pp, axis=-1, keepdims=True)
+        acc_ref[g, rs, :] = acc_ref[g, rs, :] * alpha + pv(pp, g)
+        # single-lane stats stores
+        m_ref[g, rs, :1] = m_new
+        l_ref[g, rs, :1] = l_new
 
-    @pl.when((n >= 1) & ((j_c + 1) * block_k <= kv))
-    def _consume_full():
-        _consume(masked=False)
+    def step(masked):
+        nxt = logits(*chains[0])
+        for n, chain in enumerate(chains):
+            cur = nxt
+            if n + 1 < len(chains):
+                nxt = logits(*chains[n + 1])
+            consume(*chain, cur, masked)
 
-    @pl.when((n >= 1) & (j_c * block_k < kv) & ((j_c + 1) * block_k > kv))
-    def _consume_partial():
-        _consume(masked=True)
+    if nk > 1:
+        @pl.when(j == 0)
+        def _init():
+            m_ref[...] = jnp.full_like(m_ref, M_FLOOR)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when((n >= 1) & (j_c == nk - 1))
-    def _finalize():
-        safe_l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, 0, 0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
-        val = (m_ref[:, :1] + jnp.log2(safe_l)) * LN2
-        lane = jax.lax.broadcasted_iota(jnp.int32, (block_q, LANES), 1)
+    # full key blocks skip the col-mask VPU pass entirely; only the block
+    # straddling the valid-key boundary pays for masking; a block past it
+    # is skipped
+    @pl.when((j + 1) * block <= kv)
+    def _compute_full():
+        step(masked=False)
 
-        @pl.when(t_c == 0)
-        def _first_head():
-            lse_ref[0, 0, 0] = jnp.where(lane == 0, val, NEG_INF)
+    @pl.when((j * block < kv) & ((j + 1) * block > kv))
+    def _compute_partial():
+        step(masked=True)
 
-        @pl.when(t_c > 0)
-        def _later_head():
-            lse_ref[0, 0, 0] = jnp.where(lane == t_c, val, lse_ref[0, 0, 0])
+    if nk == 1:
+        @pl.when(kv <= 0)
+        def _no_key():
+            o_ref[0, 0, 0] = jnp.zeros(o_ref.shape[3:], o_ref.dtype)
+            for g, r0 in chains:
+                deposit(g, r0, jnp.full((rows, 1), _EMPTY_LSE, jnp.float32))
+    else:
+        @pl.when(j == nk - 1)
+        def _finalize():
+            for g, r0 in chains:
+                rs = pl.ds(r0, rows)
+                safe_l = jnp.maximum(l_ref[g, rs, :1], 1e-30)
+                o_ref[0, 0, 0, g, rs, :] = (acc_ref[g, rs, :] / safe_l).astype(
+                    o_ref.dtype
+                )
+                deposit(g, r0, (m_ref[g, rs, :1] + jnp.log2(safe_l)) * LN2)
 
 
-def _fwd_impl_pipe(q6, k6, v6, kvlen, scale, heads, head_dim,
-                   block_q, block_k, interpret):
-    """Pipelined forward dispatch: same contract as _fwd_impl (non-causal).
+class FwdPlan(NamedTuple):
+    """Which forward body a branch takes, from its shapes alone."""
 
-    block_k may differ from block_q (a shallower k block deepens the
-    pipeline); the k/v packed arrays are zero-padded to a block_k multiple
-    — padded blocks are skipped by the kvlen guards."""
-    B, S, r, hb, M, Dh = q6.shape
-    Mk = k6.shape[4]
-    assert hb == heads and Dh == head_dim, (hb, heads, Dh, head_dim)
-    Mkp = _round_up(Mk, block_k)
-    if Mkp != Mk:
-        pad = ((0, 0), (0, 0), (0, 0), (0, 0), (0, Mkp - Mk), (0, 0))
-        k6 = jnp.pad(k6, pad)
-        v6 = jnp.pad(v6, pad)
-    nq, nk = M // block_q, Mkp // block_k
-    total = hb * nk
+    body: str   # "overlap" | "serial": the kernel is dilated_fwd_<body>
+    heads: int  # heads of the band a grid step holds
+    rows: int   # query rows a chain (one QK^T -> softmax -> PV)
 
-    def t_p(n):
-        return jnp.minimum(n // nk, hb - 1)
 
-    def cell_c(n):
-        tc = jnp.clip((n - 1) // nk, 0, hb - 1)
-        jc = jnp.clip(n - 1 - tc * nk, 0, nk - 1)
-        return tc, jc
+def plan_fwd_body(causal: bool, hb: int, block: int) -> FwdPlan:
+    """The forward body of a branch whose bands hold ``hb`` heads and whose
+    packed rows are cut into blocks of ``block`` (:func:`_branch_geometry`),
+    whatever their number and the heads' width (a ``[block, Dh]`` tile
+    takes 128 lanes in VMEM at 48, 64 and 96 alike).
+
+    Non-causal: the overlapped body, two heads a step where the band has an
+    even number of them (one otherwise), each head's rows in two chunks —
+    four (two) independent chains a step; finer chunks and a third or fourth
+    head gained nothing on the chip or left the default scoped VMEM (PR 35's
+    probe). Causal stays on the serial body (nothing registered runs a
+    causal dilated branch)."""
+    if causal:
+        return FwdPlan("serial", 1, block)
+    return FwdPlan("overlap", 2 if hb % 2 == 0 else 1, block // 2)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "plan", "interpret"))
+def _fwd_call_overlap(q6, k6, v6, kvlen, *, block, plan, interpret):
+    """The overlapped forward over packed q / k / v: :func:`_fwd_impl`'s
+    contract (non-causal, one block size for rows and keys, scale
+    ``Dh ** -0.5``). One jitted function, so the layers of a model share
+    one trace and one lowering a branch shape."""
+    B, S, r, hb, M, head_dim = q6.shape
+    nq, nk = M // block, k6.shape[4] // block
+    G = plan.heads
+    assert hb % G == 0 and block % plan.rows == 0, (hb, block, plan)
 
     spec_q = pl.BlockSpec(
-        (1, 1, 1, 1, block_q, head_dim),
-        lambda b, s, p, i, n: (b, s, p, t_p(n), i, 0),
+        (1, 1, 1, G, block, head_dim),
+        lambda b, s, p, i, t, j: (b, s, p, t, i, 0),
         memory_space=pltpu.VMEM,
     )
     spec_k = pl.BlockSpec(
-        (1, 1, 1, 1, block_k, head_dim),
-        # j clamped: at the drain step (n == hb*nk) no produce executes but
-        # the index must still name a real block
-        lambda b, s, p, i, n: (
-            b, s, p, t_p(n), jnp.minimum(n - t_p(n) * nk, nk - 1), 0,
-        ),
+        (1, 1, 1, G, block, head_dim),
+        lambda b, s, p, i, t, j: (b, s, p, t, j, 0),
         memory_space=pltpu.VMEM,
     )
-    def v_map(b, s, p, i, n):
-        tc, jc = cell_c(n)
-        return (b, s, p, tc, jc, 0)
-
-    spec_v = pl.BlockSpec(
-        (1, 1, 1, 1, block_k, head_dim), v_map, memory_space=pltpu.VMEM,
-    )
-
-    def o_map(b, s, p, i, n):
-        tc, _ = cell_c(n)
-        return (b, s, p, tc, i, 0)
-
-    spec_o = pl.BlockSpec(
-        (1, 1, 1, 1, block_q, head_dim), o_map, memory_space=pltpu.VMEM,
-    )
     lse_spec = pl.BlockSpec(
-        (1, 1, 1, block_q, LANES), lambda b, s, p, i, n: (b, s, p, i, 0),
+        (1, 1, 1, block, LANES), lambda b, s, p, i, t, j: (b, s, p, i, 0),
         memory_space=pltpu.VMEM,
     )
     kernel = functools.partial(
-        _fwd_kernel_pipe, scale=scale,
-        block_q=block_q, block_k=block_k, hb=hb, nk=nk,
+        _fwd_kernel_overlap, scale=head_dim ** -0.5, block=block, heads=G,
+        rows=plan.rows, nk=nk,
     )
     with jax.named_scope("kernel_fwd"):
         out, lse = pl.pallas_call(
             kernel,
-            grid=(B, S, r, nq, total + 1),
-            in_specs=[spec_q, spec_k, spec_v, pl.BlockSpec(memory_space=pltpu.SMEM)],
-            out_specs=[spec_o, lse_spec],
+            grid=(B, S, r, nq, hb // G, nk),
+            in_specs=[spec_q, spec_k, spec_k, pl.BlockSpec(memory_space=pltpu.SMEM)],
+            out_specs=[spec_q, lse_spec],
             out_shape=[
                 jax.ShapeDtypeStruct(q6.shape, q6.dtype),
                 jax.ShapeDtypeStruct((B, S, r, M, LANES), jnp.float32),
             ],
-            scratch_shapes=[
-                pltpu.VMEM((block_q, LANES), jnp.float32),
-                pltpu.VMEM((block_q, LANES), jnp.float32),
-                pltpu.VMEM((block_q, head_dim), jnp.float32),
-                pltpu.VMEM((2, block_q, block_k), jnp.float32),
+            scratch_shapes=[] if nk == 1 else [
+                pltpu.VMEM((G, block, LANES), jnp.float32),
+                pltpu.VMEM((G, block, LANES), jnp.float32),
+                pltpu.VMEM((G, block, head_dim), jnp.float32),
             ],
             interpret=interpret,
-            name="dilated_fwd_pipe",
+            name="dilated_fwd_overlap",
         )(q6, k6, v6, kvlen)
     return out, lse
 
@@ -532,7 +559,7 @@ def _dq_kernel_pipe(q_ref, k_ref, v_ref, kc_ref, do_ref, lse_ref, delta_ref,
     s_n = (q*scale)@k_n^T and dp_n = do@v_n^T — into parity scratches,
     then consumes cell n-1: p = exp2(s - lse), ds = p*(dp - delta) (VPU)
     and dq_acc += ds@k (MXU, via the LAGGED second k input kc_ref). Same
-    restructuring rationale as _fwd_kernel_pipe. Non-causal only."""
+    restructuring rationale as the forward's overlapped body. Non-causal only."""
     b, s, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n = pl.program_id(4)
     total = hb * nk
@@ -844,10 +871,8 @@ class PipelineFlags(NamedTuple):
     fresh-function-identity workaround.
     """
 
-    pipelined_fwd: bool = False
     pipelined_bwd: bool = False
-    pipe_block_k: Optional[int] = None  # None: VMEM-budget auto choice
-    pipe_bwd_block_k: Optional[int] = None
+    pipe_bwd_block_k: Optional[int] = None  # None: VMEM-budget auto choice
     # online dense branch fold (GIGAPATH_STREAMING_FUSION): fold dilated
     # branches into running (acc, m, l) instead of stacking all branch
     # outputs; keeps the dense unpack path (the packed merge epilogue is
@@ -869,9 +894,7 @@ class PipelineFlags(NamedTuple):
 
 # field -> environment twin, in field order
 FLAG_ENV = {
-    "pipelined_fwd": "GIGAPATH_PIPELINED_ATTN",
     "pipelined_bwd": "GIGAPATH_PIPELINED_BWD",
-    "pipe_block_k": "GIGAPATH_PIPE_BLOCK_K",
     "pipe_bwd_block_k": "GIGAPATH_PIPE_BWD_BLOCK_K",
     "streaming_fusion": "GIGAPATH_STREAMING_FUSION",
     "ring_attn": "GIGAPATH_RING_ATTN",
@@ -882,7 +905,7 @@ FLAG_ENV = {
 
 
 def snapshot_flags() -> PipelineFlags:
-    """Read GIGAPATH_PIPELINED_ATTN/_BWD, GIGAPATH_PIPE(_BWD)_BLOCK_K,
+    """Read GIGAPATH_PIPELINED_BWD, GIGAPATH_PIPE_BWD_BLOCK_K,
     GIGAPATH_STREAMING_FUSION, GIGAPATH_RING_ATTN,
     GIGAPATH_FOLD_PALLAS and GIGAPATH_FOLD_BLOCK_Q/_K from the
     environment, once."""
@@ -897,9 +920,7 @@ def snapshot_flags() -> PipelineFlags:
         return (int(raw) or None) if raw else None
 
     return PipelineFlags(
-        pipelined_fwd=env_flag("GIGAPATH_PIPELINED_ATTN"),
         pipelined_bwd=env_flag("GIGAPATH_PIPELINED_BWD"),
-        pipe_block_k=_int("GIGAPATH_PIPE_BLOCK_K"),
         pipe_bwd_block_k=_int("GIGAPATH_PIPE_BWD_BLOCK_K"),
         streaming_fusion=env_flag("GIGAPATH_STREAMING_FUSION"),
         ring_attn=env_flag("GIGAPATH_RING_ATTN"),
@@ -1003,10 +1024,12 @@ def _branch_geometry(L: int, E: int, sl: int, r: int) -> Tuple[int, int, int, in
     S = _round_up(L, g) // g
     gp = _round_up(g, r)
     m = gp // r
-    # per-cell VMEM is dominated by the [bq, bk] fp32 logits/probs tiles
-    # (blocks themselves are [b, Dh], tiny): 1024^2 blocks fit and are
-    # ~2x faster than 512 on the LongNet shapes (fewer K/V restreams);
-    # candidates below trade q-row padding against cell count
+    # a step's VMEM: the fp32 logits / probability tiles of its chains
+    # ([block / 2, block] each in the overlapped forward, [block, block] in
+    # the serial one and the backward) beside two heads' lane-padded q / k /
+    # v / out blocks and stats; at 1024 that fits the default scoped limit
+    # (tests/test_tpu_compile.py), and a smaller block restreams K/V more
+    # often; candidates below trade q-row padding against cell count
     cap = 1024
     single = _round_up(m, LANES)
     if single <= cap:
@@ -1494,22 +1517,14 @@ def _dilated_branch(q, k, v, vl_dyn, sl, r, H, real_len, causal, interpret,
     return out, lse
 
 
-def _pipe_block_k(block_q: int, override: Optional[int]) -> int:
-    """k-block for the pipelined forward: the PipelineFlags override
-    (GIGAPATH_PIPE_BLOCK_K, snapshotted at dispatch) or a default that
-    keeps the two parity logits tiles + the exp2 temp inside the
-    scoped-VMEM envelope at any legal block_q (<= 1408)."""
-    bk = override if override else 512
-    return max(LANES, min(bk, block_q))
-
-
 def _branch_packed_fwd_impl(q, k, v, vl_dyn, sl, r, H, real_len, causal,
-                            interpret, flags):
+                            interpret, flags, body: Optional[str] = None):
     """Shared forward core: dense [B, L, E] q/k/v -> PACKED
     ``(out6 [B, S, r, hb, Mp, Dh], lse5 [B, S, r, Mp, LANES])`` — the
     kernel-native layout, consumed either by the dense unpack/scatter pair
     (:func:`_dilated_branch_fwd_impl`) or directly by the streaming fusion
-    epilogue (which never materializes the dense per-branch tensors)."""
+    epilogue (which never materializes the dense per-branch tensors).
+    ``body``: see :func:`_packed_forward`."""
     B, L, E = q.shape
     Dh = E // H
     g, S, gp, m, Mp, block = _branch_geometry(L, E, sl, r)
@@ -1517,26 +1532,37 @@ def _branch_packed_fwd_impl(q, k, v, vl_dyn, sl, r, H, real_len, causal,
     k6 = _pack_phases(k, g, S, r, Mp, H, interpret)
     v6 = _pack_phases(v, g, S, r, Mp, H, interpret)
     kvlen = _branch_kvlen(B, S, g, r, m, real_len, vl_dyn)
-    hb = H // r
-    if not causal and flags.pipelined_fwd:
-        out6, lse5 = _fwd_impl_pipe(
-            q6, k6, v6, kvlen, Dh ** -0.5, hb, Dh,
-            block, _pipe_block_k(block, flags.pipe_block_k), interpret,
-        )
-    else:
-        out6, lse5 = _fwd_impl(
+    return _packed_forward(
+        q6, k6, v6, kvlen, causal, H // r, Dh, block, interpret, body
+    )
+
+
+def _packed_forward(q6, k6, v6, kvlen, causal, hb, Dh, block, interpret,
+                    body: Optional[str] = None):
+    """The forward kernel over packed q / k / v: the body
+    :func:`plan_fwd_body` names for these shapes. ``body="serial"`` asks for
+    the serial one whatever the shapes (the tests and the chip's kernel
+    checks hold the two to each other); no dispatch passes it."""
+    plan = plan_fwd_body(causal, hb, block)
+    if body is None:
+        body = plan.body
+    if body == "serial":
+        return _fwd_impl(
             q6, k6, v6, kvlen, causal, Dh ** -0.5, hb, Dh, block, block,
             interpret,
         )
-    return out6, lse5
+    assert body == plan.body == "overlap", (body, plan)
+    return _fwd_call_overlap(
+        q6, k6, v6, kvlen, block=block, plan=plan, interpret=interpret
+    )
 
 
 def _dilated_branch_fwd_impl(q, k, v, vl_dyn, sl, r, H, real_len, causal,
-                             interpret, flags):
+                             interpret, flags, body: Optional[str] = None):
     B, L, E = q.shape
     g, S, gp, m, Mp, block = _branch_geometry(L, E, sl, r)
     out6, lse5 = _branch_packed_fwd_impl(
-        q, k, v, vl_dyn, sl, r, H, real_len, causal, interpret, flags
+        q, k, v, vl_dyn, sl, r, H, real_len, causal, interpret, flags, body
     )
     # off-band lanes come back as exact zeros from the unpack kernel — the
     # branch's cover pattern needs no separate select

@@ -37,6 +37,7 @@ class Geometry(NamedTuple):
     grad_ratios: Sequence[int]
     grad_len: int
     bench_len: int        # block-coverage length (bench N + cls token)
+    serve_len: int        # a serve bucket + cls token: smaller blocks, one key block
     fold_chunk: int       # streaming pair_partial chunk
     vit_attn: Sequence[int]        # (B, N, heads, head_dim) of the ViT's packed-qkv core
 
@@ -53,7 +54,7 @@ def flagship(bench_tokens: int = 10240) -> Geometry:
         # exercising multi-segment branch 1 and every dilation ratio
         seq_len=2048,
         grad_segments=[256, 512], grad_ratios=[1, 2], grad_len=1024,
-        bench_len=bench_tokens + 1, fold_chunk=2048,
+        bench_len=bench_tokens + 1, serve_len=4096 + 1, fold_chunk=2048,
         vit_attn=(2, 197, 24, 64),  # ViT-G/14
     )
 
@@ -62,7 +63,7 @@ def flagship(bench_tokens: int = 10240) -> Geometry:
 TINY = Geometry(
     heads=4, head_dim=8, segment_lengths=[32, 64], dilated_ratios=[1, 2],
     seq_len=128, grad_segments=[32, 64], grad_ratios=[1, 2], grad_len=64,
-    bench_len=65, fold_chunk=32, vit_attn=(2, 19, 2, 64),
+    bench_len=65, serve_len=33, fold_chunk=32, vit_attn=(2, 19, 2, 64),
 )
 
 
@@ -215,30 +216,37 @@ def run_kernel_checks(
         o = da.dilated_attention_fused(x, y, z, SEGS, RATIOS, valid_len=n_valid)
         return (o.astype(jnp.float32) ** 2).mean()
 
-    def bhld_ref_loss(x, y, z):
-        o = da.dilated_attention_bhld(
-            x, y, z, SEGS, RATIOS, valid_len=N - 64, use_pallas=False
-        )
-        return (o.astype(jnp.float32) ** 2).mean()
-
-    qb, kb, vb = qkv(1, N, H, Dh)
     # static_argnums: a jitted int operand would be traced, silently
     # routing the "static" check through the dynamic-kvlen path too
     vg_static = jax.jit(
         jax.value_and_grad(fused_loss, argnums=(0, 1, 2)), static_argnums=3
     )
     vg_traced = jax.jit(jax.value_and_grad(fused_loss, argnums=(0, 1, 2)))
-    loss_f, grads_f = vg_static(qb, kb, vb, N - 64)
-    loss_t, grads_t = vg_traced(qb, kb, vb, jnp.asarray([N - 64], jnp.int32))
-    with highest:
-        loss_b, grads_b = jax.jit(
-            jax.value_and_grad(bhld_ref_loss, argnums=(0, 1, 2))
-        )(*(x.astype(jnp.float32) for x in (qb, kb, vb)))
-    check("fused bench-geom fwd (static vl)", loss_f, loss_b, 1e-3)
-    check("fused bench-geom fwd (traced vl == static)", loss_t, loss_f, 1e-6)
-    for name, a, t, b in zip("qkv", grads_f, grads_t, grads_b):
-        rel_check(f"fused bench-geom d{name}", a, b, 6e-2)
-        check(f"fused bench-geom d{name} traced==static", t, a, 1e-6)
+    # the bench length, and a serve bucket (other blocks, r8 / r16 one key
+    # block a row): forward and backward against the float32 reference
+    fused = {}
+    for tag, n in (("bench-geom", N), ("serve-bucket", geom.serve_len)):
+        n_valid = n - min(64, n // 4)
+
+        def bhld_ref_loss(x, y, z):
+            o = da.dilated_attention_bhld(
+                x, y, z, SEGS, RATIOS, valid_len=n_valid, use_pallas=False
+            )
+            return (o.astype(jnp.float32) ** 2).mean()
+
+        qs, ks, vs = qkv(1, n, H, Dh)
+        loss_s, grads_s = vg_static(qs, ks, vs, n_valid)
+        loss_t, grads_t = vg_traced(qs, ks, vs, jnp.asarray([n_valid], jnp.int32))
+        with highest:
+            loss_b, grads_b = jax.jit(
+                jax.value_and_grad(bhld_ref_loss, argnums=(0, 1, 2))
+            )(*(x.astype(jnp.float32) for x in (qs, ks, vs)))
+        check(f"fused {tag} fwd (static vl)", loss_s, loss_b, 1e-3)
+        check(f"fused {tag} fwd (traced vl == static)", loss_t, loss_s, 1e-6)
+        for name, a, t, b in zip("qkv", grads_s, grads_t, grads_b):
+            rel_check(f"fused {tag} d{name}", a, b, 6e-2)
+            check(f"fused {tag} d{name} traced==static", t, a, 1e-6)
+        fused[tag] = ((qs, ks, vs), loss_s, grads_s)
 
     # --- streaming fold: pallas pair_partial vs the jnp fold ------------
     C = geom.fold_chunk
@@ -274,10 +282,11 @@ def run_kernel_checks(
           pva.packed_qkv_attention(packed, Hv), ref, 3e-2)
 
     _copy_kernel_checks(geom, rng, check)
+    _forward_body_checks(geom, rng, check)
 
     if flagged_variants:
         _flagged_variant_checks(
-            da, SEGS, RATIOS, N, (qb, kb, vb), loss_f, grads_f, check, rel_check
+            da, SEGS, RATIOS, N, *fused["bench-geom"], check, rel_check
         )
     return rows
 
@@ -334,6 +343,38 @@ def _copy_kernel_checks(geom: Geometry, rng, check) -> None:
               jnp.sum(unpack(p6) != _jnp_unpack(p6, L, E, g, S, r)), 0, 0.5)
 
 
+def _forward_body_checks(geom: Geometry, rng, check) -> None:
+    """The forward body the planner names for each branch of the schedule
+    (``pallas_dilated.plan_fwd_body``: the overlapped one, non-causal)
+    against the serial body on the same packed arrays, at the bench length
+    and at a serve bucket, ragged key counts: the same arithmetic, so the
+    number compared is the count of elements that differ."""
+    from gigapath_tpu.ops import pallas_dilated as pd
+
+    H, Dh = geom.heads, geom.head_dim
+    for n in (geom.bench_len, geom.serve_len):
+        L = -(-n // 128) * 128
+        for sl, r in zip(geom.segment_lengths, geom.dilated_ratios):
+            g, S, _, m, Mp, block = pd._branch_geometry(L, H * Dh, sl, r)
+            hb = H // r
+            q6, k6, v6 = (
+                jnp.asarray(rng.normal(size=(2, S, r, hb, Mp, Dh)), jnp.bfloat16)
+                for _ in range(3)
+            )
+            kvlen = jnp.asarray(np.stack([
+                pd._phase_kvlen(S, g, r, m, n), pd._phase_kvlen(S, g, r, m, n // 3),
+            ]))
+            fwd = lambda body: jax.jit(lambda a, b, c, kl: pd._packed_forward(
+                a, b, c, kl, False, hb, Dh, block, False, body))(q6, k6, v6, kvlen)
+            (o_new, l_new), (o_old, l_old) = fwd(None), fwd("serial")
+            plan = pd.plan_fwd_body(False, hb, block)
+            tag = f"sl={sl} r={r} n={n} blk={block} {plan.body}x{plan.heads}"
+            check(f"fwd body out {tag}: elements that differ",
+                  jnp.sum(o_new != o_old), 0, 0.5)
+            check(f"fwd body lse {tag}: elements that differ",
+                  jnp.sum(l_new[..., :hb] != l_old[..., :hb]), 0, 0.5)
+
+
 def _flagged_variant_checks(da, SEGS, RATIOS, N, qkv_b, loss_f, grads_f,
                             check, rel_check) -> None:
     """The default-off env-flagged kernel variants at the bench geometry:
@@ -349,11 +390,8 @@ def _flagged_variant_checks(da, SEGS, RATIOS, N, qkv_b, loss_f, grads_f,
 
         return f
 
-    both = {"GIGAPATH_PIPELINED_ATTN": "1", "GIGAPATH_PIPELINED_BWD": "1"}
     combos = [
-        ("pipe", {"GIGAPATH_PIPELINED_ATTN": "1"}, 1e-3),
         ("pipebwd", {"GIGAPATH_PIPELINED_BWD": "1"}, 1e-6),  # fwd unchanged
-        ("all", both, 1e-3),
     ]
     for tag, env, tol in combos:
         prior = {key: os.environ.get(key) for key in env}

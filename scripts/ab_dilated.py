@@ -44,10 +44,6 @@ def main():
     ap.add_argument("--n", type=int, default=10241)
     ap.add_argument("--iters", type=int, default=24)
     ap.add_argument(
-        "--pipe-bk", default="512",
-        help="comma list of pipelined k-block sizes (with 'pipe' variant)",
-    )
-    ap.add_argument(
         "--grad", action="store_true",
         help="measure the grad step (fwd+bwd wrt q/k/v) instead of forward",
     )
@@ -180,11 +176,6 @@ def main():
         )
     if "fused" in args.variants:
         variants["fused"] = fused
-    if "pipe" in args.variants:
-        for bk in (int(b) for b in args.pipe_bk.split(",") if b):
-            variants[f"pipe{bk}"] = with_env(
-                fused, GIGAPATH_PIPELINED_ATTN=1, GIGAPATH_PIPE_BLOCK_K=bk
-            )
     if args.grad and args.pipebwd:
         for name, fn in list(variants.items()):
             if name != "bhld":

@@ -550,40 +550,56 @@ def test_fused_streaming_matches_stacked(rng):
     )
 
 
+def _branch_both_bodies(q, k, v, sl, r, H, real_len=None, valid_len_dyn=None):
+    """One branch through the dispatch as it is (overlapped, non-causal)
+    and through the same steps with the serial body asked for by the
+    forward's internal argument."""
+    import gigapath_tpu.ops.pallas_dilated as pd
+
+    call = lambda a, b, c: pd.dilated_branch_attention(
+        a, b, c, sl, r, H, interpret=True, real_len=real_len,
+        valid_len_dyn=valid_len_dyn)
+    assert "dilated_fwd_overlap" in str(jax.make_jaxpr(call)(q, k, v))
+    L = q.shape[1]
+    old = pd._dilated_branch_fwd_impl(
+        q, k, v, valid_len_dyn, sl, r, H, L if real_len is None else min(real_len, L),
+        False, True, pd.PipelineFlags(), body="serial")[:2]
+    return call(q, k, v), old
+
+
+def _assert_same_branch(new, old):
+    (o1, l1), (o0, l0) = new, old
+    np.testing.assert_allclose(np.asarray(o1), np.asarray(o0), atol=2e-6, rtol=1e-5)
+    fin = np.asarray(l0) > -1e19  # uncovered slots hold sentinels
+    assert np.array_equal(np.asarray(l1) > -1e19, fin)
+    np.testing.assert_allclose(
+        np.asarray(l1)[fin], np.asarray(l0)[fin], atol=2e-6, rtol=1e-5
+    )
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize(
     "L,sl,r,rl",
     [
         (300, 64, 1, 300),      # nk == 1, single head band
         (300, 64, 2, 277),      # nk == 1, phases + ragged tail
-        (1280, 1280, 1, 1280),  # nk > 1 (pipe block_k 512 vs block_q 1280)
-        (1280, 1280, 2, 1100),  # nk > 1 + phases + ragged tail
+        (1280, 1280, 1, 1280),  # nk > 1 (blocks of 640)
+        (1280, 1280, 2, 1100),  # phases + ragged tail at a wider block
     ],
 )
-def test_pipelined_fwd_matches_serial(rng, monkeypatch, L, sl, r, rl):
-    """GIGAPATH_PIPELINED_ATTN forward == the serial fused kernel.
+def test_overlapped_fwd_matches_serial(rng, L, sl, r, rl):
+    """The overlapped forward (what the dispatch picks for a non-causal
+    branch) == the serial kernel.
 
-    The pipelined kernel computes cell n's logits while consuming cell
-    n-1's from a parity scratch (v/out index maps lag one step); same
-    online-softmax math, so outputs agree to fp32 rounding even when the
-    k-block split differs."""
-    from gigapath_tpu.ops.pallas_dilated import dilated_branch_attention
-
+    The overlapped body holds two heads a step and each head's rows in two
+    chunks, the next chain's logits emitted before the current chain's
+    softmax; the arithmetic of a row is the serial body's."""
     H, Dh = 8, 16
     E = H * Dh
     q, k, v = (
         jnp.asarray(rng.normal(size=(2, L, E)), jnp.float32) for _ in range(3)
     )
-    monkeypatch.delenv("GIGAPATH_PIPELINED_ATTN", raising=False)
-    o0, l0 = dilated_branch_attention(q, k, v, sl, r, H, real_len=rl, interpret=True)
-    monkeypatch.setenv("GIGAPATH_PIPELINED_ATTN", "1")
-    monkeypatch.setenv("GIGAPATH_PIPE_BLOCK_K", "512")
-    o1, l1 = dilated_branch_attention(q, k, v, sl, r, H, real_len=rl, interpret=True)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o0), atol=2e-6, rtol=1e-5)
-    fin = np.asarray(l0) > -1e19  # uncovered slots hold sentinels
-    np.testing.assert_allclose(
-        np.asarray(l1)[fin], np.asarray(l0)[fin], atol=2e-6, rtol=1e-5
-    )
+    _assert_same_branch(*_branch_both_bodies(q, k, v, sl, r, H, real_len=rl))
 
 
 @pytest.mark.slow
@@ -632,29 +648,172 @@ def test_pipelined_bwd_matches_serial(rng, monkeypatch, L, sl, r, rl):
         )
 
 
-def test_pipelined_fwd_fast_small_geometry(rng, monkeypatch):
-    """Fast default-tier sibling of test_pipelined_fwd_matches_serial:
-    one L=300/nk==1 case so ``pytest -q`` exercises the
-    GIGAPATH_PIPELINED_ATTN kernel path on every run (the round-5 slow-only
-    gap gigalint GL005 now guards against)."""
-    from gigapath_tpu.ops.pallas_dilated import dilated_branch_attention
-
+def test_overlapped_fwd_fast_small_geometry(rng):
+    """Fast default-tier sibling of test_overlapped_fwd_matches_serial:
+    one L=300/nk==1 case so ``pytest -q`` holds the dispatch's forward body
+    to the serial one on every run."""
     L, sl, r, rl = 300, 64, 1, 300
     H, Dh = 8, 16
     E = H * Dh
     q, k, v = (
         jnp.asarray(rng.normal(size=(1, L, E)), jnp.float32) for _ in range(3)
     )
-    monkeypatch.delenv("GIGAPATH_PIPELINED_ATTN", raising=False)
-    o0, l0 = dilated_branch_attention(q, k, v, sl, r, H, real_len=rl, interpret=True)
-    monkeypatch.setenv("GIGAPATH_PIPELINED_ATTN", "1")
-    monkeypatch.setenv("GIGAPATH_PIPE_BLOCK_K", "512")
-    o1, l1 = dilated_branch_attention(q, k, v, sl, r, H, real_len=rl, interpret=True)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o0), atol=2e-6, rtol=1e-5)
-    fin = np.asarray(l0) > -1e19
+    _assert_same_branch(*_branch_both_bodies(q, k, v, sl, r, H, real_len=rl))
+
+
+def _packed_reference(q6, k6, v6, kvlen):
+    """Plain numpy (float64) attention over packed [B, S, r, hb, M, Dh] arrays with
+    [B, S, r] valid key counts: (out, lse) as the kernels define them where
+    a row has a valid key."""
+    q6, k6, v6 = (np.asarray(x, np.float64) for x in (q6, k6, v6))
+    M, Dh = q6.shape[-2:]
+    s = np.einsum("...qd,...kd->...qk", q6, k6) * Dh ** -0.5
+    ok = np.arange(M)[None, None, None, :] < np.asarray(kvlen)[..., None]
+    s = np.where(ok[:, :, :, None, None, :], s, -np.inf)
+    with np.errstate(invalid="ignore", divide="ignore"):
+        m = s.max(-1, keepdims=True)
+        p = np.exp(s - m)
+        l = p.sum(-1, keepdims=True)
+        out = np.einsum("...qk,...kd->...qd", p / l, v6)
+        lse = (m + np.log(l))[..., 0]  # [B, S, r, hb, M]
+    return out, np.moveaxis(lse, 3, 4)  # lse as [B, S, r, M, hb]
+
+
+@pytest.mark.parametrize(
+    "hb,M,block,dtype,tol",
+    [
+        (1, 128, 128, jnp.float32, 2e-5),    # a band of one head, nk == 1
+        (2, 128, 128, jnp.bfloat16, 2e-2),   # one pair a step, nk == 1
+        (3, 256, 128, jnp.float32, 2e-5),    # an odd band: one head a step, nk == 2
+        (4, 384, 128, jnp.float32, 2e-5),    # two pairs, nk == 3: the online carry
+        (2, 384, 128, jnp.bfloat16, 2e-2),   # the same in the kernels' own precision
+    ],
+)
+def test_overlapped_body_against_serial_and_plain_reference(rng, hb, M, block, dtype, tol):
+    """The forward bodies over packed arrays, key counts ragged (full,
+    partial, a block wholly past the count, no key at all): the overlapped
+    body gives the serial body's numbers, and both the plain reference's."""
+    import gigapath_tpu.ops.pallas_dilated as pd
+
+    B, S, r, Dh = 2, 2, 2, 16
+    shape = (B, S, r, hb, M, Dh)
+    q6, k6, v6 = (jnp.asarray(rng.normal(size=shape), dtype) for _ in range(3))
+    kvlen = rng.integers(1, M + 1, size=(B, S, r)).astype(np.int32)
+    kvlen[0, 0, 0], kvlen[1, 1, 1], kvlen[0, 1, 0] = 0, M, min(M, block - 5)
+    kvlen = jnp.asarray(kvlen)
+    plan = pd.plan_fwd_body(False, hb, block)
+    assert plan == pd.FwdPlan("overlap", 2 if hb % 2 == 0 else 1, block // 2)
+    new = pd._packed_forward(q6, k6, v6, kvlen, False, hb, Dh, block, True)
+    old = pd._packed_forward(q6, k6, v6, kvlen, False, hb, Dh, block, True, body="serial")
+    for a, b in zip(new, old):
+        assert a.shape == b.shape and a.dtype == b.dtype
     np.testing.assert_allclose(
-        np.asarray(l1)[fin], np.asarray(l0)[fin], atol=2e-6, rtol=1e-5
+        np.asarray(new[0], np.float32), np.asarray(old[0], np.float32), atol=2e-6, rtol=1e-5)
+    # lanes of the band's heads equal; lanes past them are fill in both
+    np.testing.assert_allclose(
+        np.asarray(new[1])[..., :hb], np.asarray(old[1])[..., :hb], atol=0, rtol=2e-6)
+    assert np.all(np.asarray(new[1])[..., hb:] < -1e19)
+    ref_out, ref_lse = _packed_reference(q6, k6, v6, kvlen)
+    some = (np.asarray(kvlen) > 0)[:, :, :, None, None]
+    got = np.asarray(new[0], np.float64)
+    np.testing.assert_allclose(
+        np.where(some[..., None], got, 0), np.where(some[..., None], ref_out, 0), atol=tol)
+    np.testing.assert_allclose(
+        np.where(some, np.asarray(new[1])[..., :hb], 0), np.where(some, ref_lse, 0), atol=tol)
+    # a band with no valid key: out 0, lse the stats' floor, as the serial body
+    assert np.all(got[0, 0, 0] == 0) and np.all(np.asarray(new[1])[0, 0, 0, :, :hb] < -1e19)
+
+
+@pytest.mark.parametrize("rl", [101, "traced"])
+def test_overlapped_fwd_ragged_and_traced_valid_len(rng, rl):
+    """A static ragged tail and TRACED per-batch valid lengths (they ride the
+    kernels' SMEM tables) through the dispatch's forward body, under jit as
+    the train path runs it: the serial body's numbers, and the generic jnp
+    branch's."""
+    from gigapath_tpu.ops.dilated_attention import dilated_attention
+
+    L, sl, r, H, Dh = 128, 32, 2, 4, 16
+    q, k, v = (
+        jnp.asarray(rng.normal(size=(2, L, H * Dh)), jnp.float32) for _ in range(3)
     )
+    mask_kw = (
+        {"valid_len_dyn": jnp.asarray([L, 77], jnp.int32)}
+        if rl == "traced" else {"real_len": rl}
+    )
+    new, old = _branch_both_bodies(q, k, v, sl, r, H, **mask_kw)
+    _assert_same_branch(new, old)
+    if rl != "traced":
+        x4 = lambda x: x.reshape(2, L, H, Dh)
+        ref = dilated_attention(x4(q), x4(k), x4(v), [sl], [r], valid_len=rl)
+        np.testing.assert_allclose(
+            np.asarray(new[0]).reshape(2, L, H, Dh)[:, :rl], np.asarray(ref)[:, :rl],
+            atol=2e-5, rtol=1e-4)
+
+
+def test_gradients_through_the_overlapped_fwd(rng, monkeypatch):
+    """The forward's packed (out, lse) are the backward's residuals: the
+    gradients through the overlapped body are the ones through the serial."""
+    import gigapath_tpu.ops.pallas_dilated as pd
+
+    L, sl, r, rl = 128, 32, 2, 101
+    H, Dh = 4, 16
+    q, k, v = (
+        jnp.asarray(rng.normal(size=(1, L, H * Dh)), jnp.float32) for _ in range(3)
+    )
+
+    def loss(q_, k_, v_):
+        o, _ = pd.dilated_branch_attention(
+            q_, k_, v_, sl, r, H, real_len=rl, interpret=True)
+        return (o * o).sum()
+
+    grads = lambda: jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    assert "dilated_fwd_overlap" in str(jax.make_jaxpr(jax.grad(loss))(q, k, v))
+    g1 = grads()
+    # the planner is the one place that chooses: turn it, and drop the traces
+    # made under the other choice (a trace is cached by the function, not by
+    # what the planner said)
+    with monkeypatch.context() as mp:
+        mp.setattr(pd, "plan_fwd_body",
+                   lambda causal, hb, block: pd.FwdPlan("serial", 1, block))
+        jax.clear_caches()
+        assert "dilated_fwd_overlap" not in str(jax.make_jaxpr(jax.grad(loss))(q, k, v))
+        g0 = grads()
+    jax.clear_caches()
+    for a, b in zip(g1, g0):
+        scale = max(float(jnp.max(jnp.abs(b))), 1e-12)
+        np.testing.assert_allclose(np.asarray(a) / scale, np.asarray(b) / scale, atol=2e-6)
+
+
+@pytest.mark.parametrize(
+    "L,expected",
+    [
+        # the benchmark's slides: 10,240 tiles + the class token, padded
+        (10368, {1: (16, 1024, 1), 2: (8, 1024, 3), 4: (4, 896, 3), 8: (2, 768, 2),
+                 16: (1, 768, 1)}),
+        # a serve bucket: every branch past the second is one segment
+        (4224, {1: (16, 1024, 1), 2: (8, 768, 3), 4: (4, 640, 2), 8: (2, 640, 1),
+                16: (1, 384, 1)}),
+    ],
+)
+def test_fwd_plan_at_the_flagship_schedule(L, expected):
+    """The forward body is chosen from shapes, in one place: at the
+    flagship's E = 768 and 16 heads of 48 every non-causal branch takes the
+    overlapped body, two heads a step where the band's count is even
+    (r1-r8) and one where the band is one head (r16), rows in two chunks;
+    a causal branch takes the serial body."""
+    import gigapath_tpu.ops.pallas_dilated as pd
+    from gigapath_tpu.models.longnet_config import flagship_geometry
+
+    geom = flagship_geometry()
+    H, Dh = geom["heads"], geom["head_dim"]
+    assert (H, Dh) == (16, 48)
+    for sl, r in zip(geom["segment_lengths"], geom["dilated_ratios"]):
+        g, S, gp, m, Mp, block = pd._branch_geometry(L, H * Dh, sl, r)
+        hb = H // r
+        assert (hb, block, Mp // block) == expected[r], (r, hb, block, Mp // block)
+        plan = pd.plan_fwd_body(False, hb, block)
+        assert plan == pd.FwdPlan("overlap", 2 if r < 16 else 1, block // 2), r
+        assert pd.plan_fwd_body(True, hb, block) == pd.FwdPlan("serial", 1, block)
 
 
 def test_pipelined_bwd_fast_small_geometry(rng, monkeypatch):

@@ -249,7 +249,7 @@ def test_flagship_schedule_lowers_without_dense_glue_around_the_copies(merge):
             streaming_fusion=False, interpret=False, flags=pdm.PipelineFlags()))
     x = jax.ShapeDtypeStruct((B, L, H, Dh), jnp.bfloat16)
     text = op.trace(x, x, x).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
-    calls = {"dilated_pack": 0, "dilated_unpack": 0, "dilated_fwd": 0,
+    calls = {"dilated_pack": 0, "dilated_unpack": 0, "dilated_fwd_overlap": 0,
              "dilated_epilogue_fwd": 0}
     glue = 0
     for line, path in _lowered_ops(text):
@@ -264,7 +264,7 @@ def test_flagship_schedule_lowers_without_dense_glue_around_the_copies(merge):
             size = np.prod([int(d) for d in dims.split("x") if d])
             assert size < L * E, (path, line[:200])
     copies = {"epilogue": (0, 1), "dense": (5, 0)}[merge]
-    assert calls == {"dilated_pack": 15, "dilated_fwd": 5,
+    assert calls == {"dilated_pack": 15, "dilated_fwd_overlap": 5,
                      "dilated_unpack": copies[0], "dilated_epilogue_fwd": copies[1]}
     # the dense path's lse scatter is there, and small; the forward through the
     # epilogue holds nothing but the pack calls under these scopes

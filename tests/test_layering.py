@@ -82,5 +82,5 @@ def test_the_carrier_holds_the_attention_switches_and_nothing_else():
     from tools.gigalint.rules import _GL017_FLAGS
 
     assert PipelineFlags._fields == tuple(FLAG_ENV)
-    assert len(FLAG_ENV) == 9
+    assert len(FLAG_ENV) == 7
     assert set(FLAG_ENV.values()) == set(_GL017_FLAGS)

@@ -174,8 +174,7 @@ def _lowered(path: str) -> str:
                 dilated_ratios=[1, 2])),
                 [jax.ShapeDtypeStruct((1, 64, 4, 8), jnp.bfloat16)] * 3)
         if path == "fused_variants_grad":  # the kernel variants that are off by default
-            flags = pd.snapshot_flags()._replace(
-                pipelined_fwd=True, pipelined_bwd=True)
+            flags = pd.snapshot_flags()._replace(pipelined_bwd=True)
             return _text(_grad_of(functools.partial(
                 da.dilated_attention_fused, segment_lengths=[32, 64],
                 dilated_ratios=[1, 2], interpret=True, flags=flags)), _qkv())
@@ -233,7 +232,7 @@ _NAMES = {
     "slide_jnp": ["jit_slide_forward", "dilated_attn", "branch_r1", "branch_r2", "pack",
                   "kernel_fwd", "unpack", "merge"],
     "slide_kernels": ["jit_slide_forward", "dilated_attn", "branch_r1", "branch_r2", "pack",
-                      "kernel_fwd", "merge", "dilated_pack", "dilated_fwd",
+                      "kernel_fwd", "merge", "dilated_pack", "dilated_fwd_overlap",
                       "dilated_epilogue_fwd"],
     "lm_jnp": ["jit_lm_forward", "ssm_mixer", "in_proj", "conv", "ssd_scan", "gate_norm",
                "out_proj", "moe", "router", "dispatch", "experts", "kernel_fwd", "combine",
@@ -249,10 +248,10 @@ _NAMES = {
                      "router", "dispatch", "moe_dispatch", "experts", "gmm", "combine",
                      "moe_combine", "shared_experts", "lm_head"],
     "fused_grad": ["dilated_attn", "branch_r2", "pack", "kernel_fwd", "kernel_dq",
-                   "kernel_dkv", "unpack", "merge", "dilated_pack", "dilated_fwd",
+                   "kernel_dkv", "unpack", "merge", "dilated_pack", "dilated_fwd_overlap",
                    "dilated_dq", "dilated_dkv", "dilated_unpack", "dilated_epilogue_fwd",
                    "dilated_epilogue_bwd"],
-    "fused_variants_grad": ["dilated_attn", "branch_r2", "merge", "dilated_fwd_pipe",
+    "fused_variants_grad": ["dilated_attn", "branch_r2", "merge", "dilated_fwd_overlap",
                             "dilated_dq_pipe", "dilated_dkv_pipe", "dilated_pack",
                             "dilated_unpack", "dilated_epilogue_fwd",
                             "dilated_epilogue_bwd"],
@@ -285,20 +284,24 @@ def test_name_stands_in_the_lowered_text(path, name):
 
 def test_a_branch_holds_its_steps_in_order_of_the_path():
     """``.../dilated_attn/branch_r2/pack|kernel_fwd/<kernel name>/...`` and
+    ``.../dilated_attn/branch_r2/jit(_fwd_call_overlap)`` (the forward kernel,
+    whose own path ``kernel_fwd/dilated_fwd_overlap`` starts anew inside) and
     ``.../dilated_attn/merge/jit(_epilogue_call)``: the scope-keyed reduction
     (benchmarks/lib/scopes.py) matches on this. ``unpack`` is the dense
     fallback's and the backward's: the forward holds none."""
     text = _lowered("slide_kernels")
-    for step, kernel in (("pack", "dilated_pack"), ("kernel_fwd", "dilated_fwd")):
-        assert re.search(
-            rf'"[^"]*/layers_1/self_attn/self_attn\._attend/dilated_attn/branch_r2/{step}/{kernel}/',
-            text)
+    branch = r'"[^"]*/layers_1/self_attn/self_attn\._attend/dilated_attn/branch_r2/'
+    assert re.search(branch + "pack/dilated_pack/", text)
+    assert re.search(branch + r'jit\(_fwd_call_overlap\)"', text)
     assert re.search(
         r'"[^"]*/layers_1/self_attn/self_attn\._attend/dilated_attn/merge/jit\(_epilogue_call\)"', text)
-    # the kernel's own path starts anew inside its jitted function: one
-    # trace and one lowering for all layers
+    # a kernel's own path starts anew inside its jitted function: one trace
+    # and one lowering for all layers (a forward kernel a branch shape, of
+    # which this tiny schedule has two)
+    assert re.search(r'"kernel_fwd/dilated_fwd_overlap/', text)
     assert re.search(r'"dilated_epilogue_fwd/', text)
     assert len(re.findall(r"func.func private @_epilogue_call", text)) == 1
+    assert len(re.findall(r"func.func private @_fwd_call_overlap", text)) == 2
     assert "dilated_unpack" not in text and "/unpack/" not in text
     backward = _lowered("fused_grad")
     for step, kernel in (("unpack", "dilated_unpack"), ("pack", "dilated_pack")):
@@ -328,15 +331,15 @@ def test_the_kernel_table_takes_the_epilogue_for_no_attention_kernel(path, epilo
     """``benchmarks/kernels/dilated_attn.json`` tells an attention kernel by
     what it returns, ``(bf16, f32)``. The merge epilogue returns one array
     in the forward and ``(f32, bf16)`` under differentiation, so of the
-    program's custom calls the table picks the ``dilated_fwd`` ones and no
+    program's custom calls the table picks the ``dilated_fwd*`` ones and no
     other: the epilogue's seconds are never added to the kernels'
     (``dilated_attn_roofline.slide``, ``attn_kernel_time_share.slide``)."""
     table = tables.kernel_table("dilated_attn")
     calls = [line for line in _lowered(path).splitlines() if " custom-call(" in line]
     kernel_of = lambda line: re.search(r'op_name="[^"]*?(\w+)/pallas_call"', line).group(1)
     picked = [kernel_of(line) for line in calls if _picked(table, line)]
-    assert picked and set(picked) == {"dilated_fwd"}
-    assert len(picked) == sum(kernel_of(line) == "dilated_fwd" for line in calls)
+    assert picked and set(picked) == {"dilated_fwd_overlap"}
+    assert len(picked) == sum(kernel_of(line).startswith("dilated_fwd") for line in calls)
     for kernel, count in epilogues.items():
         assert sum(kernel_of(line) == kernel for line in calls) == count, kernel
     if path == "slide_tpu_hlo":
@@ -392,11 +395,14 @@ def test_latent_attention_holds_its_steps_in_order_of_the_path():
 # and the pads, slices and reshapes around them are gone; and at PR 33
 # (2077 / 39 / 4 before): the merge is one epilogue kernel over the packed
 # results (a ``custom_vjp`` of its own around one jitted call a layer), the
-# unpack kernels, the lse scatter and the XLA merge are gone from the forward.
+# unpack kernels, the lse scatter and the XLA merge are gone from the forward;
+# and at PR 35 (2143 / 35 / 6 before): the forward kernel's body holds four
+# (two) chains a step where it held one, each with its own ``jnp.where`` and
+# its own slices, inside one jitted call a branch.
 _PARENT_EQUATIONS = {
     "tile": {"all": 245, "jit": 3, "custom_vjp_call": 0},
     "slide_jnp": {"all": 761, "jit": 17, "custom_vjp_call": 0},
-    "slide_kernels": {"all": 2143, "jit": 35, "custom_vjp_call": 6},
+    "slide_kernels": {"all": 3403, "jit": 139, "custom_vjp_call": 6},
 }
 
 

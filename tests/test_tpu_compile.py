@@ -143,7 +143,8 @@ def test_dilated_attention_at_bench_length(topo, one_chip, schedule, path, grad)
     assert len(named("dilated_unpack")) == (15 if grad else 0)  # dq, dk, dv a branch
     by_result = [c for c in calls if _picked(tables.kernel_table("dilated_attn"), c)]
     by_name = [c for c in calls if _picked(tables.kernel_table("dilated_fwd_by_name"), c)]
-    assert by_result == by_name == named("dilated_fwd") and len(by_name) == 5
+    # every branch of the non-causal schedule takes the overlapped body
+    assert by_result == by_name == named("dilated_fwd_overlap") and len(by_name) == 5
 
 
 def test_fused_grad_ragged_bucket_traced_valid_len(topo, one_chip, schedule):
@@ -204,6 +205,38 @@ def test_merge_epilogue_alone_at_the_padded_length(topo, one_chip, schedule, hea
     # one bf16 result in the forward; statistics first under differentiation
     result = re.match(r"(ROOT )?%[\w.]+ = (\(?\w+)\[", forward[0]).group(2)
     assert result == ("(f32" if grad else "bf16")
+
+
+@pytest.mark.parametrize("branch", range(5))
+@pytest.mark.parametrize("head_dim,tokens", [(DH, N_BENCH), (DH, 4096 + 1), (64, N_BENCH),
+                                             (96, N_BENCH)])
+def test_forward_body_of_each_branch_alone(topo, one_chip, schedule, head_dim, tokens, branch):
+    """The forward body the planner names, on packed arrays of each of the
+    schedule's five branches at L = 10,368 (the benchmark's slides), at
+    L = 4,224 (a serve bucket: smaller blocks, r8 and r16 one key block) and
+    at the wider encoders' heads of 64 and 96: the overlapped body, two
+    heads a step for r1-r8 and one for r16, has to fit the scoped VMEM a
+    kernel has by default (two heads' blocks, their stats and four chains'
+    logit tiles at 1,024 x 512; three heads a step, or the lagged form at a
+    key block of 1,024, do not), and returns the serial body's
+    ``(bf16, f32)`` pair under a name the benchmark's tables find."""
+    from benchmarks.lib import tables
+    from gigapath_tpu.ops import pallas_dilated as pd
+
+    L = -(-tokens // 128) * 128
+    sl, r = schedule[0][branch], schedule[1][branch]
+    g, S, _, _, Mp, block = pd._branch_geometry(L, H * head_dim, sl, r)
+    hb = H // r
+    assert pd.plan_fwd_body(False, hb, block) == pd.FwdPlan(
+        "overlap", 2 if hb > 1 else 1, block // 2)
+    x6 = jax.ShapeDtypeStruct((2, S, r, hb, Mp, head_dim), jnp.bfloat16)
+    calls = _kernel_calls(_compiled(
+        lambda q6, k6, v6, kvlen: pd._packed_forward(
+            q6, k6, v6, kvlen, False, hb, head_dim, block, False),
+        one_chip, x6, x6, x6, jax.ShapeDtypeStruct((2, S, r), jnp.int32)))
+    assert len(calls) == 1 and re.match(r"(ROOT )?%dilated_fwd_overlap[.\d]* = \(bf16\[", calls[0])
+    for table in ("dilated_attn", "dilated_fwd_by_name"):
+        assert _picked(tables.kernel_table(table), calls[0]), table
 
 
 @pytest.mark.parametrize("head_dim,branch", [

@@ -3,8 +3,9 @@
 The repo's contract (tests/conftest.py) is that everything in the slow
 tier has a faster sibling covering the same code path in the default
 tier. The round-5 advisor found the new kernel-flag parity tests broke
-that contract silently: every test exercising GIGAPATH_PIPELINED_ATTN /
-_BWD and the seq-parallel fused routing was slow-only, so
+that contract silently: every test exercising GIGAPATH_PIPELINED_BWD (and
+the forward twin it then had) and the seq-parallel fused routing was
+slow-only, so
 ``pytest -q`` exercised none of the new kernel paths.
 
 This rule makes the contract mechanical, per test file:

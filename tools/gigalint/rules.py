@@ -1262,8 +1262,7 @@ def check_lowprec_casts(project: Project) -> List[Finding]:
 # (ops/pallas_dilated.FLAG_ENV; tests/test_layering.py holds the two
 # equal).
 _GL017_FLAGS = frozenset({
-    "GIGAPATH_PIPELINED_ATTN", "GIGAPATH_PIPELINED_BWD",
-    "GIGAPATH_PIPE_BLOCK_K", "GIGAPATH_PIPE_BWD_BLOCK_K",
+    "GIGAPATH_PIPELINED_BWD", "GIGAPATH_PIPE_BWD_BLOCK_K",
     "GIGAPATH_STREAMING_FUSION", "GIGAPATH_RING_ATTN",
     "GIGAPATH_FOLD_PALLAS", "GIGAPATH_FOLD_BLOCK_Q",
     "GIGAPATH_FOLD_BLOCK_K",

@@ -16,12 +16,12 @@ def env_flag(name):
 def read_variant_flag_by_hand():
     # GL017: a variant flag read that bypasses the caller's snapshot —
     # an explicit flags= argument silently loses to this read
-    return os.environ.get("GIGAPATH_PIPELINED_ATTN", "") == "1"
+    return os.environ.get("GIGAPATH_PIPELINED_BWD", "") == "1"
 
 
 def block_override_by_hand():
     # GL017: a block flag via os.getenv
-    return int(os.getenv("GIGAPATH_PIPE_BLOCK_K", "0") or 0)
+    return int(os.getenv("GIGAPATH_PIPE_BWD_BLOCK_K", "0") or 0)
 
 
 def helper_env_flag_read():
