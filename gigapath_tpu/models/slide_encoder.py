@@ -26,7 +26,7 @@ TPU-first deltas:
 from __future__ import annotations
 
 import os
-from typing import Any, List, Optional
+from typing import Any, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -87,7 +87,7 @@ class LongNetViT(nn.Module):
     drop_path_rate: float = 0.1
     norm_eps: float = 1e-6
     mlp_ratio: float = 4.0
-    segment_length: Optional[List[int]] = None
+    segment_length: Optional[Sequence[int]] = None
     dilated_ratio: str = "[1, 2, 4, 8, 16]"
     dtype: Any = None
     checkpoint_activations: bool = False
@@ -202,6 +202,13 @@ class LongNetViT(nn.Module):
             else:
                 outcomes.append(norm(h)[:, 0])
         return outcomes
+
+    def __post_init__(self):
+        # a tuple, whatever sequence the caller gave: the module hashes by its
+        # fields, and ``pipeline.slide_forward_fn`` keeps one function a model
+        if self.segment_length is not None:
+            object.__setattr__(self, "segment_length", tuple(self.segment_length))
+        super().__post_init__()
 
 
 def _arch(defaults: dict, kwargs: dict) -> LongNetViT:

@@ -12,9 +12,12 @@
   events, folded into a canonical per-run ledger JSON that
   ``scripts/ledger_diff.py`` diffs across commits;
 - :mod:`gigapath_tpu.obs.spans` — nestable ``span`` context manager
-  (monotonic wall time, optional device fence, per-host rank tag) plus
-  the ``jax.profiler`` trace/annotate passthroughs (the GL010-sanctioned
-  ``start_trace``/``stop_trace`` entry points live here);
+  (a ``perf_counter_ns`` start and end, optional device fence, per-host
+  rank tag; ``record()`` keeps the spans in memory with their parent,
+  and JAX's trace / lower / compile phases as children of the span that
+  paid for them) plus the ``jax.profiler`` trace/annotate
+  passthroughs (the GL010-sanctioned ``start_trace``/``stop_trace`` entry
+  points live here);
 - :mod:`gigapath_tpu.obs.anomaly` — the closed loop: an ``AnomalyEngine``
   taps the event stream, fires detectors (step-time spike, stall,
   unexpected retrace, memory-watermark growth, throughput dip), and

@@ -1,45 +1,63 @@
-"""Nestable host-side spans: honest wall timing as obs events.
+"""Nestable host-side spans: where the host was, and for how long.
 
-A ``span`` brackets a region of driver code and lands one ``span`` event
-(schema v1) in the run's JSONL when it closes:
+A ``span`` brackets a region of driver code. When it closes it goes, by one
+exit path and with one interval, to whatever is listening:
 
     with span("epoch", runlog, epoch=3):
         with span("step", runlog, fence=True) as sp:
             out = step_fn(params, batch)
             sp.fence(out)          # block_until_ready(out) at span exit
 
-Fields: ``name``, ``path`` (dotted nesting, e.g. ``epoch/step``),
-``depth``, ``dur_s`` (``time.monotonic`` delta), ``fenced``, ``rank``
-(``jax.process_index()`` for multi-host skew analysis —
-``scripts/obs_report.py`` folds per-rank spans into a straggler table),
-plus any free-form keyword fields.
+    with record() as rec:          # in memory; nothing is written
+        run_inference_with_slide_encoder(...)
+    rec.spans                      # [SpanRecord(name, start_ns, end_ns, ...)]
+
+The interval is one read of ``time.perf_counter_ns()`` at entry and one at
+exit: the clock ``benchmarks/lib/trace.py`` ties to the device's timeline, so
+a recorded span can be laid over a profiler trace. Sinks:
+
+- a :class:`Recorder` installed by :func:`record` keeps a
+  :class:`SpanRecord` (``name``, ``start_ns``, ``end_ns`` and the span that
+  caused it as ``parent``: a span with none is a root, a request of
+  ``pipeline.py``'s entries). While it is installed it also listens to
+  ``jax.monitoring`` and files every trace / lowering / backend compile as a
+  ``trace`` / ``lower`` / ``compile`` span under the span that paid for it;
+- a recording ``runlog`` gets one ``span`` event (schema v1): ``name``,
+  ``path`` (dotted nesting, e.g. ``epoch/step``), ``depth``, ``dur_s``
+  (``end_ns - start_ns``), ``fenced``, ``rank`` (``jax.process_index()`` for
+  multi-host skew analysis — ``scripts/obs_report.py`` folds per-rank spans
+  into a straggler table), plus any free-form keyword fields;
+- ``trace=`` mirrors the region into a fleet causal tree
+  (:mod:`gigapath_tpu.obs.reqtrace`).
 
 Why ``fence``: under async dispatch a wall-clock delta around a jitted
 call measures *dispatch*, not execution (gigalint GL008 flags exactly
 that). ``fence=True`` makes the span call ``jax.block_until_ready`` on
 every value registered via :meth:`Span.fence` (or passed directly as
-``fence=value``) before reading the clock, so ``dur_s`` is device truth.
+``fence=value``) before reading the clock, so the span ends when the values
+are ready.
 
-Zero-overhead contract: against a :class:`~gigapath_tpu.obs.runlog.NullRunLog`
-(``GIGAPATH_OBS=0``) a span is a true no-op — no event, no clock reads,
-no ``TraceAnnotation``, and no fence sync (there is no timing consumer,
-and an opt-out run must behave byte-identically minus obs artifacts).
-Spans never touch the traced program either way, so they can add no
-retraces (pinned by tests/test_obs.py).
+Zero-overhead contract: with no recorder installed and against a
+:class:`~gigapath_tpu.obs.runlog.NullRunLog` (``GIGAPATH_OBS=0``) or no
+runlog at all, a span is a true no-op — no event, no clock reads and no
+fence sync (there is no timing consumer, and an opt-out run must behave
+byte-identically minus obs artifacts). Spans never touch the traced program
+either way, so they can add no retraces (pinned by tests/test_obs.py).
 
 This module is also the home of the ``jax.profiler`` passthroughs that
 ``gigapath_tpu.utils.profiling`` used to own (thin shims remain there):
-:func:`trace` captures a full XLA device trace, :func:`annotate` names a
-host region inside one, and ``span(..., annotate=True)`` nests a
-``TraceAnnotation`` so obs spans and profiler traces line up.
+:func:`trace` captures a full XLA device trace and :func:`annotate` names a
+host region inside one.
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
+import itertools
 import threading
 import time
-from typing import Any, List, Optional
+from typing import Any, Dict, List, Optional
 
 
 @contextlib.contextmanager
@@ -137,16 +155,108 @@ def process_index() -> int:
 
 class _SpanStack(threading.local):
     def __init__(self):
-        self.names: List[str] = []
+        self.open: List["Span"] = []
+        self.cache: Optional[str] = None  # the persistent cache's last answer
 
 
 _STACK = _SpanStack()
 
 
+@dataclasses.dataclass(frozen=True)
+class SpanRecord:
+    """One closed span as a :class:`Recorder` keeps it. ``parent`` is the
+    ``id`` of the span that was open around it on its thread (None for a
+    root, and for a compile phase paid for under no span at all)."""
+
+    id: int
+    name: str
+    start_ns: int
+    end_ns: int
+    parent: Optional[int]
+    thread: int
+    fields: Dict[str, Any]
+
+
+# jax.monitoring's duration events (jax/_src/dispatch.py) -> the span filed
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+
+
+class Recorder:
+    """Closed spans, kept in memory in the order they closed (a child before
+    its parent). Installed by :func:`record`; nothing is written anywhere
+    until the caller does so."""
+
+    def __init__(self):
+        self.spans: List[SpanRecord] = []
+        self._ids = itertools.count()
+
+    def _file(self, name: str, start_ns: int, end_ns: int, *, span_id: int,
+              parent: Optional[int], fields: dict) -> None:
+        self.spans.append(SpanRecord(
+            span_id, name, start_ns, end_ns, parent, threading.get_ident(), fields,
+        ))
+
+    def _on_duration(self, event: str, duration: float, fun_name: str = "", **_) -> None:
+        """A compile phase ended on this thread just now: a span of its
+        duration that ends at this instant, under the span open here (on a
+        first call, the entry's ``dispatch``). Nested jitted functions report
+        one by one, the inner inside the outer's interval."""
+        phase = _COMPILE_PHASES.get(event)
+        if phase is None:
+            return
+        end_ns = time.perf_counter_ns()
+        fields = {"fun_name": fun_name}
+        if phase == "compile" and _STACK.cache is not None:
+            fields["cache"], _STACK.cache = _STACK.cache, None
+        # a span opened before this recorder was installed has no id: no parent
+        parent = _STACK.open[-1] if _STACK.open else _NULL_SPAN
+        # JAX times the phase by time.time(): keep it inside the span that paid
+        start_ns = max(end_ns - int(duration * 1e9), parent.start_ns or 0)
+        self._file(phase, start_ns, end_ns, span_id=next(self._ids),
+                   parent=parent.id, fields=fields)
+
+    def _on_event(self, event: str, **_) -> None:
+        # the persistent cache answers inside the backend compile's interval
+        if event.endswith("/cache_hits"):
+            _STACK.cache = "hit"
+        elif event.endswith("/cache_misses"):
+            _STACK.cache = "miss"
+
+
+_RECORDER: Optional[Recorder] = None
+
+
+@contextlib.contextmanager
+def record():
+    """Install a :class:`Recorder` for the enclosed block: every ``span`` of
+    the process lands in it, with or without a runlog, and so does every
+    trace / lowering / compile JAX reports. One at a time."""
+    global _RECORDER
+    from jax import monitoring
+
+    if _RECORDER is not None:
+        raise RuntimeError("a span recorder is already installed")
+    rec = Recorder()
+    monitoring.register_event_duration_secs_listener(rec._on_duration)
+    monitoring.register_event_listener(rec._on_event)
+    _RECORDER = rec
+    try:
+        yield rec
+    finally:
+        _RECORDER = None
+        monitoring.unregister_event_duration_listener(rec._on_duration)
+        monitoring.unregister_event_listener(rec._on_event)
+
+
 class Span:
     """Live span handle yielded by :func:`span`.
 
-    ``dur_s`` is populated at exit (None until then, and always None for
+    ``start_ns`` / ``end_ns`` (``time.perf_counter_ns``) and ``dur_s``, their
+    difference, are populated at exit (None until then, and always None for
     the no-op span), so drivers can reuse the span's measurement::
 
         with span("step", runlog, fence=True) as sp:
@@ -155,12 +265,16 @@ class Span:
         runlog.step(i, wall_s=sp.dur_s, synced=True)
     """
 
-    __slots__ = ("name", "fenced", "dur_s", "_fence_values", "_fields")
+    __slots__ = ("name", "fenced", "start_ns", "end_ns", "dur_s", "id",
+                 "_fence_values", "_fields")
 
     def __init__(self, name: str, fenced: bool):
         self.name = name
         self.fenced = fenced
+        self.start_ns: Optional[int] = None
+        self.end_ns: Optional[int] = None
         self.dur_s: Optional[float] = None
+        self.id: Optional[int] = None        # drawn from the recorder, if one
         self._fence_values: List[Any] = []
         self._fields: dict = {}
 
@@ -195,15 +309,15 @@ def _is_recording(runlog) -> bool:
 
 
 @contextlib.contextmanager
-def span(name: str, runlog=None, *, fence: Any = None, annotate: bool = False,
+def span(name: str, runlog=None, *, fence: Any = None,
          rank: Optional[int] = None, trace=None, **fields):
-    """Nestable timed region emitting one ``span`` event at exit.
+    """Nestable timed region; at exit it goes to the installed recorder, to
+    a recording ``runlog`` as one ``span`` event, and to ``trace``.
 
-    ``fence``: falsy -> no sync (dur_s is host dispatch time, marked
-    ``fenced: false``); ``True`` -> block on values registered via
+    ``fence``: falsy -> no sync (the span ends when the host leaves it,
+    marked ``fenced: false``); ``True`` -> block on values registered via
     ``Span.fence``; any other value -> block on it (plus registered
-    values). ``annotate=True`` additionally wraps the region in a
-    ``jax.profiler.TraceAnnotation`` so it shows up in captured traces.
+    values).
 
     ``rank`` overrides the event's rank tag (default:
     ``jax.process_index()``). The dist dryrun's worker processes use it
@@ -214,16 +328,20 @@ def span(name: str, runlog=None, *, fence: Any = None, annotate: bool = False,
 
     ``trace`` threads a fleet :class:`~gigapath_tpu.obs.reqtrace.TraceContext`:
     at exit the region is MIRRORED into the context's causal tree (same
-    name, same interval, structural span id) in addition to the span
-    event. ``dist/`` library code must pass it (gigalint GL022) so no
-    per-slide region is orphaned from the cross-process timeline; a
-    ``chunk=`` field keys the mirrored span per chunk.
+    name, same interval in seconds, structural span id). ``dist/`` library
+    code must pass it (gigalint GL022) so no per-slide region is orphaned
+    from the cross-process timeline; a ``chunk=`` field keys the mirrored
+    span per chunk. The tree's own intervals are ``time.monotonic`` values,
+    which on Linux is this clock (CLOCK_MONOTONIC; tests/test_obs.py holds
+    the two together).
 
-    Against a ``NullRunLog`` (``GIGAPATH_OBS=0``) the whole thing is a
-    no-op: the yielded span absorbs ``fence``/``note`` calls and nothing
-    is timed, synced, annotated, or written.
+    With no recorder installed and a ``NullRunLog`` (``GIGAPATH_OBS=0``) or
+    no runlog, the whole thing is a no-op: the yielded span absorbs
+    ``fence``/``note`` calls and nothing is timed, synced or written.
     """
-    if not _is_recording(runlog):
+    rec = _RECORDER
+    to_runlog = _is_recording(runlog)
+    if rec is None and not to_runlog:
         yield _NULL_SPAN
         return
 
@@ -233,19 +351,13 @@ def span(name: str, runlog=None, *, fence: Any = None, annotate: bool = False,
     sp = Span(name, fenced=fenced)
     if fence is not None and fence is not True and fence is not False:
         sp._fence_values.append(fence)
-    _STACK.names.append(name)
-    path = "/".join(_STACK.names)
-    depth = len(_STACK.names)
-    annotate_ctx = None
-    if annotate:
-        try:
-            import jax
-
-            annotate_ctx = jax.profiler.TraceAnnotation(name)
-            annotate_ctx.__enter__()
-        except Exception:
-            annotate_ctx = None
-    t0 = time.monotonic()
+    parent = _STACK.open[-1] if _STACK.open else _NULL_SPAN
+    if rec is not None:
+        sp.id = next(rec._ids)
+    _STACK.open.append(sp)
+    path = "/".join(s.name for s in _STACK.open)
+    depth = len(_STACK.open)
+    sp.start_ns = time.perf_counter_ns()
     status = "ok"
     try:
         yield sp
@@ -272,33 +384,36 @@ def span(name: str, runlog=None, *, fence: Any = None, annotate: bool = False,
                 except Exception as e:
                     fence_error = f"{type(e).__name__}: {e}"
                     status = "error"
-            sp.dur_s = round(time.monotonic() - t0, 6)
-            if annotate_ctx is not None:
-                annotate_ctx.__exit__(None, None, None)
+            sp.end_ns = time.perf_counter_ns()
+            sp.dur_s = round((sp.end_ns - sp.start_ns) / 1e9, 6)
             merged = dict(fields)
             merged.update(sp._fields)
-            # caller fields must not shadow the span schema (a collision
-            # would TypeError inside this finally and crash the driver)
-            for reserved in _RESERVED_SPAN_KEYS:
-                if reserved in merged:
-                    merged[f"field_{reserved}"] = merged.pop(reserved)
-            if fence_error is not None:
-                merged["fence_error"] = fence_error
-            # a swallowed fence error is recorded, not raised: without the
-            # span there would be no sync here at all, so surfacing it
-            # would introduce a new failure site the bare driver lacks
-            runlog.event(
-                "span", name=name, path=path, depth=depth, dur_s=sp.dur_s,
-                fenced=sp.fenced,
-                rank=process_index() if rank is None else int(rank),
-                status=status,
-                **merged,
-            )
+            failed = {} if fence_error is None else {"fence_error": fence_error}
+            if rec is not None:
+                rec._file(name, sp.start_ns, sp.end_ns, span_id=sp.id,
+                          parent=parent.id,
+                          fields={**merged, "status": status, **failed})
+            if to_runlog:
+                # caller fields must not shadow the span schema (a collision
+                # would TypeError inside this finally and crash the driver)
+                for reserved in _RESERVED_SPAN_KEYS:
+                    if reserved in merged:
+                        merged[f"field_{reserved}"] = merged.pop(reserved)
+                # a swallowed fence error is recorded, not raised: without the
+                # span there would be no sync here at all, so surfacing it
+                # would introduce a new failure site the bare driver lacks
+                runlog.event(
+                    "span", name=name, path=path, depth=depth, dur_s=sp.dur_s,
+                    fenced=sp.fenced,
+                    rank=process_index() if rank is None else int(rank),
+                    status=status,
+                    **merged, **failed,
+                )
             if trace is not None:
                 # mirror the region into the fleet causal tree; the
                 # context dedups on its structural id, so a retried
                 # region re-announcing itself cannot fork the tree
-                trace.add_span(name, t0, t0 + sp.dur_s,
+                trace.add_span(name, sp.start_ns / 1e9, sp.end_ns / 1e9,
                                chunk=merged.get("chunk"), status=status)
         finally:
-            _STACK.names.pop()
+            _STACK.open.pop()

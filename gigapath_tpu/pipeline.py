@@ -33,7 +33,7 @@ from gigapath_tpu.data.tile_dataset import TileEncodingDataset
 from gigapath_tpu.data.transforms import preprocess_tile
 from gigapath_tpu.models import slide_encoder as slide_encoder_lib
 from gigapath_tpu.models import tile_encoder as tile_encoder_lib
-from gigapath_tpu.obs import console
+from gigapath_tpu.obs import console, span
 from gigapath_tpu.preprocessing.create_tiles_dataset import process_slide
 
 
@@ -119,11 +119,11 @@ def load_tile_slide_encoder(
     return (tile_model, tile_params), (slide_model, slide_params)
 
 
+@functools.lru_cache(maxsize=8)
 def tile_encode_fn(tile_encoder):
     """The jitted tile-batch forward ``encode(params, imgs [B, H, W, 3]) ->
     [B, 1536]`` that :func:`run_inference_with_tile_encoder` runs (params
     ride as an argument, never as 4 GB of inline constants)."""
-
     @jax.jit
     def tile_encode(params, imgs):
         return tile_encoder.apply({"params": params}, imgs)
@@ -131,10 +131,10 @@ def tile_encode_fn(tile_encoder):
     return tile_encode
 
 
+@functools.lru_cache(maxsize=8)
 def slide_forward_fn(slide_encoder_model):
-    """The jitted all-layer slide forward ``(params, tile_embeds [B, N, D]
-    bf16, coords [B, N, 2]) -> per-layer embeddings`` that
-    :func:`run_inference_with_slide_encoder` runs."""
+    """The jitted all-layer slide forward ``(params, tile_embeds [B, N, D] bf16, coords [B, N, 2])
+    -> per-layer embeddings`` that :func:`run_inference_with_slide_encoder` runs."""
     @jax.jit
     def slide_forward(params, tile_embeds, coords):
         return slide_encoder_model.apply(
@@ -154,13 +154,55 @@ def lm_forward_fn(lm):
     would be 3.3 GB of logits a request. A model that counts or predicts more
     returns a dict of named arrays as a third output, and it is handed on as
     it is. One function a model (flax modules hash by their fields), so a
-    second call of the entry traces nothing."""
+    second call of the entry traces nothing; the same holds for the two above."""
 
     @jax.jit
     def lm_forward(params, ids, positions):
         return lm.apply({"params": params}, ids, positions)
 
     return lm_forward
+
+
+# A request through any of the three ``run_inference_with_*`` entries is three
+# halves: ``<entry>_to_device(host batch) -> device arguments``, the jitted
+# function above, ``<entry>_to_host(outputs)``, called in that order by
+# ``<entry>_request`` under the same six spans (``obs/spans.py``; no-ops unless
+# a recorder or a runlog listens): ``request`` > ``prepare`` (host-side
+# shaping), ``h2d`` (ends when the bytes are on the device), ``dispatch`` (the
+# jitted call; JAX's ``trace`` / ``lower`` / ``compile`` beneath it on a first
+# call), ``device_wait`` (ends when the outputs are ready), ``d2h`` (the
+# conversions to numpy).
+
+
+def tile_encoder_to_device(imgs: np.ndarray, batch_size: int = 128) -> tuple:
+    """Host tiles ``[n <= batch_size, H, W, 3]`` -> the device arguments of
+    :func:`tile_encode_fn`: padded to the compiled batch shape, bfloat16."""
+    with span("prepare"):
+        n = imgs.shape[0]
+        if n < batch_size:  # pad to the compiled batch shape, slice after
+            imgs = np.concatenate(
+                [imgs, np.zeros((batch_size - n, *imgs.shape[1:]), imgs.dtype)]
+            )
+    with span("h2d", fence=True) as sp:
+        return (sp.fence(jnp.asarray(imgs, jnp.bfloat16)),)
+
+
+def tile_encoder_to_host(out, n: int) -> np.ndarray:
+    """The first ``n`` embeddings of a batch, float32 on the host."""
+    with span("device_wait", fence=out):
+        pass
+    with span("d2h"):
+        return np.asarray(out if n == out.shape[0] else out[:n], np.float32)
+
+
+def tile_encoder_request(encode, tile_params, imgs: np.ndarray,
+                         batch_size: int = 128) -> np.ndarray:
+    """One batch of host tiles -> ``[n, 1536]`` float32 on the host."""
+    with span("request"):
+        args = tile_encoder_to_device(imgs, batch_size)
+        with span("dispatch"):
+            out = encode(tile_params, *args)
+        return tile_encoder_to_host(out, imgs.shape[0])
 
 
 def run_inference_with_tile_encoder(
@@ -187,13 +229,7 @@ def run_inference_with_tile_encoder(
     for start in range(0, len(dataset), batch_size):
         samples = [dataset[i] for i in range(start, min(start + batch_size, len(dataset)))]
         imgs = np.stack([s["img"] for s in samples])
-        n = imgs.shape[0]
-        if n < batch_size:  # pad to the compiled batch shape, slice after
-            imgs = np.concatenate(
-                [imgs, np.zeros((batch_size - n, *imgs.shape[1:]), imgs.dtype)]
-            )
-        out = encode(tile_params, jnp.asarray(imgs, jnp.bfloat16))
-        embeds.append(np.asarray(out[:n], np.float32))
+        embeds.append(tile_encoder_request(encode, tile_params, imgs, batch_size))
         coords.append(np.stack([s["coords"] for s in samples]))
     return {
         "tile_embeds": np.concatenate(embeds),
@@ -248,6 +284,46 @@ def run_inference_with_slide_encoder_streaming(
     return embeds_to_outputs(session.finalize())
 
 
+def slide_encoder_to_device(tile_embeds: np.ndarray, coords: np.ndarray) -> tuple:
+    """Host tile embeddings ``[N, D]`` or ``[B, N, D]`` and coordinates ->
+    the device arguments of :func:`slide_forward_fn`: float32 to the device,
+    cast to bfloat16 there."""
+    with span("prepare"):
+        tile_embeds, coords = (
+            x if hasattr(x, "ndim") else np.asarray(x) for x in (tile_embeds, coords))
+        if tile_embeds.ndim == 2:
+            tile_embeds, coords = tile_embeds[None], coords[None]
+    with span("h2d", fence=True) as sp:
+        return sp.fence((
+            jnp.asarray(tile_embeds).astype(jnp.bfloat16),
+            jnp.asarray(coords, jnp.float32),
+        ))
+
+
+def slide_encoder_to_host(slide_embeds) -> dict:
+    """Every layer's embedding, float32 on the host, keyed as the reference
+    keys them."""
+    with span("device_wait", fence=slide_embeds):
+        pass
+    with span("d2h"):
+        outputs = {
+            f"layer_{i}_embed": np.asarray(e, np.float32)
+            for i, e in enumerate(slide_embeds)
+        }
+        outputs["last_layer_embed"] = np.asarray(slide_embeds[-1], np.float32)
+        return outputs
+
+
+def slide_encoder_request(forward, slide_params, tile_embeds: np.ndarray,
+                          coords: np.ndarray) -> dict:
+    """One batch of slides' tile embeddings -> all-layer slide embeddings."""
+    with span("request"):
+        args = slide_encoder_to_device(tile_embeds, coords)
+        with span("dispatch"):
+            slide_embeds = forward(slide_params, *args)
+        return slide_encoder_to_host(slide_embeds)
+
+
 def run_inference_with_slide_encoder(
     tile_embeds: np.ndarray,
     coords: np.ndarray,
@@ -258,21 +334,49 @@ def run_inference_with_slide_encoder(
     (reference ``pipeline.py:165-190``)."""
     if slide_params is None:
         slide_encoder_model, slide_params = slide_encoder_model
-    tile_embeds = jnp.asarray(tile_embeds)
-    coords = jnp.asarray(coords, jnp.float32)
-    if tile_embeds.ndim == 2:
-        tile_embeds = tile_embeds[None]
-        coords = coords[None]
+    return slide_encoder_request(
+        slide_forward_fn(slide_encoder_model), slide_params, tile_embeds, coords)
 
-    slide_embeds = slide_forward_fn(slide_encoder_model)(
-        slide_params, tile_embeds.astype(jnp.bfloat16), coords
-    )
-    outputs = {
-        f"layer_{i}_embed": np.asarray(e, np.float32)
-        for i, e in enumerate(slide_embeds)
-    }
-    outputs["last_layer_embed"] = np.asarray(slide_embeds[-1], np.float32)
-    return outputs
+
+def lm_to_device(token_ids: np.ndarray, positions: Optional[np.ndarray] = None) -> tuple:
+    """``token_ids [L]`` or ``[B, L]`` and the rows wanted (the last where
+    none is given) -> the device arguments of :func:`lm_forward_fn`, int32
+    ``ids [B, L]`` and ``positions [B, P]``, and beside them the host's
+    ``positions [B, P]``, which the answer hands back."""
+    with span("prepare"):
+        ids = np.atleast_2d(np.asarray(token_ids)).astype(np.int32)
+        if positions is None:
+            positions = np.full((ids.shape[0], 1), ids.shape[1] - 1)
+        positions = np.broadcast_to(
+            np.atleast_2d(np.asarray(positions)), (ids.shape[0], np.shape(positions)[-1])
+        ).astype(np.int32)
+    with span("h2d", fence=True) as sp:
+        return sp.fence((jnp.asarray(ids), jnp.asarray(positions))), positions
+
+
+def lm_to_host(outputs, positions) -> dict:
+    """The entry's answer on the host; ``positions`` are the rows the logits
+    are for, the host array :func:`lm_to_device` gave."""
+    logits, received, *more = outputs
+    with span("device_wait", fence=outputs):
+        pass
+    with span("d2h"):
+        return {
+            **{name: np.asarray(value) for extras in more for name, value in extras.items()},
+            "logits": np.asarray(logits, np.float32),
+            "positions": positions,
+            "expert_tokens": np.asarray(received),
+        }
+
+
+def lm_request(forward, lm_params, token_ids: np.ndarray,
+               positions: Optional[np.ndarray] = None) -> dict:
+    """One batch of token ids -> the logits at the rows asked for."""
+    with span("request"):
+        args, positions = lm_to_device(token_ids, positions)
+        with span("dispatch"):
+            outputs = forward(lm_params, *args)
+        return lm_to_host(outputs, positions)
 
 
 def run_inference_with_lm(
@@ -294,17 +398,4 @@ def run_inference_with_lm(
     ``'mtp_logits' [B, P, vocab]`` where its prediction module runs)."""
     if lm_params is None:
         lm, lm_params = lm
-    ids = np.atleast_2d(np.asarray(token_ids)).astype(np.int32)
-    if positions is None:
-        positions = np.full((ids.shape[0], 1), ids.shape[1] - 1)
-    positions = np.broadcast_to(
-        np.atleast_2d(np.asarray(positions)), (ids.shape[0], np.shape(positions)[-1])
-    ).astype(np.int32)
-    logits, received, *more = lm_forward_fn(lm)(
-        lm_params, jnp.asarray(ids), jnp.asarray(positions))
-    return {
-        **{name: np.asarray(value) for extras in more for name, value in extras.items()},
-        "logits": np.asarray(logits, np.float32),
-        "positions": positions,
-        "expert_tokens": np.asarray(received),
-    }
+    return lm_request(lm_forward_fn(lm), lm_params, token_ids, positions)

@@ -15,6 +15,7 @@ import io
 import json
 import os
 import sys
+import threading
 import time
 
 import jax
@@ -339,6 +340,162 @@ class TestSpans:
             pass
         assert sp2.dur_s is None
 
+    def test_null_path_reads_no_clock_and_blocks_on_nothing(self, monkeypatch):
+        """No recorder, no recording runlog: not one clock read and no
+        ``block_until_ready``, whatever ``fence`` says."""
+        from gigapath_tpu.obs import spans
+
+        here = threading.get_ident()  # a thread an earlier test left behind is not the null path
+        real_time, real_sync = spans.time, jax.block_until_ready
+
+        class NoClock:
+            def __getattr__(self, name):
+                if threading.get_ident() != here:
+                    return getattr(real_time, name)
+                raise AssertionError(f"time.{name} read on the null path")
+
+        def no_sync(value):
+            if threading.get_ident() != here:
+                return real_sync(value)
+            raise AssertionError("block_until_ready on the null path")
+
+        monkeypatch.setattr(spans, "time", NoClock())
+        monkeypatch.setattr(jax, "block_until_ready", no_sync)
+        x = jnp.ones(2)
+        for runlog in (None, NullRunLog(driver="t", echo=False)):
+            with span("request", runlog):
+                with span("h2d", runlog, fence=True) as sp:
+                    sp.fence(x)
+                with span("device_wait", runlog, fence=x):
+                    pass
+            assert sp is spans._NULL_SPAN and sp.start_ns is None
+
+
+# ---------------------------------------------------------------------------
+# the in-memory recorder: where a span was, and what caused it
+# ---------------------------------------------------------------------------
+
+class TestRecorder:
+    def _two_requests(self):
+        from gigapath_tpu.obs import spans
+
+        x = jnp.ones((4,))  # made (and whatever it compiles, compiled) outside the recorder
+        with spans.record() as rec:
+            for _ in range(2):
+                with span("request"):
+                    with span("prepare"):
+                        pass
+                    with span("h2d", fence=True) as sp:
+                        sp.fence(x)
+                    with span("dispatch"):
+                        with span("inner"):
+                            time.sleep(0.002)
+        return rec.spans
+
+    @staticmethod
+    def _root_of(recorded):
+        """``{span id: the id of the root its chain of parents ends at}``."""
+        by_id = {s.id: s for s in recorded}
+
+        def root(s):
+            return s.id if s.parent is None else root(by_id[s.parent])
+
+        return {s.id: root(s) for s in recorded}
+
+    def test_schema_start_end_and_parent(self):
+        recorded = self._two_requests()
+        by_id = {s.id: s for s in recorded}
+        assert len(by_id) == len(recorded) == 10
+        roots = [s for s in recorded if s.parent is None]
+        assert [s.name for s in roots] == ["request", "request"]
+        root_of = self._root_of(recorded)  # every span lies under one of the two
+        assert sorted(root_of.values()) == sorted([r.id for r in roots] * 5)
+        for s in recorded:
+            assert s.start_ns <= s.end_ns
+            if s.parent is not None:
+                parent = by_id[s.parent]
+                assert parent.start_ns <= s.start_ns and s.end_ns <= parent.end_ns
+        inner = next(s for s in recorded if s.name == "inner")
+        assert by_id[inner.parent].name == "dispatch"
+        assert inner.end_ns - inner.start_ns >= 2_000_000
+
+    def test_self_times_add_up_to_the_roots_duration(self):
+        from benchmarks.lib import host_spans
+
+        recorded = self._two_requests()
+        root_of = self._root_of(recorded)
+        for root in (s for s in recorded if s.parent is None):
+            mine = [s for s in recorded if root_of[s.id] == root.id]
+            total_ns = round(1e9 * sum(host_spans.self_seconds(mine).values()))
+            assert abs(total_ns - (root.end_ns - root.start_ns)) <= len(mine)
+
+    def test_runlog_event_mirror_and_recorder_share_one_interval(self, tmp_path):
+        from gigapath_tpu.obs import spans
+
+        class Mirror:
+            def add_span(self, name, t0, t1, **args):
+                self.got = (name, t0, t1)
+
+        log = RunLog(str(tmp_path / "run.jsonl"), driver="t", echo=False)
+        mirror = Mirror()
+        with spans.record() as rec:
+            with span("fold", log, trace=mirror, chunk=3) as sp:
+                pass
+        (ev,) = read_events(log.path)
+        (kept,) = rec.spans
+        assert (sp.start_ns, sp.end_ns) == (kept.start_ns, kept.end_ns)
+        assert "start_ns" not in ev and "end_ns" not in ev  # nothing reads them there
+        assert ev["dur_s"] == sp.dur_s == round((kept.end_ns - kept.start_ns) / 1e9, 6)
+        assert mirror.got == ("fold", kept.start_ns / 1e9, kept.end_ns / 1e9)
+        assert kept.fields["chunk"] == 3 and kept.fields["status"] == "ok"
+        log.close()
+
+    def test_perf_counter_is_the_monotonic_clock(self):
+        """``serve/`` and ``dist/`` file their own ``add_span`` intervals by
+        ``time.monotonic``; the mirror files ``perf_counter_ns / 1e9`` beside
+        them. On Linux both read CLOCK_MONOTONIC."""
+        gaps = []
+        for _ in range(5):
+            a = time.monotonic()
+            b = time.perf_counter_ns() / 1e9
+            gaps.append(abs(b - a))
+        assert min(gaps) < 1e-3
+
+    def test_compile_phases_are_children_of_the_span_that_paid(self):
+        from jax._src import monitoring
+
+        from gigapath_tpu.obs import spans
+
+        fn = jax.jit(lambda x: jnp.tanh(x) * 3)
+        x = jnp.ones((5, 7))
+        with spans.record() as rec:
+            assert rec._on_duration in monitoring._event_duration_secs_listeners
+            for _ in range(2):
+                with span("request"):
+                    with span("dispatch"):
+                        fn(x)
+        assert rec._on_duration not in monitoring._event_duration_secs_listeners
+        assert rec._on_event not in monitoring._event_listeners
+        first, second = [s for s in rec.spans if s.name == "dispatch"]
+        children = {s.name: s for s in rec.spans if s.parent == first.id}
+        assert set(children) == {"trace", "lower", "compile"}
+        for child in children.values():
+            assert first.start_ns <= child.start_ns <= child.end_ns <= first.end_ns
+            assert "<lambda>" in child.fields["fun_name"]
+        assert not [s for s in rec.spans if s.parent == second.id]  # nothing retraced
+
+    def test_one_recorder_at_a_time_and_none_left_behind(self):
+        from gigapath_tpu.obs import spans
+
+        with spans.record():
+            with pytest.raises(RuntimeError):
+                with spans.record():
+                    pass
+        assert spans._RECORDER is None
+        with span("after") as sp:
+            pass
+        assert sp.dur_s is None  # the null span again
+
 
 # ---------------------------------------------------------------------------
 # zero-overhead contracts (ISSUE 4 acceptance)
@@ -378,6 +535,33 @@ class TestZeroOverhead:
         assert sum(wd.compile_count.values()) == 2
         assert wd.unexpected_retraces == []
         assert list(tmp_path.iterdir()) == [], "obs-off run left artifacts"
+
+    def test_recorder_on_adds_zero_retraces_and_the_same_program(self):
+        """A recorder installed and fenced spans around every call: the
+        function compiles as often as the bare one and lowers to the same
+        text."""
+        from gigapath_tpu.obs import spans
+
+        def make():  # two functions of one name: jit keeps its cache by function
+            def step(params, x):
+                return params["w"] * jnp.sum(x)
+
+            return jax.jit(step)
+
+        params = {"w": jnp.float32(2.0)}
+        buckets = [jnp.ones((1, 128)), jnp.ones((1, 256))]
+        bare = make()
+        for x in buckets * 3:
+            bare(params, x)
+        recorded = make()
+        with spans.record() as rec:
+            for x in buckets * 3:
+                with span("step", fence=True) as sp:
+                    sp.fence(recorded(params, x))
+            text = recorded.lower(params, buckets[0]).as_text()
+        assert bare._cache_size() == recorded._cache_size() == 2
+        assert text == bare.lower(params, buckets[0]).as_text()
+        assert sum(s.name == "compile" for s in rec.spans) == 2
 
     def test_obs_on_instrumented_hlo_is_identical(self, tmp_path):
         """With obs ON, watching + ledgering a function must not alter

@@ -33,7 +33,8 @@ def _tile_forward():
     s = _TILE["img_size"]
     x = jax.ShapeDtypeStruct((2, s, s, 3), jnp.bfloat16)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), x)["params"]
-    return pipeline.tile_encode_fn(model), (params, x)
+    # a function of its own: the entry's cached one may hold a trace made under the other gate
+    return pipeline.tile_encode_fn.__wrapped__(model), (params, x)
 
 
 def _tile_forward_heads_of_64():
@@ -46,7 +47,7 @@ def _tile_forward_heads_of_64():
                               num_heads=2, mlp_ratio=2.0, dtype=jnp.bfloat16)
     x = jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.bfloat16)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), x)["params"]
-    return pipeline.tile_encode_fn(model), (params, x)
+    return pipeline.tile_encode_fn.__wrapped__(model), (params, x)
 
 
 def _slide_forward():
@@ -60,7 +61,7 @@ def _slide_forward():
     x = jax.ShapeDtypeStruct((2, _N_TOKENS, _SLIDE["in_chans"]), jnp.bfloat16)
     c = jax.ShapeDtypeStruct((2, _N_TOKENS, 2), jnp.float32)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), x, c)["params"]
-    return pipeline.slide_forward_fn(model), (params, x, c)
+    return pipeline.slide_forward_fn.__wrapped__(model), (params, x, c)
 
 
 def _lm_forward(length=40, **widths):
