@@ -12,35 +12,35 @@ replacement for that CUDA dependency:
   over K blocks, loop Q) — recomputing probabilities from the saved LSE
   rather than storing the attention matrix.
 
-Performance notes (v5e measurements of earlier rounds, PERFORMANCE.md):
+Performance notes (PERF.md §5 / §6 hold the v5e readings):
 - kernels run on ``[B, H, L, D]`` layout with ``(1, 1, block_q, D)`` blocks —
   the only layout whose trailing block dims satisfy Mosaic's (8, 128)
   tiling rule for head counts > 1; the public API stays ``[B, L, H, D]``
   and the wrapper transposes (XLA folds the relayout into the surrounding
   projection reshapes);
-- the softmax scale is folded into the small q block (``block_q x D``
-  elements) instead of the ``block_q x block_k`` logits — the inner loop is
-  VPU-bound, so per-logit ops are what matter;
-- the online softmax runs in base-2 units (log2(e) folded into the q
-  scale, ``exp2`` in the hot loop — one fewer VPU pass per logit than
+- two forward bodies, one arithmetic (:func:`plan_fwd_body` picks from the
+  mask and the block; the tests hold them equal to the bit): the serial one,
+  a QK^T -> softmax -> PV chain a grid step, and the overlapped one, which
+  cuts the query block's rows into independent chains and emits the next
+  chain's QK^T before the current chain's softmax. At keys of 192 and blocks
+  of 1,024 the serial body took 5.5 us a block, this one 4.55, the MXU's
+  passes 4.09 (PR 37); "the inner loop is VPU-bound" was read at heads of 48;
+- the softmax scale, with log2(e), is folded into the small q block, and the
+  online softmax runs in base 2 (``exp2``: one VPU pass a logit fewer than
   ``exp``); the emitted lse is converted back to natural log;
 - masked slots rely on exp2 underflow instead of a second ``where``: the
   running max is floored at ``M_FLOOR`` so ``exp2(NEG_INF - m)`` is exactly
-  0.0 in fp32, which also makes fully-masked rows produce out=0 and an lse
-  sentinel of ~ -7e19 (ignored by the branch fusion) without extra
-  per-element work;
-- head_dim is NOT padded: a block whose last dim equals the full array dim
-  satisfies TPU tiling, and padding 64 -> 128 lanes would waste 2x MXU
-  work on the contractions;
-- sequence length is zero-padded to the block size with padded *keys masked*
-  in every kernel; ragged per-(batch,head) key counts (``kv_len``) are
-  masked the same way from an SMEM table.
+  0.0 in fp32: fully-masked rows give out=0 and an lse sentinel of ~ -7e19
+  (ignored by the branch fusion) without extra per-element work;
+- head_dim is NOT padded, and sequence length is zero-padded to the block
+  size with padded *keys masked* in every kernel; ragged per-(batch,head)
+  key counts (``kv_len``) are masked the same way from an SMEM table.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -347,18 +347,18 @@ def _pad_seg(x: jnp.ndarray, M: int) -> jnp.ndarray:
     return jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, M - x.shape[3]), (0, 0)))
 
 
-def _fwd_impl(q, k, v, kv_lens, causal, scale, block_q, block_k, interpret):
+def _fwd_impl(q, k, v, kv_lens, causal, scale, block_q, block_k, interpret, body=None):
     """Segment-batched flash forward on [B, H, S, M, D] -> (out, lse [B,H,S,M]).
 
     Each of the S segments attends independently (block-diagonal attention);
-    the segment axis is a grid dimension, so segmented layouts coming from
-    dilated attention need no batch-axis reshuffling. ``k`` / ``v`` may carry
-    ``H / group`` heads (grouped KV heads): the K/V index map sends query head
-    ``h`` to KV head ``h // group``, so a KV head is read from where it lies
-    and never repeated in memory. ``v`` may be narrower or wider than ``q`` and
-    ``k`` (latent attention: keys of 192 beside values of 128): the value
-    block, the output and the accumulator then take ``v``'s width, and nothing
-    is padded to the keys'.
+    the segment axis is a grid dimension. ``k`` / ``v`` may carry ``H / group``
+    heads (grouped KV heads): the K/V index map sends query head ``h`` to KV
+    head ``h // group``, so a KV head is read from where it lies and never
+    repeated in memory. ``v`` may be narrower or wider than ``q`` and ``k``
+    (latent attention: keys of 192 beside values of 128): the value block, the
+    output and the accumulator then take ``v``'s width. ``body``: ``None`` for
+    the body :func:`plan_fwd_body` names; tests and probes pass ``"serial"``
+    or a :class:`FwdPlan` to hold the bodies to each other.
     """
     B, H, S, Mq, D = q.shape
     Mk, Dv = k.shape[3], v.shape[4]
@@ -370,10 +370,10 @@ def _fwd_impl(q, k, v, kv_lens, causal, scale, block_q, block_k, interpret):
     nq, nk = Mqp // block_q, Mkp // block_k
     kvlen = _kvlen_array(kv_lens, B, H, S, Mk)
 
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k,
-    )
+    plan = plan_fwd_body("causal" if causal else None, block_q, nq * nk) if body is None else body
+    if plan != "serial" and plan.body == "overlap":  # else the serial call below, as it always was
+        return _fwd_overlap(qp, kp, vp, kvlen, Mq, causal, scale, block_q, block_k, plan.rows, interpret)
+    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal, block_q=block_q, block_k=block_k)
     def kv_index(b, h, s, i, j):
         if causal:  # past the diagonal: the block already there, so no new copy
             j = jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
@@ -761,3 +761,244 @@ def pallas_flash_attention(
         kv_lens, is_causal, interpret, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, scale, q5, k5, v5
     )
     return out[:, :, 0].transpose(0, 2, 1, 3), lse[:, :, 0]
+
+
+# ---------------------------------------------------------------------------
+# the overlapped forward body: causal flash and the core over selected keys
+# ---------------------------------------------------------------------------
+
+
+class FwdPlan(NamedTuple):
+    """Which forward body a call takes, from its mask and its shapes alone."""
+
+    body: str  # "overlap" | "serial": the kernel is <name>_overlap or <name>
+    rows: int  # query rows a chain (one QK^T -> softmax -> PV)
+
+
+# the overlapped call's three step tables lie in SMEM, 12 bytes a visited pair of
+# the 1 MiB a v5e core has: a causal 262,144 tokens (65,536 pairs of blocks, 32,896
+# at or below the diagonal) compile for it, 524,288 tokens (131,328) do not
+_MAX_BLOCK_PAIRS = 1 << 16
+
+
+def plan_fwd_body(mask: Optional[str], block_q: int, pairs: int) -> FwdPlan:
+    """The forward body of a call whose query blocks hold ``block_q`` rows and
+    whose grid holds ``pairs`` (query block, key block) pairs, visited or not,
+    whatever the heads' number, grouping and widths (one head a step: at
+    1,024 x 1,024 and keys of 192 a second head's blocks leave the default
+    16 MiB of scoped VMEM, PERF.md §6 PR 37).
+
+    ``mask`` "causal" (an iota compare on the blocks that straddle the
+    diagonal) or "selection" (an int8 tile of chosen keys on every visited
+    block): the overlapped body over the visited blocks alone, the block's
+    rows in up to four chains of at least 128 (under that a chain streams
+    fewer rows past a key tile than the tile took to load). ``None`` (every
+    key up to the valid count: the slide encoder's undilated and head-major
+    branches) and a grid past the step tables' room stay on the serial body
+    and on the text it always lowered to."""
+    if mask is None or pairs > _MAX_BLOCK_PAIRS:
+        return FwdPlan("serial", block_q)
+    return FwdPlan("overlap", max(block_q // 4, min(block_q, LANES)))
+
+
+def _fwd_kernel_overlap(qi_ref, kj_ref, last_ref, q_ref, k_ref, v_ref, aux_ref, o_ref, *rest,
+                        scale, mask, block_q, block_k, rows, nk):
+    """The overlapped forward: grid ``(..., steps)`` over the visited (query
+    block, key block) pairs, which the three prefetched tables name row by
+    row (``last_ref``: 1 on a row's last pair); blocks ``(1, ..., 1, block,
+    D)``, one head a step as :func:`_fwd_kernel`.
+
+    The serial body is one MXU -> VPU -> MXU chain a step (QK^T, softmax,
+    PV) and steps through the key blocks above the diagonal too, each for
+    nothing. This body cuts the query block's rows into ``block_q / rows``
+    independent chains and emits the next chain's QK^T before the current
+    chain's softmax, so that a softmax has a product beside it that does not
+    wait for it (the order of emission is what the scheduler follows:
+    PERF.md §6, PR 35). The q block is scaled once a row of key blocks, into
+    a scratch of its own type. Chains cut rows; the keys a chain leaves out
+    are those no row of it may see, whose ``p`` was an exact zero: a row's
+    operations and their order are the serial body's, to the bit.
+
+    ``mask``: ``None`` | ``"causal"`` (``aux_ref``: the valid-key counts in
+    SMEM, one per leading grid index; the iota compare only on blocks that
+    straddle the diagonal, none wholly below it) | ``"selection"``
+    (``aux_ref``: the ``(1, block_q, block_k)`` int8 tile of chosen keys,
+    nothing above the diagonal, widened a chain at a time). With a mask and
+    ``block_q == block_k`` the block on the diagonal is cut a chain: rows
+    ``r0 .. r0 + rows`` of it take the keys up to column ``r0 + rows``.
+    ``rest``: the ``(1, ..., block_q, LANES)`` lse block where the call writes
+    one, then the running max, sum, accumulator and the scaled q block."""
+    *lse_ref, m_ref, l_ref, acc_ref, qs_ref = rest
+    lead = (0,) * (len(q_ref.shape) - 2)
+    ids = tuple(pl.program_id(d) for d in range(len(lead)))
+    t = pl.program_id(len(lead))
+    i, j = qi_ref[t], kj_ref[t]
+    chains = range(0, block_q, rows)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, M_FLOOR)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        # log2(e) folded into the scale: exp2 instead of exp in the hot loop
+        qs_ref[:] = (q_ref[lead].astype(jnp.float32) * (scale * LOG2E)).astype(qs_ref.dtype)
+
+    def logits(r0, diagonal):
+        # the aligned diagonal block: no row of the chain sees a later column
+        n = min(block_k, round_up(r0 + rows, LANES)) if diagonal and block_q == block_k \
+            else block_k
+        return jax.lax.dot_general(
+            qs_ref[pl.ds(r0, rows), :], k_ref[lead + (pl.ds(0, n), slice(None))],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        )  # [rows, n], in log2 units
+
+    def consume(r0, s, v, diagonal, ragged):
+        rs, n = pl.ds(r0, rows), s.shape[1]
+        # selects, not additive biases, and all before the running max
+        # (_fwd_kernel says why); M_FLOOR under the max keeps p at exactly 0
+        # in a row that has no key yet
+        if mask == "selection":
+            s = jnp.where(aux_ref[0, rs, pl.ds(0, n)].astype(jnp.int32) != 0, s, NEG_INF)
+        elif diagonal:
+            cols = jax.lax.broadcasted_iota(jnp.int32, (rows, n), 1) + j * block_k
+            at = jax.lax.broadcasted_iota(jnp.int32, (rows, n), 0) + (i * block_q + r0)
+            s = jnp.where(cols > at, NEG_INF, s)
+        if ragged:
+            col_ok = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) + j * block_k < aux_ref[ids]
+            s = jnp.where(col_ok, s, NEG_INF)
+        m_prev = m_ref[rs, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp2(s - m_new)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v[:n], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if nk == 1:  # no online carry: no rescale of what is still zero
+            l_new = jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[rs, :] = pv
+        else:
+            alpha = jnp.exp2(m_prev - m_new)
+            l_new = l_ref[rs, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[rs, :] = acc_ref[rs, :] * alpha + pv
+        # single-lane stats stores
+        m_ref[rs, :1] = m_new
+        l_ref[rs, :1] = l_new
+
+    def step(diagonal=False, ragged=False):
+        v = v_ref[lead]
+        if ragged:  # masked value rows can be garbage, and 0 * NaN = NaN in PV
+            row_ok = (
+                jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0) + j * block_k
+                < aux_ref[ids]
+            )
+            v = jnp.where(row_ok, v, 0)
+        nxt = logits(chains[0], diagonal)
+        for n, r0 in enumerate(chains):
+            cur = nxt
+            if n + 1 < len(chains):
+                nxt = logits(chains[n + 1], diagonal)
+            consume(r0, cur, v, diagonal, ragged)
+
+    # which form of the step a block takes: (on the diagonal?, where) x (some
+    # keys past the valid count?, where)
+    if mask is None:
+        places = [(False, True)]
+    else:  # only a block that straddles the diagonal holds a pair to mask
+        straddles = (j + 1) * block_k - 1 > i * block_q
+        places = [(False, ~straddles), (True, straddles)]
+    if mask == "selection":
+        counts = [(False, True)]
+    else:
+        kv = aux_ref[ids]
+        counts = [(False, (j + 1) * block_k <= kv),
+                  (True, (j * block_k < kv) & ((j + 1) * block_k > kv))]
+    for diagonal, here in places:
+        for ragged, so in counts:
+            pl.when(here & so)(functools.partial(step, diagonal=diagonal, ragged=ragged))
+
+    @pl.when(last_ref[t] == 1)
+    def _finalize():
+        safe_l = jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[lead] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+        if lse_ref:  # natural log from the base-2 stats, at LANES width
+            lse_ref[0][lead] = jnp.broadcast_to(
+                (m_ref[:, :1] + jnp.log2(safe_l)) * LN2, (block_q, LANES)
+            )
+
+
+def _visited_steps(nq, nk, block_q, block_k, masked):
+    """``(qi, kj, last)`` int32 ``[steps]``: the (query block, key block) pairs a
+    call computes, row by row, and a 1 on each row's last pair: every pair
+    without a mask; with one, the pairs with a key at or below the diagonal."""
+    qi, kj = np.divmod(np.arange(nq * nk, dtype=np.int32), np.int32(nk))
+    if masked:
+        keep = kj * block_k < (qi + 1) * block_q
+        qi, kj = qi[keep], kj[keep]
+    last = np.append(qi[1:] != qi[:-1], True).astype(np.int32)
+    return jnp.asarray(qi), jnp.asarray(kj), jnp.asarray(last)
+
+
+def fwd_call_overlap(q, k, v, aux, *, mask, name, scale, block_q, block_k, rows, lse,
+                     interpret):
+    """The overlapped forward over ``q [*lead, Mq, D]``, ``k [*lead_kv, Mk,
+    D]``, ``v [*lead_kv, Mk, Dv]`` (``Mq`` / ``Mk`` whole blocks; the second
+    leading axis the heads, ``k`` / ``v`` with a divisor of ``q``'s), ``aux``
+    the mask's operand (:func:`_fwd_kernel_overlap`): ``out [*lead, Mq, Dv]``
+    and, with ``lse``, the ``[*lead, Mq, LANES]`` float32 log-sum-exp. The call
+    is ``<name>_overlap``: the name in a trace says which body ran."""
+    *lead, Mq, D = q.shape
+    Mk, Dv = k.shape[-2], v.shape[-1]
+    group = q.shape[1] // k.shape[1]
+    n, nk = len(lead), Mk // block_k
+    tables = _visited_steps(Mq // block_q, nk, block_q, block_k, mask is not None)
+
+    def spec(block, width, table, kv_head=False):
+        def index(*args):  # grid indices, then the prefetched tables
+            ids, t = list(args[:n]), args[n]
+            if kv_head and group > 1:
+                ids[1] = ids[1] // group
+            return (*ids, args[n + 1 + table][t], 0)
+
+        return pl.BlockSpec((1,) * n + (block, width), index, memory_space=pltpu.VMEM)
+
+    if mask == "selection":  # one [Mq, Mk] selection a batch row, for every head
+        aux_spec = pl.BlockSpec(
+            (1, block_q, block_k), lambda *args: (args[0], args[n + 1][args[n]], args[n + 2][args[n]]),
+            memory_space=pltpu.VMEM)
+    else:  # the whole table of valid-key counts; indexed by program_id
+        aux_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
+    kernel = functools.partial(
+        _fwd_kernel_overlap, scale=scale, mask=mask, block_q=block_q, block_k=block_k,
+        rows=rows, nk=nk)
+    with jax.named_scope("kernel_fwd"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(*lead, tables[0].shape[0]),
+                in_specs=[spec(block_q, D, 0), spec(block_k, D, 1, True),
+                          spec(block_k, Dv, 1, True), aux_spec],
+                out_specs=[spec(block_q, Dv, 0)] + ([spec(block_q, LANES, 0)] if lse else []),
+                scratch_shapes=[
+                    pltpu.VMEM((block_q, LANES), jnp.float32),
+                    pltpu.VMEM((block_q, LANES), jnp.float32),
+                    pltpu.VMEM((block_q, Dv), jnp.float32),
+                    pltpu.VMEM((block_q, D), q.dtype),
+                ],
+            ),
+            out_shape=[jax.ShapeDtypeStruct((*lead, Mq, Dv), q.dtype)]
+            + ([jax.ShapeDtypeStruct((*lead, Mq, LANES), jnp.float32)] if lse else []),
+            interpret=interpret,
+            name=f"{name}_overlap",
+        )(*tables, q, k, v, aux)
+
+
+def _fwd_overlap(qp, kp, vp, kvlen, Mq, causal, scale, block_q, block_k, rows, interpret):
+    """:func:`_fwd_impl`'s overlapped arm, over its padded arrays. Down here and
+    not inside it: a kernel's bytecode holds its call stack's line numbers, and
+    with ``_fwd_impl``'s serial call left on its lines the non-causal paths
+    lower to the text they always had, to the byte (PERF.md §6, PR 37)."""
+    out, lse = fwd_call_overlap(
+        qp, kp, vp, kvlen, mask="causal" if causal else None, name="flash_fwd", scale=scale,
+        block_q=block_q, block_k=block_k, rows=rows, lse=True, interpret=interpret)
+    return out[:, :, :, :Mq], lse[:, :, :, :Mq, 0]

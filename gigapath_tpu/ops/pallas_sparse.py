@@ -13,9 +13,12 @@
   it than are wanted, ``log2 L`` more passes find the column up to which the
   equal ones are taken. Exact, no sort. Only the columns at or below the
   block's last row are read.
-- ``sparse_attn``: the flash forward of :mod:`gigapath_tpu.ops.pallas_flash`
-  for one segment, with an int8 ``[block_q, block_k]`` tile of the selection
-  in the causal mask's place (the selection has nothing above the diagonal).
+- ``sparse_attn``: no body of its own. The call is
+  :func:`gigapath_tpu.ops.pallas_flash.fwd_call_overlap` with the mask mode
+  "selection": the flash forward's overlapped body over the blocks at or
+  below the diagonal, an int8 ``[block_q, block_k]`` tile of the selection in
+  the causal compare's place (the selection has nothing above the diagonal)
+  and no lse output; the kernel is ``sparse_attn_overlap``.
 
 Forward only.
 """
@@ -30,7 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from gigapath_tpu.ops.common import round_up
-from gigapath_tpu.ops.pallas_flash import LANES, LOG2E, M_FLOOR, NEG_INF
+from gigapath_tpu.ops.pallas_flash import LANES, fwd_call_overlap, plan_fwd_body
 
 INT_MIN = -(2 ** 31)
 
@@ -186,81 +189,24 @@ def index_select_fwd(scores, topk, *, rows=SELECT_ROWS, chunk=SELECT_CHUNK, inte
 
 
 # ----------------------------------------------------------------------- core
-def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref,
-                 *, scale, block_q, block_k):
-    i, j = pl.program_id(2), pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, M_FLOOR)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    @pl.when(j * block_k < (i + 1) * block_q)
-    def _step():
-        # the scale, with log2(e), folded into the small q block; exp2 in the loop
-        q = (q_ref[0, 0].astype(jnp.float32) * (scale * LOG2E)).astype(q_ref.dtype)
-        s = jax.lax.dot_general(q, k_ref[0, 0], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = jnp.where(mask_ref[0].astype(jnp.int32) != 0, s, NEG_INF)
-        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
-        # M_FLOOR under the running max: a row with no key yet keeps p at exactly 0
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp2(s - m_new)
-        alpha = jnp.exp2(m_prev - m_new)
-        v = v_ref[0, 0]
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[:, :1] = m_new
-        l_ref[:, :1] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-
-    @pl.when(j == pl.num_programs(3) - 1)
-    def _finalize():
-        o_ref[0, 0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
-
-
 def sparse_attn_fwd(q, k, v, mask, *, scale, block_q=ATTN_BLOCK_Q, block_k=ATTN_BLOCK_K,
-                    interpret=False):
+                    interpret=False, body=None):
     """``q, k [B, L, H, D]``, ``v [B, L, H, Dv]``, ``mask [B, L, L]`` int8 with
-    nothing above the diagonal -> ``[B, L, H, Dv]``."""
+    nothing above the diagonal -> ``[B, L, H, Dv]``. ``body``: a
+    ``pallas_flash.FwdPlan`` in the planner's place (tests and probes)."""
     B, L, H, D = q.shape
-    Dv = v.shape[-1]
     block_q = min(block_q, round_up(L, LANES))
     block_k = min(block_k, round_up(L, LANES))
     Lq, Lk = round_up(L, block_q), round_up(L, block_k)
+    plan = body or plan_fwd_body("selection", block_q, (Lq // block_q) * (Lk // block_k))
+    assert plan.body == "overlap", plan  # the serial flash body reads no selection
 
     def heads_first(x, length):
         return jnp.pad(x, ((0, 0), (0, length - L), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
 
-    maskp = jnp.pad(mask, ((0, 0), (0, Lq - L), (0, Lk - L)))
-
-    def diag(i, j):   # above the diagonal: the tile already there, so no new copy
-        return jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
-
-    kernel = functools.partial(_attn_kernel, scale=scale, block_q=block_q, block_k=block_k)
-    with jax.named_scope("kernel_fwd"):
-        out = pl.pallas_call(
-            kernel,
-            grid=(B, H, Lq // block_q, Lk // block_k),
-            in_specs=[
-                pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, h, diag(i, j), 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, i, j: (b, h, diag(i, j), 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, block_q, block_k), lambda b, h, i, j: (b, i, diag(i, j)),
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, i, j: (b, h, i, 0),
-                                   memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((B, H, Lq, Dv), q.dtype),
-            scratch_shapes=[
-                pltpu.VMEM((block_q, LANES), jnp.float32),
-                pltpu.VMEM((block_q, LANES), jnp.float32),
-                pltpu.VMEM((block_q, Dv), jnp.float32),
-            ],
-            interpret=interpret,
-            name="sparse_attn",
-        )(heads_first(q, Lq), heads_first(k, Lk), heads_first(v, Lk), maskp)
+    out, = fwd_call_overlap(
+        heads_first(q, Lq), heads_first(k, Lk), heads_first(v, Lk),
+        jnp.pad(mask, ((0, 0), (0, Lq - L), (0, Lk - L))),
+        mask="selection", name="sparse_attn", scale=scale, block_q=block_q, block_k=block_k,
+        rows=plan.rows, lse=False, interpret=interpret)
     return out[:, :, :L].transpose(0, 2, 1, 3)

@@ -223,6 +223,39 @@ def test_core_attends_to_the_selected_keys_and_to_no_other():
         assert bool(jnp.abs(moved[0, t] - got[0, t]).max() > 1e-3) is moves
 
 
+@pytest.mark.parametrize("window", [3, 40])
+def test_core_passes_a_key_block_that_holds_none_of_a_rows_keys(window):
+    """A selection of each query's last ``window`` keys: for the second and
+    third query blocks of 128 the earlier key blocks are visited (other heads
+    of other selections would need them) and hold no selected key of any of
+    their rows. There the running max stays at ``M_FLOOR``, every ``p`` is
+    exactly 0 and nothing reaches the sum or the accumulator: the result is
+    the softmax over the window alone, at the kernel's bfloat16 operands to
+    the jnp tier's rounding and, rows in two chains or four, the same bits."""
+    from gigapath_tpu.ops import pallas_flash as pf
+    from gigapath_tpu.ops.pallas_sparse import sparse_attn_fwd
+
+    rng = np.random.default_rng(4)
+    B, L, H = 1, 300, 2
+    t = np.arange(L)
+    mask = jnp.asarray(((t[None, :] <= t[:, None]) & (t[None, :] > t[:, None] - window))[None],
+                       jnp.int8)
+    q, k = (jnp.asarray(rng.standard_normal((B, L, H, 24)) * 3, jnp.bfloat16) for _ in range(2))
+    v = jnp.asarray(rng.standard_normal((B, L, H, 16)), jnp.bfloat16)
+    # the empty blocks' keys would win every row's max by far if a p leaked
+    k = k.at[:, :128].multiply(8.0)
+    v = v.at[:, :128].add(100.0)
+    want = sparse_index.sparse_attention(*(x.astype(jnp.float32) for x in (q, k, v)), mask,
+                                         scale=0.2, use_pallas=False)
+    got = sparse_attn_fwd(q, k, v, mask, scale=0.2, block_q=128, block_k=128, interpret=True)
+    np.testing.assert_allclose(np.asarray(got, np.float32)[:, 128 + window:],
+                               np.asarray(want)[:, 128 + window:], atol=0.06)
+    assert float(jnp.abs(got[:, 128 + window:].astype(jnp.float32)).max()) < 10  # no v + 100 in it
+    four = sparse_attn_fwd(q, k, v, mask, scale=0.2, block_q=128, block_k=128, interpret=True,
+                           body=pf.FwdPlan("overlap", 32))
+    assert int((np.asarray(got, np.float32) != np.asarray(four, np.float32)).sum()) == 0
+
+
 def test_below_index_topk_the_layer_is_dense_causal_latent_attention():
     """At ``L <= index_topk`` every earlier key is selected: the same weights
     under A.X-K1's module (which has no indexer and ignores its parameters)

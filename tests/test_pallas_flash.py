@@ -344,15 +344,112 @@ def test_unequal_widths_are_forward_only_on_the_kernel_tier(rng):
     assert g.shape == q.shape and np.isfinite(g).all()
 
 
-def test_equal_widths_grouped_causal_lowers_to_the_parents_text():
-    """Granite's side of the shared kernel: grouped KV heads, a causal mask
-    and a scale, v as wide as k. The wrapper lowers to the text it lowered to
-    before the value block got a width of its own (sha256 taken from commit
-    f77107b's tree, same JAX)."""
-    import hashlib
+def _differing(a, b):
+    return int((np.asarray(a, np.float32) != np.asarray(b, np.float32)).sum())
 
-    q = jax.ShapeDtypeStruct((1, 256, 4, 64), jnp.bfloat16)
-    k = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.bfloat16)
-    text = jax.jit(lambda q, k, v: flash(q, k, v, is_causal=True, scale=0.1)).lower(q, k, k).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == (
-        "49c4274addcbe72f4303efbe204ee5e57350c867ac4cf57dda8ed365bfdd64cc")
+
+# (B, H, H_kv, L, D, Dv, block_q, block_k, kv_lens a (batch, head), dtype)
+_BODY_CASES = {
+    # Granite's side at the shape whose serial text was pinned until PR 37
+    "grouped_equal_widths_256": (1, 4, 2, 256, 64, 64, 128, 128, None, jnp.bfloat16),
+    "grouped_equal_widths_three_blocks": (2, 8, 2, 300, 32, 32, 128, 128, None, jnp.float32),
+    "keys_192_values_128": (1, 2, 2, 300, 192, 128, 128, 128, None, jnp.bfloat16),
+    "unaligned_length": (1, 2, 2, 333, 48, 48, 128, 128, None, jnp.float32),
+    "ragged_kv_lens": (2, 2, 1, 256, 32, 32, 128, 128, (7, 256, 0, 130), jnp.bfloat16),
+    "single_key_block": (1, 2, 2, 100, 32, 32, 128, 128, None, jnp.bfloat16),
+    # blocks of 256: a chain of 128 rows on the diagonal block takes 128 of its 256 keys
+    "diagonal_block_cut_a_chain": (1, 2, 1, 600, 32, 32, 256, 256, None, jnp.bfloat16),
+    "diagonal_block_cut_a_chain_ragged": (1, 2, 2, 600, 48, 32, 256, 256, (600, 300),
+                                          jnp.float32),
+    "block_q_twice_block_k": (1, 2, 1, 384, 32, 32, 256, 128, None, jnp.bfloat16),
+    "block_k_twice_block_q": (1, 2, 1, 384, 32, 32, 128, 256, None, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", _BODY_CASES)
+@pytest.mark.parametrize("chains", ["planned", 2, 4])
+def test_planned_causal_body_is_the_serial_body_to_the_bit(rng, case, chains):
+    """The overlapped body cuts rows, steps through the visited blocks alone and
+    leaves out only compares that were identities and keys whose ``p`` was an
+    exact zero: ``out`` and ``lse`` equal the serial body's in every element,
+    at the planner's chains and at two and four a block. The first case takes
+    the place of ``test_equal_widths_grouped_causal_lowers_to_the_parents_
+    text`` (the serial causal text's sha256): that call now takes the
+    overlapped body by design, and is held to the serial one here."""
+    from gigapath_tpu.ops import pallas_flash as pf
+
+    B, H, Hkv, L, D, Dv, bq, bk, kv_lens, dtype = _BODY_CASES[case]
+    if chains == 4 and dtype == jnp.float32:
+        # a float32 product of 32 rows takes another routine of the CPU's (one ulp
+        # in ``out``; bfloat16 operands multiply exactly): chains of 64 rows there
+        bq, bk = 2 * bq, 2 * bk
+    q = jnp.asarray(rng.normal(size=(B, H, 1, L, D)), dtype)
+    k = jnp.asarray(rng.normal(size=(B, Hkv, 1, L, D)), dtype)
+    v = jnp.asarray(rng.normal(size=(B, Hkv, 1, L, Dv)), dtype)
+    block = min(bq, pf.round_up(L, pf.LANES))
+    plan = pf.plan_fwd_body("causal", block, pairs=9)  # at most three blocks a side here
+    assert plan == pf.FwdPlan("overlap", max(block // 4, 128))
+    body = None if chains == "planned" else plan._replace(rows=block // chains)
+    want = pf._fwd_impl(q, k, v, kv_lens, True, 0.11, bq, bk, True, body="serial")
+    got = pf._fwd_impl(q, k, v, kv_lens, True, 0.11, bq, bk, True, body=body)
+    assert (_differing(got[0], want[0]), _differing(got[1], want[1])) == (0, 0)
+    assert np.isfinite(np.asarray(want[0], np.float32)).all()
+
+
+def test_overlapped_body_without_a_mask_is_the_serial_body_to_the_bit(rng):
+    """The mask is a parameter of the one body: with none (every pair of
+    blocks visited, ragged counts from the table) it is still the serial
+    body's arithmetic, though no caller is planned onto it."""
+    from gigapath_tpu.ops import pallas_flash as pf
+
+    q, k, v = (jnp.asarray(rng.normal(size=(2, 2, 1, 300, 32)), jnp.bfloat16) for _ in range(3))
+    kv_lens = (300, 200, 0, 129)
+    want = pf._fwd_impl(q, k, v, kv_lens, False, 0.11, 128, 128, True)
+    got = pf._fwd_impl(q, k, v, kv_lens, False, 0.11, 128, 128, True,
+                       body=pf.FwdPlan("overlap", 64))
+    assert (_differing(got[0], want[0]), _differing(got[1], want[1])) == (0, 0)
+
+
+def test_the_body_a_call_took_is_its_kernels_name():
+    """``flash_fwd_overlap`` for a causal call, ``flash_fwd`` for the rest and
+    for ``body="serial"``; no mask keeps the serial body (and the text
+    ``test_equal_heads_default_scale_lowers_to_the_parents_text`` pins)."""
+    from gigapath_tpu.ops import pallas_flash as pf
+
+    assert pf.plan_fwd_body(None, 1024, 256) == pf.FwdPlan("serial", 1024)
+    assert pf.plan_fwd_body("causal", 1024, 256) == pf.FwdPlan("overlap", 256)
+    assert pf.plan_fwd_body("selection", 1024, 256) == pf.FwdPlan("overlap", 256)
+    assert pf.plan_fwd_body("causal", 128, 1) == pf.FwdPlan("overlap", 128)
+    # a million tokens: the tables of steps would not fit beside the kernel's scalars
+    assert pf.plan_fwd_body("causal", 1024, 1024 * 1024) == pf.FwdPlan("serial", 1024)
+    x = jax.ShapeDtypeStruct((1, 2, 1, 256, 64), jnp.bfloat16)
+
+    def names(causal, body=None):
+        text = jax.jit(lambda q, k, v: pf._fwd_impl(q, k, v, None, causal, 0.1, 128, 128, True,
+                                                    body=body)).lower(x, x, x).as_text(debug_info=True)
+        return {"overlap": "kernel_fwd/flash_fwd_overlap/" in text,
+                "serial": "kernel_fwd/flash_fwd/" in text}
+
+    assert names(True) == {"overlap": True, "serial": False}
+    assert names(True, "serial") == {"overlap": False, "serial": True}
+    assert names(False) == {"overlap": False, "serial": True}
+
+
+@pytest.mark.parametrize("L,block", [(300, 128), (600, 256)])
+def test_a_lower_triangle_selection_is_the_causal_flash_forward_to_the_bit(rng, L, block):
+    """One body behind both calls: ``sparse_attn`` with every earlier key
+    selected gives ``flash_fwd``'s causal ``out`` (serial body) in every
+    element, at keys of 24 beside values of 16 and three blocks a row (of
+    128, and of 256, where the diagonal block is cut a chain)."""
+    from gigapath_tpu.ops import pallas_flash as pf
+    from gigapath_tpu.ops.pallas_sparse import sparse_attn_fwd
+
+    B, H, D, Dv = 2, 2, 24, 16
+    q, k = (jnp.asarray(rng.normal(size=(B, L, H, D)), jnp.bfloat16) for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(B, L, H, Dv)), jnp.bfloat16)
+    triangle = jnp.asarray(np.tril(np.ones((B, L, L), np.int8)))
+    got = sparse_attn_fwd(q, k, v, triangle, scale=0.2, block_q=block, block_k=block,
+                          interpret=True)
+    q5, k5, v5 = (x.transpose(0, 2, 1, 3)[:, :, None] for x in (q, k, v))
+    want, _ = pf._fwd_impl(q5, k5, v5, None, True, 0.2, block, block, True, body="serial")
+    assert _differing(got, want[:, :, 0].transpose(0, 2, 1, 3)) == 0

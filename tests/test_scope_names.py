@@ -239,14 +239,14 @@ _NAMES = {
                "out_proj", "moe", "router", "dispatch", "experts", "kernel_fwd", "combine",
                "shared_mlp", "self_attn", "attn_core", "lm_head"],
     "lm_kernels": ["jit_lm_forward", "ssd_scan", "moe", "dispatch", "moe_dispatch", "experts",
-                   "kernel_fwd", "gmm", "combine", "moe_combine", "attn_core", "flash_fwd",
-                   "lm_head"],
+                   "kernel_fwd", "gmm", "combine", "moe_combine", "attn_core",
+                   "flash_fwd_overlap", "lm_head"],
     "axk1_jnp": ["jit_lm_forward", "self_attn", "q_a_proj", "q_a_layernorm", "q_b_proj",
                  "kv_a_proj_with_mqa", "kv_a_layernorm", "kv_b_proj", "rope", "attn_core",
                  "kernel_fwd", "o_proj", "mlp", "moe", "router", "dispatch", "experts",
                  "combine", "shared_experts", "lm_head"],
-    "axk1_kernels": ["jit_lm_forward", "rope", "attn_core", "kernel_fwd", "flash_fwd", "moe",
-                     "router", "dispatch", "moe_dispatch", "experts", "gmm", "combine",
+    "axk1_kernels": ["jit_lm_forward", "rope", "attn_core", "kernel_fwd", "flash_fwd_overlap",
+                     "moe", "router", "dispatch", "moe_dispatch", "experts", "gmm", "combine",
                      "moe_combine", "shared_experts", "lm_head"],
     "fused_grad": ["dilated_attn", "branch_r2", "pack", "kernel_fwd", "kernel_dq",
                    "kernel_dkv", "unpack", "merge", "dilated_pack", "dilated_fwd_overlap",
@@ -376,7 +376,7 @@ def test_latent_attention_holds_its_steps_in_order_of_the_path():
                  "kv_b_proj", "rope", "o_proj"):
         assert re.search(rf'"[^"]*/layers_2/self_attn/{step}[/"]', text), step
     assert "/layers_2/self_attn/attn_core/jit(_causal_core)" in text
-    assert re.search(r'"kernel_fwd/flash_fwd/', text)
+    assert re.search(r'"kernel_fwd/flash_fwd_overlap/', text)
     assert len(re.findall(r"func.func private @_causal_core", text)) == 1
     assert re.search(r'"[^"]*/layers_0/mlp/', text) and not re.search(r'"[^"]*/layers_0/moe/', text)
     assert re.search(r'"[^"]*/layers_1/moe/router/', text)

@@ -20,6 +20,7 @@ live in this one file; the persistent compile cache is off around them (a
 cached entry for a described chip cannot be read back without one).
 """
 
+import functools
 import os
 import re
 
@@ -324,6 +325,67 @@ def test_flash_and_flat_segment_standalone(topo, one_chip):
     _compile(jax.value_and_grad(flat_loss, argnums=(0, 1, 2)), one_chip, xh, xh, xh)
 
 
+# heads, KV heads, keys' width, values' width: the three cells' cores
+_CAUSAL_CORES = {"axk1": (64, 64, 192, 128), "granite": (32, 8, 128, 128),
+                 "dsv32": (128, 128, 192, 128)}
+
+
+@pytest.mark.parametrize("tokens", [16384, 32768])
+@pytest.mark.parametrize("core", _CAUSAL_CORES)
+def test_overlapped_forward_body_alone_at_the_published_widths(topo, one_chip, core, tokens):
+    """The body ``pallas_flash.plan_fwd_body`` names for a causal call and for
+    the core over selected keys, alone, at the three cells' heads and widths
+    and at twice their 16,384 tokens: blocks of 1,024 x 1,024, four chains of
+    256 rows, the q block scaled once a row into a scratch of its own, the
+    steps named by three prefetched tables, have to fit the 16 MiB of scoped
+    VMEM a kernel has by default (with keys of 192 a ``[1024, 192]`` block
+    takes 256 lanes; DeepSeek-V3.2's call adds two int8 tiles of the
+    selection), under a name the benchmark's tables find. The selection is
+    ``[1, L, L]``: 1.07 GB at 32,768."""
+    from benchmarks.lib import tables
+    from gigapath_tpu.ops import pallas_flash as pf
+    from gigapath_tpu.ops.pallas_sparse import sparse_attn_fwd
+
+    heads, kv_heads, d, dv = _CAUSAL_CORES[core]
+    assert pf.plan_fwd_body("selection" if core == "dsv32" else "causal", pf.DEFAULT_BLOCK_Q,
+                            (tokens // 1024) ** 2) == pf.FwdPlan("overlap", 256)
+    bf16 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16)
+    if core == "dsv32":
+        q, v = bf16((1, tokens, heads, d)), bf16((1, tokens, heads, dv))
+        calls = _kernel_calls(_compiled(
+            lambda q, k, v, mask: sparse_attn_fwd(q, k, v, mask, scale=0.1352),
+            one_chip, q, q, v, jax.ShapeDtypeStruct((1, tokens, tokens), jnp.int8)))
+        name, result, table = "sparse_attn_overlap", r"bf16\[", "sparse_attn_by_name"
+    else:
+        calls = _kernel_calls(_compiled(
+            lambda q, k, v: pf._fwd_impl(q, k, v, None, True, 0.1, pf.DEFAULT_BLOCK_Q,
+                                         pf.DEFAULT_BLOCK_K, False),
+            one_chip, bf16((1, heads, 1, tokens, d)), bf16((1, kv_heads, 1, tokens, d)),
+            bf16((1, kv_heads, 1, tokens, dv))))
+        name, result, table = "flash_fwd_overlap", r"\(bf16\[", "flash_fwd_by_name"
+    assert len(calls) == 1 and re.match(rf"(ROOT )?%{name}[.\d]* = {result}", calls[0]), calls
+    assert _picked(tables.kernel_table(table), calls[0])
+
+
+def test_overlapped_forward_step_tables_fit_at_the_planners_cap(topo, one_chip):
+    """The three prefetched step tables at the longest call the planner sends
+    to the overlapped body (a causal 262,144 tokens in blocks of 1,024:
+    65,536 pairs of blocks, 32,896 of them visited): 395 KB of the 1 MiB of
+    SMEM, beside the table of valid-key counts. Past it the planner keeps
+    the serial body."""
+    from gigapath_tpu.ops import pallas_flash as pf
+
+    tokens = 256 * 1024
+    assert pf.plan_fwd_body("causal", 1024, 256 * 256).body == "overlap"
+    assert pf.plan_fwd_body("causal", 1024, 257 * 257).body == "serial"
+    q = jax.ShapeDtypeStruct((1, 2, 1, tokens, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 1, 1, tokens, 128), jnp.bfloat16)
+    calls = _kernel_calls(_compiled(
+        lambda q, k, v: pf._fwd_impl(q, k, v, None, True, 0.1, 1024, 1024, False),
+        one_chip, q, kv, kv))
+    assert len(calls) == 1 and "s32[32896]" in calls[0]
+
+
 def test_quant_kernels_at_vit_g_widths(topo, one_chip):
     """``quant/`` Pallas tiers at the tile encoder's widths: d=1536 into the
     SwiGLU hidden 8192 over a batch-128 x 197-token activation, and int8-logit
@@ -410,7 +472,7 @@ def test_fused_local_branches_inside_shard_map_on_four_chips(topo, schedule):
 def test_granite_layers_at_published_widths_hold_their_kernels(topo, one_chip, monkeypatch, piece):
     """granite-4.0-h-small's two kernel-bearing layers at the cell's 16,384
     tokens, the device gate answering "TPU": the causal core is one
-    ``flash_fwd`` call over 32 query and 8 KV heads of 128 (no repeated K/V in
+    ``flash_fwd_overlap`` call over 32 query and 8 KV heads of 128 (no repeated K/V in
     memory); the dropless expert layer, 36 of 72 experts held, is one
     ``moe_dispatch``, two ``gmm`` and one ``moe_combine`` call, and no gather
     of the ``[163840, 4096]`` sorted buffer is left to XLA. Each stands under
@@ -424,7 +486,7 @@ def test_granite_layers_at_published_widths_hold_their_kernels(topo, one_chip, m
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     if piece == "attention":
         layer, shape = CausalGQAttention(4096, 32, 8, 1 / 128), (1, 16384, 4096)
-        kernels = {"flash_fwd": (1, r"attn_core/kernel_fwd/flash_fwd/")}
+        kernels = {"flash_fwd_overlap": (1, r"attn_core/kernel_fwd/flash_fwd_overlap/")}
     else:
         layer, shape = DroplessMoE(4096, 768, 72, 10, experts_held=36), (16384, 4096)
         kernels = {"moe_dispatch": (1, r"dispatch/kernel_fwd/jit\(_dispatch_call\)/moe_dispatch/"),
@@ -465,7 +527,7 @@ def test_granite_layers_at_published_widths_hold_their_kernels(topo, one_chip, m
 @pytest.mark.parametrize("piece", ["attention", "experts"])
 def test_axk1_layers_at_published_widths_hold_their_kernels(topo, one_chip, monkeypatch, piece):
     """A.X-K1's two kernel-bearing pieces at the cell's 16,384 tokens, the
-    device gate answering "TPU": latent attention is one ``flash_fwd`` call
+    device gate answering "TPU": latent attention is one ``flash_fwd_overlap`` call
     over 64 heads whose keys are 192 wide and whose values and output are 128
     (no value is padded to the keys' width, and no stride-2 gather is left of
     the rotary pairing); the dropless expert layer, 12 of 192 experts held
@@ -486,7 +548,8 @@ def test_axk1_layers_at_published_widths_hold_their_kernels(topo, one_chip, monk
         shapes = (jax.ShapeDtypeStruct((1, 16384, 7168), jnp.bfloat16),
                   jax.ShapeDtypeStruct((16384, 32), jnp.float32),
                   jax.ShapeDtypeStruct((16384, 32), jnp.float32))
-        kernels = {"flash_fwd": (1, r"attn_core/jit\(_causal_core\)/kernel_fwd/flash_fwd/")}
+        kernels = {"flash_fwd_overlap": (
+            1, r"attn_core/jit\(_causal_core\)/kernel_fwd/flash_fwd_overlap/")}
     else:
         assert pallas_rows.fits(16384, 7168, 8, jnp.bfloat16)
         layer = DroplessMoE(7168, 2048, 192, 8, experts_held=12,
@@ -506,8 +569,8 @@ def test_axk1_layers_at_published_widths_hold_their_kernels(topo, one_chip, monk
         # anchored at the instruction's own name, as kernels/expert_gmm_by_name.json is
         assert len(re.findall(rf"\n\s*(?:ROOT )?%{kernel}(?:\.\d+)? = [^\n]* custom-call\(", text)) == calls
     if piece == "attention":
-        call = re.search(r"%flash_fwd(?:\.\d+)? = \((\S+) [^\n]* custom-call\([^\n]*"
-                         r"operand_layout_constraints=\{([^}]*\}[^}]*\}[^}]*\}[^}]*\})", text)
+        call = re.search(r"%flash_fwd_overlap(?:\.\d+)? = \((\S+) [^\n]* custom-call\([^\n]*"
+                         r"operand_layout_constraints=\{((?:[^}]*\}){6})", text)  # 3 step tables, q, k, v
         assert call.group(1).startswith("bf16[1,64,1,16384,128]")   # out at the values' width
         assert call.group(2).count("bf16[1,64,1,16384,192]") == 2   # q and k at the keys'
         assert call.group(2).count("bf16[1,64,1,16384,128]") == 1   # v at its own
@@ -524,7 +587,7 @@ def test_deepseek_v32_attention_at_published_widths_holds_its_kernels(topo, one_
     answering "TPU": one ``index_score`` call over 64 index heads of 128 whose
     result is the ``[L, L]`` float32 scores (no ``[64, L, L]`` anywhere), one
     ``index_select`` call that turns them into the int8 selection, one
-    ``sparse_attn`` call over 128 heads whose keys are 192 wide and whose values
+    ``sparse_attn_overlap`` call over 128 heads whose keys are 192 wide and whose values
     and output are 128, each under the scope the trace's reduction finds it by
     (benchmarks/scopes/dsv32.json, benchmarks/kernels/*_by_name.json); no
     ``flash_fwd``, no sort and no top-k is left in the program."""
@@ -546,13 +609,13 @@ def test_deepseek_v32_attention_at_published_widths_holds_its_kernels(topo, one_
     text = compiled.as_text()
     for kernel, scope in (("index_score", r"indexer/score/kernel_fwd/index_score"),
                           ("index_select", r"select/kernel_fwd/index_select"),
-                          ("sparse_attn", r"attn_core/kernel_fwd/sparse_attn")):
+                          ("sparse_attn_overlap", r"attn_core/kernel_fwd/sparse_attn_overlap")):
         assert re.search(rf'op_name="[^"]*/{scope}', text), kernel
         # anchored at the instruction's own name, as the kernel tables are
         assert len(re.findall(rf"\n\s*(?:ROOT )?%{kernel}(?:\.\d+)? = [^\n]* custom-call\(", text)) == 1
     assert re.search(r"%index_score(?:\.\d+)? = f32\[1,16384,16384\]", text)
     assert re.search(r"%index_select(?:\.\d+)? = s8\[1,16384,16384\]", text)
-    assert re.search(r"%sparse_attn(?:\.\d+)? = bf16\[1,128,16384,128\]", text)
+    assert re.search(r"%sparse_attn_overlap(?:\.\d+)? = bf16\[1,128,16384,128\]", text)
     assert "[1,64,16384,16384]" not in text and "[64,16384,16384]" not in text
     assert " sort(" not in text and " topk(" not in text and "%flash_fwd" not in text
     # the temporaries of one layer's attention beside 6.45 GB of weights on a 16 GB chip
