@@ -115,7 +115,7 @@ def main(argv=None) -> int:
         os.environ["JAX_COMPILATION_CACHE_DIR"] = harness.CACHE_DIR
     import numpy as np
 
-    from benchmarks.lib import host_spans
+    from benchmarks.lib import host_spans, tables
     from benchmarks.lib import trace as trace_lib
     from gigapath_tpu.obs import spans as program_spans
 
@@ -134,7 +134,7 @@ def main(argv=None) -> int:
             return 3
         ctx, _ = prepared
         system, traffic = ctx.system, ctx.traffic
-        kind = ctx.cell["per_layer"][0].rsplit(".", 1)[-1]
+        kind = tables.cell_kind(ctx.cell)
 
         # ---- set-up: a copy of drivers/closed_loop.py `run`'s (weights, batches,
         # order, two warm-ups) and of the one line drivers/closed_loop_lm.py puts

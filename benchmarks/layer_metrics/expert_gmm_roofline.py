@@ -6,7 +6,7 @@ configuration's widths (``lib/flops_axk1.py``).
 
 The rows are those the program's own counter says were routed here (the
 tokens each held expert received, a request and expert layer:
-``systems/lm.py:received``): operations 2 x 3 x hidden x expert width a row;
+``systems/lm.py``: ``kept["received"]``): operations 2 x 3 x hidden x expert width a row;
 bytes every held expert's two matrices once a layer pass and every row in and
 out of both products. With 12 of 192 experts held about a sixteenth of the
 static sorted buffer is routed here: a product that visited the rest would
@@ -19,7 +19,7 @@ from benchmarks.lib.tables import kernel_table
 
 
 def read(metric, trace, window, ctx):
-    received = getattr(ctx.system, "received", None)
+    received = getattr(ctx.system, "kept", {}).get("received")
     if trace is None or ctx.peaks is None or not received or not window["attempted"]:
         return None
     seconds = trace.kernel_seconds(kernel_table("expert_gmm_by_name")) * trace.n_devices

@@ -2,7 +2,7 @@
 selection handed latent attention's core, over the window's requests and
 their layers: the program's own counter (the ones of each layer's selection,
 counted on the device; ``[layers, batch]`` int32 a request from the model's third
-output, ``systems/deepseek_v32.py:selected``) over ``layers x L (L + 1) / 2``.
+output, ``systems/lm.py``: ``kept["selected_pairs"]``) over ``layers x L (L + 1) / 2``.
 An exact top-2048 reads 0.2344 at 16,384 tokens; 1.0 would mean a core that
 attends densely, and a figure off ``sum_t min(t + 1, index_topk)`` a selection
 that lets through more or fewer keys than it may. None where the system keeps
@@ -12,7 +12,7 @@ import numpy as np
 
 
 def read(metric, trace, window, ctx):
-    selected = getattr(ctx.system, "selected", None)
+    selected = getattr(ctx.system, "kept", {}).get("selected_pairs")
     if not selected or not window["attempted"] or not window["items"]:
         return None
     served = selected[-window["attempted"]:]   # the window's requests, not the warm-up's

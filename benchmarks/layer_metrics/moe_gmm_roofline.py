@@ -4,7 +4,7 @@ them (``kernels/moe_gmm_by_name.json``), in %.
 
 The rows are those the program's own counter says were routed here (the
 tokens each held expert received, a request and layer:
-``systems/lm.py:received``), not the expected load: operations 2 x 3 x hidden
+``systems/lm.py``: ``kept["received"]``), not the expected load: operations 2 x 3 x hidden
 x width a row; bytes every held expert's two matrices once a layer and
 request and every row in and out of both products (``lib/flops_lm.py``). The
 sorted buffer is sized for every choice landing here, about twice the rows
@@ -17,7 +17,7 @@ from benchmarks.lib.tables import kernel_table
 
 
 def read(metric, trace, window, ctx):
-    received = getattr(ctx.system, "received", None)
+    received = getattr(ctx.system, "kept", {}).get("received")
     if trace is None or ctx.peaks is None or not received or not window["attempted"]:
         return None
     seconds = trace.kernel_seconds(kernel_table("moe_gmm_by_name")) * trace.n_devices

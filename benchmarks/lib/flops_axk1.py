@@ -1,4 +1,4 @@
-"""Operations and bytes of the `axk1` system's forward pass, from shapes
+"""Operations and bytes of the `axk1` model's forward pass, from shapes
 alone, whatever implements a layer (``lib/flops_lm.py`` has the Granite
 hybrid's). A multiply-add is two operations. ``sizes`` is the configuration
 file. What is counted is the published, un-absorbed forward:

@@ -1,4 +1,4 @@
-"""Operations and bytes of the `deepseek_v32` system's forward pass, from
+"""Operations and bytes of the `deepseek_v32` model's forward pass, from
 shapes alone, whatever implements a layer (``lib/flops_axk1.py`` has the
 DeepSeek-V3 layer's; its counts are taken from there). A multiply-add is two
 operations. ``sizes`` is the configuration file. What is counted is the least

@@ -1,4 +1,4 @@
-"""Operations and bytes of the `lm` system's forward pass, from shapes
+"""Operations and bytes of the `granite_hybrid` model's forward pass, from shapes
 alone, whatever implements a layer (``lib/flops.py`` has the encoders'). A
 multiply-add is two operations. ``sizes`` is the configuration file.
 

@@ -1,4 +1,4 @@
-"""Plain reference for the `axk1` system: the forward pass of A.X-K1
+"""Plain reference for the `axk1` model: the forward pass of A.X-K1
 (``model_type: axk1``, the DeepSeek-V3 family's layer: multi-head latent
 attention, a leading dense layer, sigmoid group-limited routing beside a
 shared expert) in float32 ``jax.numpy`` under

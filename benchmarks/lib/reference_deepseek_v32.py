@@ -1,4 +1,4 @@
-"""Plain reference for the `deepseek_v32` system: the forward pass of
+"""Plain reference for the `deepseek_v32` model: the forward pass of
 DeepSeek-V3.2 (``model_type: deepseek_v32``: the DeepSeek-V3 layer with a
 lightning indexer and a top-k in front of latent attention's core, a biased
 group-limited gate, leading dense layers, one multi-token-prediction module)

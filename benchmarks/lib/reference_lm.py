@@ -1,4 +1,4 @@
-"""Plain reference for the `lm` system: the forward pass of a Granite 4.0-H
+"""Plain reference for the `granite_hybrid` model: the forward pass of a Granite 4.0-H
 hybrid stack (``model_type: granitemoehybrid``) in float32 ``jax.numpy`` under
 ``jax.default_matmul_precision("highest")``, one sequence at a time. No
 kernels, no chunks, no sorting, no batching; nothing here imports the

@@ -35,6 +35,12 @@ def kernel_table(name: str) -> dict:
     return load("kernels", name)
 
 
+def cell_kind(cell: dict) -> str:
+    """The suffix every layer metric of a cell's file carries, which names its
+    scope table and its readers' families: ``step_mfu.tile`` -> ``tile``."""
+    return cell["per_layer"][0].rsplit(".", 1)[-1]
+
+
 def manifest() -> dict:
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         return json.load(f)

@@ -21,6 +21,11 @@ in front of it. The rules keep activations of order one through the depth:
   unnamed leaf, every head would forget within 3 positions: the state a chunk
   hands to the next would be nothing, and a fault in it (the planted one of
   ``tests/benchmarks/test_benchmark_lm.py``) would not reach the comparison;
+- a router's selection bias (``e_score_correction_bias``): drawn as an
+  unnamed leaf, then multiplied by ``BIAS_SCALE`` (0.04) in its own dtype, a
+  product of its own after the draw: 0.02 beside scores that spread by 0.2
+  moves choices at the edge and leaves the load near even, as a router that
+  the bias has balanced is; at 0.5 it alone would pick every token's experts;
 - ``A_log``, ``D`` and anything else: 0.5 normal, as ``lib/weights.py`` draws
   an unnamed leaf.
 """
@@ -34,6 +39,7 @@ import jax.numpy as jnp
 
 _MATRICES = ("kernel", "w1", "w2")
 _NAMED = _MATRICES + ("conv_weight", "embedding", "weight", "conv_bias", "dt_bias")
+BIAS_SCALE = 0.04
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3, 4))
@@ -69,6 +75,7 @@ def make_weights(shapes, seed: int):
     built = []
     for i, (path, leaf) in enumerate(leaves):
         name = str(getattr(path[-1], "key", path[-1]))
-        name = name if name in _NAMED else "other"
-        built.append(_fill(key, i, name, tuple(leaf.shape), jnp.dtype(leaf.dtype)))
+        made = _fill(key, i, name if name in _NAMED else "other", tuple(leaf.shape),
+                     jnp.dtype(leaf.dtype))
+        built.append(made * BIAS_SCALE if name == "e_score_correction_bias" else made)
     return jax.tree_util.tree_unflatten(treedef, built)

@@ -130,6 +130,7 @@ def _listing() -> dict:
         "systems": tables.names("systems", ".py"),
         "layer_metrics": tables.names("layer_metrics", ".py"),
         "kernels": tables.names("kernels"),
+        "scopes": tables.names("scopes"),
     }
 
 

@@ -38,7 +38,7 @@ def metric_names(kind: str) -> list:
 
 def main(argv=None) -> int:
     from benchmarks import run as harness
-    from benchmarks.lib import scopes
+    from benchmarks.lib import scopes, tables
     from benchmarks.lib import trace as trace_lib
 
     ap = argparse.ArgumentParser()
@@ -54,8 +54,7 @@ def main(argv=None) -> int:
     if prepared is None:
         return 3
     ctx, driver = prepared
-    # a cell's layer metrics all end in its kind: step_mfu.tile -> tile
-    kind = ctx.cell["per_layer"][0].rsplit(".", 1)[-1]
+    kind = tables.cell_kind(ctx.cell)
     window = driver.run(ctx)
     trace = trace_lib.reduce_xplane(
         trace_lib.newest_xplane(ctx.trace_dir), ctx.spans.spans, ctx.sync_host_ns)

@@ -42,7 +42,7 @@ def main(argv=None) -> int:
     from benchmarks.drivers.closed_loop import row_gaps
     from benchmarks.lib import reference_deepseek_v32 as reference
     from benchmarks.lib import tables, weights_lm
-    from benchmarks.systems.deepseek_v32 import System
+    from benchmarks.systems.lm import System
     from gigapath_tpu import pipeline
 
     cell = tables.load("workloads", args.workload)
@@ -69,7 +69,7 @@ def main(argv=None) -> int:
                           for name in names])
 
     for seed in (int(s) for s in args.seeds.split(",")):
-        params = system._params(weights_lm.make_weights(system.param_shapes(), seed))
+        params = weights_lm.make_weights(system.param_shapes(), seed)
         ids, positions = system.host_batch(np.random.default_rng(seed), {**traffic, "batch": 1})
         out = pipeline.run_inference_with_lm(ids, positions, lm=(model, params))
         chosen = np.asarray(selections(params, jnp.asarray(ids), jnp.asarray(positions))) != 0

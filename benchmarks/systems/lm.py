@@ -1,22 +1,54 @@
-"""The scoring forward as ``pipeline.run_inference_with_lm`` runs it: the
-jitted function ``pipeline.lm_forward_fn`` returns, built once, with that
-entry's conversions around it (int32 ids and positions to the device, float32
-logits back). The program's second answer, the tokens each held expert
-received, rides here (``received``, one ``[layers, experts_held]`` array a
-request fetched) because the driver wants one array from ``to_host``."""
+"""Every language model behind the scoring forward as
+``pipeline.run_inference_with_lm`` runs it: the jitted function
+``pipeline.lm_forward_fn`` returns, built once, with that entry's conversions
+around it (int32 ids and positions to the device, float32 logits back).
+
+What differs by model is data in the configuration file, read here and
+nowhere branched on:
+
+- ``share``: the factory's share arguments, each to the configuration key that
+  gives its value (``{"experts_held": "n_routed_experts", ...}``); a key the
+  file does not name is not passed. Where the two names differ, the program's
+  field under the key's own name has to hold the published count
+  (``published``) behind the share.
+- ``built``: the keys whose values the program has to build under the same
+  name, or ``[field, "group.key"]`` for a value inside a group
+  (``["rope_factor", "rope_scaling.factor"]``); ``built_over_depth``: list
+  keys compared over the share's layers; ``supported``: ``{key: [the one
+  value the program has, why]}``; ``expert_layer_leaves``: ``{"a.b": [the
+  published count that is the leaf's length, why]}``, in every expert layer
+  held (``first_k_dense_replace`` on). Each is checked once the program is
+  built, and a difference is an error.
+- ``model_module``: the program's module that registers the ``arch``;
+  ``reference`` / ``flops``: ``"<module of lib/>.<function>"``, with the
+  signatures ``lm_forward(params, ids, positions, sizes, mode)`` and
+  ``lm_forward_flops(sizes, L, P)``.
+
+The program's outputs after the logits are kept by name, one array a request
+in the order fetched (the warm-up's first): ``kept["received"]`` (the tokens
+each held expert received, ``[expert layers, experts_held]``) and every key of
+a third output dict (``selected_pairs``, ``mtp_logits``). The readers of
+``layer_metrics/`` take them from ``kept``."""
 
 from __future__ import annotations
 
+import collections
+import importlib
+
 import numpy as np
 
-from benchmarks.lib import flops_lm, reference_lm
 
-# configuration key -> the program's field (models/granite_hybrid.GraniteHybridConfig)
-_BUILT = ("hidden_size", "num_attention_heads", "num_key_value_heads", "intermediate_size",
-          "shared_intermediate_size", "num_experts_per_tok", "mamba_n_heads", "mamba_d_head",
-          "mamba_d_state", "mamba_d_conv", "mamba_chunk_size",
-          "attention_multiplier", "embedding_multiplier", "logits_scaling",
-          "residual_multiplier", "rms_norm_eps", "vocab_size", "depth", "expert_offset")
+def _lib_function(name: str):
+    """``"reference_lm.lm_forward"`` -> ``benchmarks.lib.reference_lm.lm_forward``."""
+    module, function = name.rsplit(".", 1)
+    return getattr(importlib.import_module("benchmarks.lib." + module), function)
+
+
+def _at(tree, path: str):
+    """The entry ``"a.b"`` names in nested dicts; None where there is none."""
+    for key in path.split("."):
+        tree = tree.get(key) if hasattr(tree, "get") else None
+    return tree
 
 
 class System:
@@ -25,28 +57,42 @@ class System:
     def __init__(self, config: dict, tiny: bool):
         from gigapath_tpu import pipeline
         from gigapath_tpu.utils.registry import create_model_from_registry
-        import gigapath_tpu.models.granite_hybrid  # noqa: F401  (registers the archs)
 
+        importlib.import_module(config["model_module"])  # registers the archs
         self.sizes = sizes = config["tiny"] if tiny else config
-        self.model = create_model_from_registry(
-            sizes["arch"], depth=int(sizes["depth"]), vocab_size=int(sizes["vocab_size"]),
-            experts_held=int(sizes["num_local_experts"]),
-            expert_offset=int(sizes["expert_offset"]),
-        )
-        built = self.model.cfg
-        stated = dict(sizes, experts_held=sizes["num_local_experts"],
-                      num_local_experts=sizes["published"]["num_local_experts"])
-        for key in _BUILT + ("experts_held", "num_local_experts"):
-            if getattr(built, key) != stated[key]:
-                raise ValueError(
-                    f"{sizes['arch']}: the program builds {key}={getattr(built, key)!r}, "
-                    f"the configuration file says {stated[key]!r}")
-        if sizes["mamba_n_groups"] != 1:
-            raise ValueError(f"{sizes['arch']}: the program shares B and C over all heads (one group)")
-        if list(built.layer_types[: built.depth]) != list(sizes["layer_types"][: built.depth]):
-            raise ValueError(f"{sizes['arch']}: layer_types differ from the configuration file's")
+        share = {arg: int(sizes[key]) for arg, key in config["share"].items()}
+        self.model = create_model_from_registry(sizes["arch"], **share)
+        self._reference = _lib_function(config["reference"])
+        self._flops = _lib_function(config["flops"])
+        self._check_built(config, sizes)
         self._pipeline = pipeline
-        self.received = []
+        self.kept = collections.defaultdict(list)
+
+    def _check_built(self, config: dict, sizes: dict):
+        built, arch = self.model.cfg, sizes["arch"]
+        pairs = [(arg, sizes[key]) for arg, key in config["share"].items()]
+        pairs += [(key, sizes["published"][key]) for arg, key in config["share"].items()
+                  if arg != key]
+        pairs += [(entry, sizes[entry]) if isinstance(entry, str)
+                  else (entry[0], _at(sizes, entry[1])) for entry in config["built"]]
+        for field, stated in pairs:
+            if getattr(built, field) != stated:
+                raise ValueError(f"{arch}: the program builds {field}={getattr(built, field)!r}, "
+                                 f"the configuration file says {stated!r}")
+        depth = int(sizes["depth"])
+        for key in config.get("built_over_depth", ()):
+            if list(getattr(built, key)[:depth]) != list(sizes[key][:depth]):
+                raise ValueError(f"{arch}: {key} differ from the configuration file's")
+        for key, (value, why) in config.get("supported", {}).items():
+            if sizes[key] != value:
+                raise ValueError(f"{arch}: {key}={sizes[key]!r}, and {why}")
+        leaves = config.get("expert_layer_leaves", {})
+        shapes = self.param_shapes() if leaves else None
+        for i in range(int(sizes.get("first_k_dense_replace", 0)), depth):
+            for path, (count, why) in leaves.items():
+                leaf = _at(shapes, f"layers_{i}.{path}")
+                if getattr(leaf, "shape", None) != (sizes["published"][count],):
+                    raise ValueError(f"{arch}: layer {i} has no {path} of {count} entries: {why}")
 
     def param_shapes(self):
         import jax
@@ -72,8 +118,11 @@ class System:
         return tuple(jnp.asarray(a, jnp.int32) for a in batch)
 
     def to_host(self, out):
-        logits, received = out
-        self.received.append(np.asarray(received))
+        logits, received, *more = out
+        self.kept["received"].append(np.asarray(received))
+        for extras in more:
+            for name, value in extras.items():
+                self.kept[name].append(np.asarray(value))
         logits = np.asarray(logits, np.float32)
         return logits.reshape(-1, logits.shape[-1])  # [B * P, vocab], a row an answer
 
@@ -85,8 +134,7 @@ class System:
 
     def flops(self, batch) -> float:
         ids, positions = batch
-        return ids.shape[0] * flops_lm.lm_forward_flops(
-            self.sizes, ids.shape[1], positions.shape[1])
+        return ids.shape[0] * self._flops(self.sizes, ids.shape[1], positions.shape[1])
 
     def rows(self, batch) -> int:
         return batch[1].size
@@ -97,7 +145,7 @@ class System:
         out = np.empty((len(rows), int(self.sizes["vocab_size"])), np.float32)
         for b in sorted({int(r) // p for r in rows}):  # one forward a sequence
             mine = [i for i, r in enumerate(rows) if int(r) // p == b]
-            out[mine] = reference_lm.lm_forward(
+            out[mine] = self._reference(
                 params, ids[b], positions[b][[int(rows[i]) % p for i in mine]],
                 self.sizes, mode)
         return out

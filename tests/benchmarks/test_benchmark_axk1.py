@@ -1,4 +1,4 @@
-"""The `axk1` system's side of the yardstick at the tiny size: a fault planted
+"""The A.X-K1 cell's side of the yardstick at the tiny size: a fault planted
 inside each new mechanism (the rotary pairing, the group ranking, the shared
 rotary key, YaRN's factor in the softmax scale) comes out not correct, the
 scope table puts each path in its group and the cell lists a share for every
@@ -94,7 +94,7 @@ def test_the_counters_ride_on_the_adapter(capsys):
         workload=CELL, seed=SEED, seconds=0.2, trace=0, tiny=True))
     window = driver.run(ctx)
     tiny = CONFIG["tiny"]
-    received = ctx.system.received
+    received = ctx.system.kept["received"]
     assert len(received) == window["attempted"] + 2  # the two warm-up requests first
     tokens = ctx.traffic["batch"] * ctx.traffic["tokens"]
     for counts in received:  # the expert layers only: the dense layer routes nothing

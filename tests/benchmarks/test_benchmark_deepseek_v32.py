@@ -1,4 +1,4 @@
-"""The `deepseek_v32` system's side of the yardstick at the tiny size: the
+"""The DeepSeek-V3.2 cell's side of the yardstick at the tiny size: the
 rehearsal is correct and its fp8 control is not; a fault planted inside each
 new mechanism (a dense core in the selected one's place, a selection one key
 short, the indexer's rotation paired as latent attention's, its scores ranked
@@ -6,7 +6,8 @@ upside down, its head weights left out) is caught, by ``correct`` or by
 ``index_selected_share`` (the gate's bias and its two-best group ranking move
 too few choices at the bias the benchmark draws to pass a limit at this size:
 ``tests/test_deepseek_v32.py`` holds them to a per-token loop); the
-counters ride on the adapter; the scope table puts each path in its group and
+counters ride on the adapter and the weight maker scales the selection
+bias as PR 34's adapter did; the scope table puts each path in its group and
 the cell lists a share for every group; the operation counts are a hand count;
 the cell sends the traffic ISSUE 34 names; and the new readers find nothing
 (None, never 0) in a program that has no such counter or kernel."""
@@ -155,12 +156,12 @@ def test_fault_inside_a_new_mechanism_is_caught(monkeypatch, fault):
 
 def test_the_counters_ride_on_the_adapter(monkeypatch):
     ctx, driver, window = _window(monkeypatch)
-    system = ctx.system
+    kept = ctx.system.kept
     layers = TINY["depth"] + TINY["num_nextn_predict_layers"]          # the module's layer last
     tokens = ctx.traffic["batch"] * ctx.traffic["tokens"]
-    assert len(system.received) == len(system.selected) == len(system.mtp_logits) \
+    assert len(kept["received"]) == len(kept["selected_pairs"]) == len(kept["mtp_logits"]) \
         == window["attempted"] + 2                                       # the two warm-up requests first
-    for counts, pairs, mtp in zip(system.received, system.selected, system.mtp_logits):
+    for counts, pairs, mtp in zip(kept["received"], kept["selected_pairs"], kept["mtp_logits"]):
         assert counts.shape == (layers - TINY["first_k_dense_replace"], TINY["n_routed_experts"])
         assert pairs.shape == (layers, ctx.traffic["batch"]) and pairs.dtype == np.int32
         assert (pairs == flops.selected_pairs(TINY, ctx.traffic["tokens"])).all()
@@ -168,25 +169,35 @@ def test_the_counters_ride_on_the_adapter(monkeypatch):
         assert (counts.sum(-1) <= tokens * TINY["num_experts_per_tok"]).all() and counts.sum() > 0
     share = index_selected_share.read("index_selected_share.dsv32", None, window, ctx)
     assert share == pytest.approx(1112 / 3003, abs=1e-12)               # 16 of 77: by hand
-    # the adapter's one conversion: the bias leaves scaled, every other leaf the one given
-    from benchmarks.systems.deepseek_v32 import BIAS_SCALE, scaled_bias
 
-    params = window["_state"][0]
-    scaled = scaled_bias(params)
-    raw = params["layers_1"]["moe"]["e_score_correction_bias"]
-    np.testing.assert_allclose(scaled["layers_1"]["moe"]["e_score_correction_bias"],
-                               np.asarray(raw) * BIAS_SCALE, rtol=1e-6)
-    assert 0.2 < float(jnp.std(raw)) < 1.0 and BIAS_SCALE == 0.04
-    assert scaled["layers_1"]["moe"]["w1"] is params["layers_1"]["moe"]["w1"]
-    first, second = system._params(params), system._params(params)
-    assert first["layers_1"]["moe"]["e_score_correction_bias"] is second["layers_1"]["moe"][
-        "e_score_correction_bias"]                                        # the product made once
-    assert not any(leaf is params["layers_1"]["moe"]["w1"] for made in system._bias_made.values()
-                   for leaf in made)                                      # no weight is kept alive
+
+def test_the_weight_maker_scales_the_selection_bias_as_the_adapter_did():
+    """``lib/weights_lm.py``'s rule for ``e_score_correction_bias`` gives, bit
+    for bit, what PR 34's adapter handed program and reference: the leaf drawn
+    as an unnamed one, then times 0.04 in its own dtype; every other leaf is
+    its rule's draw, untouched (ISSUE 38)."""
+    from benchmarks.lib import weights_lm
+    from benchmarks.systems.lm import System
+
+    shapes = System(CONFIG, tiny=True).param_shapes()
+    made = jax.tree.leaves(weights_lm.make_weights(shapes, SEED))
+    key = jax.random.fold_in(jax.random.PRNGKey(SEED & 0x7FFFFFFF), SEED >> 31)
+    scaled = 0
+    for i, (path, leaf) in enumerate(jax.tree_util.tree_flatten_with_path(shapes)[0]):
+        name = str(path[-1].key)
+        drawn = weights_lm._fill(key, i, name if name in weights_lm._NAMED else "other",
+                                 tuple(leaf.shape), jnp.dtype(leaf.dtype))
+        if name == "e_score_correction_bias":
+            assert made[i].dtype == jnp.float32 and 0.2 < float(jnp.std(drawn)) < 1.0
+            drawn, scaled = drawn * 0.04, scaled + 1
+        assert np.array_equal(np.asarray(made[i], np.float32), np.asarray(drawn, np.float32))
+    # the expert layers of the stack, and the prediction module's
+    assert scaled == TINY["depth"] - TINY["first_k_dense_replace"] + TINY["num_nextn_predict_layers"]
+    assert weights_lm.BIAS_SCALE == 0.04
 
 
 def test_the_adapter_holds_the_program_to_the_file():
-    from benchmarks.systems.deepseek_v32 import System
+    from benchmarks.systems.lm import System
 
     system = System(CONFIG, tiny=True)
     assert system.model.cfg.index_topk == TINY["index_topk"] and system.model.cfg.mtp == 1
@@ -401,13 +412,15 @@ def test_the_cell_lists_a_share_for_every_group_of_its_table():
     listed = {m["name"]: m for m in MANIFEST["per_layer"]}
     for name in cell["per_layer"]:
         assert listed[name]["workloads"] == [CELL] and listed[name]["moves"] == "slide_tokens_per_s"
-    assert [m["name"] for m in MANIFEST["per_layer"]][-len(cell["per_layer"]):] == cell["per_layer"]
+    names = [m["name"] for m in MANIFEST["per_layer"]]           # one run, in the file's order
+    start = names.index(cell["per_layer"][0])
+    assert names[start:start + len(cell["per_layer"])] == cell["per_layer"]
 
 
 def test_the_cell_sends_the_traffic_the_issue_named():
     """ISSUE 34: the traffic file that is there, so that the three language-model
-    cells differ by the model alone; the manifest's entries are additions at
-    the end of their lists."""
+    cells differ by the model alone; the manifest lists the cell as its file
+    has it (a later cell's entries come after, so no position is asserted)."""
     cell = tables.load("workloads", CELL)
     assert cell["traffic"] == "closed_ids_b1_16k" == tables.load(
         "workloads", "axk1_prefill_b1_16k")["traffic"]
@@ -422,10 +435,10 @@ def test_the_cell_sends_the_traffic_the_issue_named():
     assert cell["end_to_end"] == {"rate": "slide_tokens_per_s"}
     assert cell["correct"]["control"] == "fp8" and cell["correct"]["rows"] == 16
     assert cell["correct"]["requests"] == 2
-    assert MANIFEST["workloads"][-1] == {k: cell[k] for k in ("name", "config", "traffic", "chips", "why")}
-    assert MANIFEST["configs"][-1]["name"] == "deepseek_v32_ep32"
+    assert {k: cell[k] for k in ("name", "config", "traffic", "chips", "why")} in MANIFEST["workloads"]
+    assert "deepseek_v32_ep32" in [c["name"] for c in MANIFEST["configs"]]
     rate = next(m for m in MANIFEST["end_to_end"] if m["name"] == "slide_tokens_per_s")
-    assert rate["workloads"][-1] == CELL and rate["bound"] == 0.02
+    assert CELL in rate["workloads"] and rate["bound"] == 0.02
     # the floors of a model_config cut: four expert layers after the dense one, 8 experts, an eighth
     assert CONFIG["depth"] - CONFIG["first_k_dense_replace"] == 4 and CONFIG["n_routed_experts"] == 8
     assert CONFIG["vocab_size"] == 16160 == CONFIG["published"]["vocab_size"] // 8

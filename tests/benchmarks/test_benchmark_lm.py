@@ -1,9 +1,12 @@
-"""The `lm` system's side of the yardstick at the tiny size: faults planted
-inside the two new mechanisms come out not correct, the operation counts
-are the ones ISSUE 27 derived from shapes, the weights give every stacked
-expert its own fan-in, and the new readers find nothing (None, never 0) in a
-program that has no such counter or kernel."""
+"""The Granite cell's side of the yardstick at the tiny size, and the one
+`lm` adapter's: faults planted inside the two new mechanisms come out not
+correct, the operation counts are the ones ISSUE 27 derived from shapes, the
+weights give every stacked expert its own fan-in, the new readers find
+nothing (None, never 0) in a program that has no such counter or kernel, and
+the adapter holds each language model's program to its configuration file key
+for key, as the three adapters it replaced did."""
 
+import copy
 import dataclasses
 import json
 import types
@@ -63,7 +66,7 @@ def test_the_counter_rides_on_the_adapter_one_array_a_request(capsys):
         workload=CELL, seed=SEED, seconds=0.2, trace=0, tiny=True))
     window = driver.run(ctx)
     tiny = CONFIG["tiny"]
-    received = ctx.system.received
+    received = ctx.system.kept["received"]
     assert len(received) == window["attempted"] + 2  # the two warm-up requests first
     tokens = ctx.traffic["batch"] * ctx.traffic["tokens"]
     for counts in received:
@@ -186,3 +189,100 @@ def test_the_cell_sends_the_traffic_the_issue_named():
         "positions": 16, "distinct_batches": 4}
     assert traffic["tiny"] == {"batch": 2, "tokens": 77, "positions": 4, "distinct_batches": 2}
     assert cell["chips"] == 1 and "2 in flight" in cell["why"]
+
+
+# What the three adapters before PR 38 checked between the program they built
+# and the configuration file (their ``_BUILT``, the share's published counts,
+# the YaRN block, Granite's one group and ``layer_types``, DeepSeek's bias
+# leaf): a record for today's three configurations, which a new one does not
+# extend. A key dropped from a file's ``share`` / ``built`` / ... fails here.
+_ROPE = [f"rope_{k}" for k in ("factor", "original_max_position_embeddings", "beta_fast",
+                               "beta_slow", "mscale", "mscale_all_dim")]
+_MLA = ["hidden_size", "num_hidden_layers", "num_attention_heads", "q_lora_rank", "kv_lora_rank",
+        "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim", "intermediate_size",
+        "moe_intermediate_size", "num_experts_per_tok", "n_group", "topk_group",
+        "routed_scaling_factor", "n_shared_experts", "first_k_dense_replace", "rope_theta",
+        "rms_norm_eps", "vocab_size", "depth", "expert_offset", "experts_held", "n_routed_experts"]
+CHECKED = {
+    "granite4h_small_ep2": [
+        "hidden_size", "num_attention_heads", "num_key_value_heads", "intermediate_size",
+        "shared_intermediate_size", "num_experts_per_tok", "mamba_n_heads", "mamba_d_head",
+        "mamba_d_state", "mamba_d_conv", "mamba_chunk_size", "attention_multiplier",
+        "embedding_multiplier", "logits_scaling", "residual_multiplier", "rms_norm_eps",
+        "vocab_size", "depth", "expert_offset", "experts_held", "num_local_experts",
+        "layer_types", "mamba_n_groups"],
+    "axk1_ep16": _MLA + _ROPE,
+    "deepseek_v32_ep32": _MLA + _ROPE + [
+        "index_n_heads", "index_head_dim", "index_topk", "mtp", "num_nextn_predict_layers",
+        "e_score_correction_bias"],
+}
+
+
+def _other(value):
+    if isinstance(value, tuple):
+        return tuple(reversed(value))
+    return value + 1 if isinstance(value, int) else value * 2 + 1
+
+
+@pytest.mark.parametrize("config,key", [(c, k) for c, keys in CHECKED.items() for k in keys])
+def test_the_one_adapter_checks_every_key_the_three_checked(monkeypatch, config, key):
+    """The program built with one field other than the file states (or the
+    file stating a form the program lacks, or a layer without the leaf) is
+    refused by name; as built, the tiny preset passes."""
+    from benchmarks.systems.lm import System
+    from gigapath_tpu.utils import registry
+
+    stated = tables.load("configs", config)
+    assert stated["system"] == "lm"
+    System(stated, tiny=True)
+    if key == "mamba_n_groups":
+        stated = dict(stated, tiny=dict(stated["tiny"], mamba_n_groups=2))
+    elif key == "e_score_correction_bias":
+        shapes = System.param_shapes
+
+        def without_bias(self):
+            tree = shapes(self)
+            layer = tree["layers_1"]
+            return dict(tree, layers_1=dict(layer, moe={
+                k: v for k, v in layer["moe"].items() if k != key}))
+
+        monkeypatch.setattr(System, "param_shapes", without_bias)
+    else:
+        build = registry.create_model_from_registry
+
+        def off_by_one_field(arch, **share):
+            model = build(arch, **share)
+            cfg = copy.copy(model.cfg)
+            object.__setattr__(cfg, key, _other(getattr(cfg, key)))
+            return model.clone(cfg=cfg)
+
+        monkeypatch.setattr(registry, "create_model_from_registry", off_by_one_field)
+    with pytest.raises(ValueError, match=key):
+        System(stated, tiny=True)
+
+
+LM_CELLS = [w["name"] for w in tables.manifest()["workloads"]
+            if tables.load("configs", w["config"])["system"] == "lm"]
+
+
+@pytest.mark.parametrize("cell", LM_CELLS)
+def test_every_reader_of_the_adapters_outputs_finds_them(cell):
+    """Each layer metric of the cell whose reader takes something from the
+    system reads a number from the one adapter's tiny window, given a trace in
+    which every kernel took a second and the v5e's peaks: a reader that looks
+    for an output under another name would leave its metric out in silence."""
+    import importlib
+    import inspect
+
+    ctx, driver = harness.prepare(types.SimpleNamespace(
+        workload=cell, seed=SEED, seconds=0.2, trace=0, tiny=True))
+    window = driver.run(ctx)
+    ctx.peaks = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    trace = types.SimpleNamespace(kernel_seconds=lambda table: 1.0, n_devices=1)
+    read = 0
+    for name in ctx.cell["per_layer"]:
+        module = importlib.import_module("benchmarks.layer_metrics." + name.split(".")[0])
+        if "ctx.system" in inspect.getsource(module):
+            assert module.read(name, trace, window, ctx) is not None, name
+            read += 1
+    assert read >= 1
