@@ -184,7 +184,8 @@ class GraniteHybridLM(nn.Module):
 def create_lm(model_arch: str = "granite_4_0_h_small", *, rng=None, **share):
     """Build a causal LM through the registry (the hybrids of this module,
     ``axk1`` / ``axk1_tiny`` of :mod:`gigapath_tpu.models.axk1`, ``deepseek_v32`` /
-    ``deepseek_v32_tiny`` of :mod:`gigapath_tpu.models.deepseek_v32`) and initialise
+    ``deepseek_v32_tiny`` of :mod:`gigapath_tpu.models.deepseek_v32`, ``brumby`` /
+    ``brumby_tiny`` of :mod:`gigapath_tpu.models.brumby`) and initialise
     its share's parameters at random on the device, under ``jit``. Returns
     ``(module, params)``. ``share`` cuts the model to what this chip holds
     (``depth``, ``experts_held``, ``expert_offset``, ``vocab_size``). No
@@ -192,6 +193,7 @@ def create_lm(model_arch: str = "granite_4_0_h_small", *, rng=None, **share):
     fetch one."""
     import gigapath_tpu.models.axk1  # noqa: F401  (registers its archs)
     import gigapath_tpu.models.deepseek_v32  # noqa: F401
+    import gigapath_tpu.models.brumby  # noqa: F401
     from gigapath_tpu.utils.registry import create_model_from_registry
 
     model = create_model_from_registry(model_arch, **share)

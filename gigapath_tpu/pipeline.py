@@ -148,13 +148,13 @@ def slide_forward_fn(slide_encoder_model):
 def lm_forward_fn(lm):
     """The jitted scoring forward ``(params, ids [B, L] int32, positions [B,
     P] int32) -> (logits [B, P, vocab] float32, tokens each held expert
-    received [expert layers, experts_held] int32)`` that
-    :func:`run_inference_with_lm` runs, for any LM of the registry. The head runs on the rows
-    ``positions`` names and on no other: all 16,384 rows of a long document
-    would be 3.3 GB of logits a request. A model that counts or predicts more
-    returns a dict of named arrays as a third output, and it is handed on as
-    it is. One function a model (flax modules hash by their fields), so a
-    second call of the entry traces nothing; the same holds for the two above."""
+    received [expert layers, experts_held] int32; ``()``, no counts, where the
+    model has no expert layer)`` that :func:`run_inference_with_lm` runs, for
+    any LM of the registry. The head runs on the rows ``positions`` names and on
+    no other: all 16,384 rows of a document would be 3.3 GB of logits a request.
+    A model that counts or predicts more returns a dict of named arrays as a
+    third output, handed on as it is. One function a model (flax modules hash
+    by their fields), so a second call traces nothing, as for the two above."""
 
     @jax.jit
     def lm_forward(params, ids, positions):
@@ -365,7 +365,7 @@ def lm_to_host(outputs, positions) -> dict:
             **{name: np.asarray(value) for extras in more for name, value in extras.items()},
             "logits": np.asarray(logits, np.float32),
             "positions": positions,
-            "expert_tokens": np.asarray(received),
+            "expert_tokens": np.asarray(received, np.int32),
         }
 
 
@@ -387,15 +387,16 @@ def run_inference_with_lm(
 ) -> dict:
     """Score token ids with a causal LM of the registry (``granite_4_0_h_small``
     of ``models/granite_hybrid.py``, ``axk1`` of ``models/axk1.py``,
-    ``deepseek_v32`` of ``models/deepseek_v32.py``; any module with their
-    contract, nothing here asks which): ``token_ids [L]`` or ``[B,
-    L]`` int, ``positions [P]`` or ``[B, P]`` the rows whose next-token logits
-    are wanted (the last row where none is given). ``lm`` may be the ``(model,
-    params)`` pair ``models.granite_hybrid.create_lm`` returns. Returns
-    ``{'logits' [B, P, vocab] float32, 'positions' [B, P], 'expert_tokens'
-    [expert layers, experts_held]}``, and beside them whatever the model's
-    third output names (``deepseek_v32``: ``'selected_pairs' [layers, B]``, and
-    ``'mtp_logits' [B, P, vocab]`` where its prediction module runs)."""
+    ``deepseek_v32`` of ``models/deepseek_v32.py``, ``brumby`` of
+    ``models/brumby.py``; any module with their contract, nothing here asks
+    which): ``token_ids [L]`` or ``[B, L]`` int, ``positions [P]`` or ``[B, P]``
+    the rows whose next-token logits are wanted (the last row where none is
+    given). ``lm`` may be the ``(model, params)`` pair that
+    ``models.granite_hybrid.create_lm`` returns. Returns ``{'logits' [B, P,
+    vocab] float32, 'positions' [B, P], 'expert_tokens' [expert layers,
+    experts_held] int32 (shape ``(0,)`` where the model has no expert layer)}``
+    and whatever the third output names (``selected_pairs``, ``mtp_logits``;
+    ``carried_share``)."""
     if lm_params is None:
         lm, lm_params = lm
     return lm_request(lm_forward_fn(lm), lm_params, token_ids, positions)

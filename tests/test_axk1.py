@@ -349,7 +349,7 @@ def test_the_cut_holds_the_parameters_the_issue_counted():
     """4,166 M parameters at bfloat16 for the chip's share (8.33 GB): an
     expert layer here 675.0 M, the dense layer 497.5 M, embedding + head
     293.6 M."""
-    from benchmarks.systems.axk1 import System
+    from benchmarks.systems.lm import System
 
     shapes = System(CONFIG, tiny=False).param_shapes()
     count = lambda tree: sum(x.size for x in jax.tree.leaves(tree))  # noqa: E731

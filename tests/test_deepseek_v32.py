@@ -21,7 +21,6 @@ import pytest
 from benchmarks.drivers.closed_loop import row_gaps
 from benchmarks.lib import reference_deepseek_v32 as reference
 from benchmarks.lib import tables, weights_lm
-from benchmarks.systems.deepseek_v32 import scaled_bias
 from gigapath_tpu import pipeline
 from gigapath_tpu.models import axk1, deepseek_v32, granite_hybrid
 from gigapath_tpu.ops import rope, sparse_index
@@ -43,7 +42,7 @@ def _tiny_model(**share):
 def _weights(model, seed, dtype=None):
     ids = jax.ShapeDtypeStruct((1, 4), jnp.int32)
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids, ids)["params"]
-    params = scaled_bias(weights_lm.make_weights(shapes, seed))
+    params = weights_lm.make_weights(shapes, seed)
     return params if dtype is None else jax.tree.map(lambda a: a.astype(dtype), params)
 
 

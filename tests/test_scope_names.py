@@ -21,6 +21,7 @@ _TILE = tables.load("configs", "gigapath_tile_enc")["tiny"]
 _SLIDE = tables.load("configs", "gigapath_slide_enc12l768d")["tiny"]
 _LM = tables.load("configs", "granite4h_small_ep2")["tiny"]
 _AXK1 = tables.load("configs", "axk1_ep16")["tiny"]
+_BRUMBY = tables.load("configs", "brumby14b_pp5")["tiny"]
 _N_TOKENS = 40  # + class token = 41: three 16-token and two 32-token segments
 
 
@@ -87,6 +88,18 @@ def _axk1_forward(length=40, **widths):
     model = create_model_from_registry(
         _AXK1["arch"], depth=_AXK1["depth"], vocab_size=_AXK1["vocab_size"],
         experts_held=_AXK1["n_routed_experts"], expert_offset=_AXK1["expert_offset"], **widths)
+    ids = jax.ShapeDtypeStruct((2, length), jnp.int32)
+    rows = jax.ShapeDtypeStruct((2, 4), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids, rows)["params"]
+    return pipeline.lm_forward_fn.__wrapped__(model), (params, ids, rows)
+
+
+def _brumby_forward(length=40, **widths):
+    from gigapath_tpu import pipeline
+    from gigapath_tpu.utils.registry import create_model_from_registry
+    import gigapath_tpu.models.brumby  # noqa: F401
+
+    model = create_model_from_registry(_BRUMBY["arch"], depth=_BRUMBY["depth"], **widths)
     ids = jax.ShapeDtypeStruct((2, length), jnp.int32)
     rows = jax.ShapeDtypeStruct((2, 4), jnp.int32)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids, rows)["params"]
@@ -163,6 +176,11 @@ def _lowered(path: str) -> str:
         if path == "axk1_kernels":  # widths the grouped product and the row kernels take, as lm_kernels
             return _on_kernels(mp, lambda: _text(*_axk1_forward(
                 length=512, hidden_size=256, moe_intermediate_size=128)))
+        if path == "brumby_jnp":
+            return _text(*_brumby_forward())
+        if path == "brumby_kernels":  # the kernel takes heads of 128 and chunks of 128
+            return _on_kernels(mp, lambda: _text(*_brumby_forward(
+                length=200, head_dim=128, retention_chunk=128)))
         if path == "fused_grad":
             return _text(_grad_of(functools.partial(
                 da.dilated_attention_fused, segment_lengths=[16, 32],
@@ -248,6 +266,11 @@ _NAMES = {
     "axk1_kernels": ["jit_lm_forward", "rope", "attn_core", "kernel_fwd", "flash_fwd_overlap",
                      "moe", "router", "dispatch", "moe_dispatch", "experts", "gmm", "combine",
                      "moe_combine", "shared_experts", "lm_head"],
+    "brumby_jnp": ["jit_lm_forward", "self_attn", "q_proj", "k_proj", "v_proj", "q_norm",
+                   "k_norm", "rope", "retention", "gate", "kernel_fwd", "o_proj", "mlp",
+                   "lm_head"],
+    "brumby_kernels": ["jit_lm_forward", "rope", "retention", "gate", "kernel_fwd",
+                       "power_retention_fwd", "mlp", "lm_head"],
     "fused_grad": ["dilated_attn", "branch_r2", "pack", "kernel_fwd", "kernel_dq",
                    "kernel_dkv", "unpack", "merge", "dilated_pack", "dilated_fwd_overlap",
                    "dilated_dq", "dilated_dkv", "dilated_unpack", "dilated_epilogue_fwd",
@@ -385,6 +408,21 @@ def test_latent_attention_holds_its_steps_in_order_of_the_path():
                          ("combine", "jit(_combine_call)")):
         assert f"/layers_1/moe/{step}/kernel_fwd/{kernel}" in text, step
     assert re.search(r'"[^"]*/layers_1/shared_experts/', text)
+    assert re.search(r'"[^"]*/lm_head/lm_head/', text)
+
+
+def test_power_retention_holds_its_steps_in_order_of_the_path():
+    """``.../layers_<i>/self_attn/<projection | norm | rope | retention>/...``
+    with the gate and the kernel inside ``retention`` (benchmarks/scopes/
+    brumby.json matches on these), the SwiGLU under ``mlp``, the head under
+    ``lm_head``."""
+    text = _lowered("brumby_kernels")
+    for step in ("q_proj", "k_proj", "v_proj", "q_norm", "k_norm", "rope", "o_proj"):
+        assert re.search(rf'"[^"]*/layers_1/self_attn/{step}[/"]', text), step
+    assert re.search(r'"[^"]*/layers_1/self_attn/retention/gate/', text)
+    assert re.search(r'"[^"]*/layers_1/self_attn/retention/kernel_fwd/power_retention_fwd[/"]',
+                     text)
+    assert re.search(r'"[^"]*/layers_1/mlp/', text)
     assert re.search(r'"[^"]*/lm_head/lm_head/', text)
 
 

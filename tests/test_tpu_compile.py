@@ -620,3 +620,38 @@ def test_deepseek_v32_attention_at_published_widths_holds_its_kernels(topo, one_
     assert " sort(" not in text and " topk(" not in text and "%flash_fwd" not in text
     # the temporaries of one layer's attention beside 6.45 GB of weights on a 16 GB chip
     assert compiled.memory_analysis().temp_size_in_bytes < 6.5e9
+
+
+def test_brumby_retention_at_published_widths_holds_its_kernel(topo, one_chip, monkeypatch):
+    """Brumby's token mixer at the cell's 32,768 tokens, the device gate
+    answering "TPU": one ``power_retention_fwd`` call over 40 query heads
+    reading 8 KV heads of 128, under the scope the trace's reduction finds it
+    by (benchmarks/scopes/brumby.json, benchmarks/kernels/
+    power_retention_by_name.json), its state in the kernel's VMEM (a kernel
+    over the scoped limit fails here) and no ``[L, L]`` array anywhere."""
+    import gigapath_tpu.ops.flash_attention as fa
+    from benchmarks.lib import tables
+    from gigapath_tpu.models.brumby import PowerRetention
+    from gigapath_tpu.obs.ledger import custom_calls_of
+    from gigapath_tpu.utils.registry import create_model_from_registry
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    layer = PowerRetention(create_model_from_registry("brumby").cfg)
+    shapes = (jax.ShapeDtypeStruct((1, 32768, 5120), jnp.bfloat16),
+              jax.ShapeDtypeStruct((32768, 64), jnp.float32),
+              jax.ShapeDtypeStruct((32768, 64), jnp.float32))
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), *shapes)
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), (params, *shapes))
+    compiled = jax.jit(layer.apply).lower(*avals).compile()
+    assert custom_calls_of(compiled) == 1
+    text = compiled.as_text()
+    assert re.search(r'op_name="[^"]*/retention/kernel_fwd/power_retention_fwd', text)
+    calls = re.findall(r"\n\s*(?:ROOT )?%power_retention_fwd(?:\.\d+)? = ([^\n]*) custom-call\(",
+                       text)
+    assert len(calls) == 1 and "bf16[1,8,5,128,32768]" in calls[0], calls
+    call = next(line for line in text.splitlines() if "%power_retention_fwd" in line and " = " in line)
+    assert _picked(tables.kernel_table("power_retention_by_name"), call)
+    assert "[32768,32768]" not in text and "[1,32768,32768]" not in text
+    # the layer's temporaries beside 8.40 GB of weights on a 16 GB chip
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
