@@ -12,10 +12,16 @@ The chunked form (Dao & Gu 2024, "Transformers are SSMs", section 6) cuts
 the sequence into chunks of ``Q`` positions. Inside a chunk the output is a
 masked matrix product, ``(C B^T * decay * dt) x``; what a chunk leaves
 behind is one ``[P, N]`` state per head, carried to the next chunk by the
-recurrence above taken ``Q`` steps at a time. Everything here is plain XLA
-einsums: the decay matrix ``[heads, Q, Q]`` in float32 is built for a block
-of chunks at a time inside one ``lax.scan`` that carries the state, so a
-16,384-token sequence never holds all 64 chunks' matrices (2.1 GB) at once.
+recurrence above taken ``Q`` steps at a time.
+
+Two tiers, chosen by the library's one device gate and the shapes: the
+``jnp`` tier here (any backend; plain XLA einsums, the decay matrix ``[heads,
+Q, Q]`` in float32 built for a block of chunks at a time inside one
+``lax.scan`` that carries the state, so a 16,384-token sequence never holds
+all 64 chunks' matrices (2.1 GB) at once) and the Pallas kernel of
+:mod:`gigapath_tpu.ops.pallas_ssd` (a TPU, widths its ``fits`` takes), which
+reads ``x``, ``B`` and ``C`` where the convolution leaves them and keeps the
+state in VMEM.
 """
 
 from __future__ import annotations
@@ -27,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from gigapath_tpu.ops import flash_attention as _gate
 from gigapath_tpu.ops.common import round_up
 from gigapath_tpu.ops.norms import RMSNorm
 
@@ -84,9 +91,9 @@ def _chunk_block(D, state, block):
     return state, (y + D[:, None] * x.astype(jnp.float32)).astype(x.dtype)
 
 
-def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 256,
-             chunks_per_block: int = CHUNKS_PER_BLOCK):
-    """The state-space scan in its chunked form, ``y_t = S_t C_t + D x_t``.
+def ssd_scan_jnp(x, dt, A, B, C, D, *, chunk: int = 256,
+                 chunks_per_block: int = CHUNKS_PER_BLOCK):
+    """The ``jnp`` tier of :func:`ssd_scan`, ``y_t = S_t C_t + D x_t``.
 
     ``x [b, L, H, P]``; ``dt [b, L, H]`` float32, after the softplus; ``A [H]``
     float32, negative; ``B``, ``C`` ``[b, L, N]``; ``D [H]``. Returns ``y [b,
@@ -112,6 +119,36 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 256,
     return jnp.moveaxis(y, 0, 1).reshape(b, Lp, H, P)[:, :L]
 
 
+def _steps(dt, A_log, dt_bias):
+    """``(softplus(dt + dt_bias), A = -exp(A_log))``, float32."""
+    return (jax.nn.softplus(dt.astype(jnp.float32) + dt_bias.astype(jnp.float32)),
+            -jnp.exp(A_log.astype(jnp.float32)))
+
+
+def ssd_scan(xBC, dt, A_log, dt_bias, D, *, state_size: int, chunk: int = 256):
+    """The state-space scan over the convolution's output ``xBC [b, L, H P +
+    2 N]`` (``x``, then ``B``, then ``C``), with ``dt [b, L, H]`` the input
+    projection's columns: the steps are ``softplus(dt + dt_bias)`` and ``A =
+    -exp(A_log)``. Returns ``y [b, L, H P]`` in ``xBC``'s type, the gate
+    norm's layout. The kernel where the device gate says TPU and
+    :func:`gigapath_tpu.ops.pallas_ssd.fits` takes the widths and the chunk,
+    the ``jnp`` tier elsewhere."""
+    b, L, H = dt.shape
+    N = state_size
+    inner = xBC.shape[-1] - 2 * N
+    P = inner // H
+    if _gate._on_tpu():
+        from gigapath_tpu.ops import pallas_ssd
+
+        if pallas_ssd.fits(H, P, N, chunk):
+            return pallas_ssd.ssd_scan_fwd(xBC, *_steps(dt, A_log, dt_bias), D,
+                                           state_size=N, chunk=chunk)
+    x, B, C = jnp.split(xBC, [inner, inner + N], axis=-1)
+    x = x.reshape(b, L, H, P)
+    dt, A = _steps(dt, A_log, dt_bias)
+    return ssd_scan_jnp(x, dt, A, B, C, D, chunk=chunk).reshape(b, L, inner)
+
+
 class Mamba2Mixer(nn.Module):
     """``u [b, L, hidden] -> [b, L, hidden]``: ``[z | xBC | dt] = u W_in``;
     ``xBC = silu(conv(xBC))``; the scan over ``x``, ``B``, ``C`` with
@@ -130,7 +167,6 @@ class Mamba2Mixer(nn.Module):
 
     @nn.compact
     def __call__(self, u: jnp.ndarray) -> jnp.ndarray:
-        b, L, _ = u.shape
         H, P, N = self.num_heads, self.head_dim, self.state_size
         inner, conv_dim = H * P, H * P + 2 * N
         dense = dict(use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype)
@@ -141,17 +177,13 @@ class Mamba2Mixer(nn.Module):
                                 (self.conv_kernel, conv_dim), self.param_dtype)
             bias = self.param("conv_bias", nn.initializers.zeros, (conv_dim,), self.param_dtype)
             xBC = jax.nn.silu(causal_conv1d(xBC, weight, bias)).astype(self.dtype)
-        x, B, C = jnp.split(xBC, [inner, inner + N], axis=-1)
-        x = x.reshape(b, L, H, P)
         A_log = self.param("A_log", nn.initializers.zeros, (H,), self.param_dtype)
         dt_bias = self.param("dt_bias", nn.initializers.zeros, (H,), self.param_dtype)
         D = self.param("D", nn.initializers.ones, (H,), self.param_dtype)
         with jax.named_scope("ssd_scan"):
-            dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias.astype(jnp.float32))
-            A = -jnp.exp(A_log.astype(jnp.float32))
-            y = ssd_scan(x, dt, A, B, C, D, chunk=self.chunk_size)
+            y = ssd_scan(xBC, dt, A_log, dt_bias, D, state_size=N, chunk=self.chunk_size)
         with jax.named_scope("gate_norm"):
-            gated = y.reshape(b, L, inner).astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+            gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
             y = RMSNorm(inner, eps=self.norm_eps, param_dtype=self.param_dtype,
                         name="norm")(gated).astype(self.dtype)
         return nn.Dense(self.hidden_size, name="out_proj", **dense)(y)

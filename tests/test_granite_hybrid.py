@@ -54,19 +54,57 @@ def test_program_matches_the_reference_at_the_rows_asked_for(seed, length):
         assert gaps.max() < 0.03 and gaps.mean() < 0.015, gaps
 
 
-@pytest.mark.parametrize("length,chunk,per_block", [(77, 16, 8), (50, 16, 2), (256, 64, 8), (33, 64, 8)])
+@pytest.mark.parametrize("length,chunk,per_block", [
+    (77, 16, 8), (50, 16, 2), (256, 64, 8), (33, 64, 8),
+    # the kernel (in interpret mode) at widths it takes: three chunks, and a
+    # length it pads to three
+    pytest.param(384, 128, "kernel", id="kernel-384-128"),
+    pytest.param(300, 128, "kernel", id="kernel-300-128")])
 def test_chunked_scan_matches_the_recurrence(length, chunk, per_block):
-    H, P, N = 4, 8, 16
+    """Both tiers against the recurrence one position at a time. ``dt_bias``
+    is drawn as ``lib/weights_lm.py`` draws it (a head remembers 3 to 1,000
+    positions), so the state a chunk hands on matters: the kernel called a
+    chunk at a time, which drops it, misses the same tolerance."""
+    from gigapath_tpu.ops import pallas_ssd
+
+    H, P, N = (16, 64, 128) if per_block == "kernel" else (4, 8, 16)
     rng = np.random.default_rng(length)
-    x = jnp.asarray(rng.standard_normal((2, length, H, P)), jnp.float32)
-    dt = jax.nn.softplus(jnp.asarray(rng.standard_normal((2, length, H)), jnp.float32))
+    xBC = jnp.asarray(rng.standard_normal((2, length, H * P + 2 * N)), jnp.float32)
+    dt_bias = jnp.asarray(rng.uniform(-7.0, -1.0, H), jnp.float32)
+    dt = jax.nn.softplus(jnp.asarray(rng.standard_normal((2, length, H)), jnp.float32) + dt_bias)
     A = -jnp.exp(jnp.asarray(0.5 * rng.standard_normal(H), jnp.float32))
-    B, C = (jnp.asarray(rng.standard_normal((2, length, N)), jnp.float32) for _ in range(2))
     D = jnp.asarray(rng.standard_normal(H), jnp.float32)
-    got = ssd.ssd_scan(x, dt, A, B, C, D, chunk=chunk, chunks_per_block=per_block)
-    for b in range(2):
-        want = reference_lm.state_space_recurrence(x[b], dt[b], A, B[b], C[b]) + D[:, None] * x[b]
-        np.testing.assert_allclose(got[b], want, rtol=2e-4, atol=2e-4)
+    x, B, C = jnp.split(xBC, [H * P, H * P + N], axis=-1)
+    x = x.reshape(2, length, H, P)
+    want = jnp.stack([reference_lm.state_space_recurrence(x[b], dt[b], A, B[b], C[b])
+                      + D[:, None] * x[b] for b in range(2)]).reshape(2, length, H * P)
+    if per_block != "kernel":
+        got = ssd.ssd_scan_jnp(x, dt, A, B, C, D, chunk=chunk, chunks_per_block=per_block)
+        np.testing.assert_allclose(got.reshape(2, length, H * P), want, rtol=2e-4, atol=2e-4)
+        return
+    assert pallas_ssd.fits(H, P, N, chunk)
+
+    def kernel(xBC, dt):
+        return pallas_ssd.ssd_scan_fwd(xBC, dt, A, D, state_size=N, chunk=chunk, interpret=True)
+
+    got = kernel(xBC, dt)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    tier = ssd.ssd_scan_jnp(x, dt, A, B, C, D, chunk=chunk).reshape(2, length, H * P)
+    np.testing.assert_allclose(got, tier, rtol=1e-5, atol=1e-5)
+    # in bfloat16, as the cell runs it: the kernel rounds the update's weighted
+    # B where the jnp tier rounds the weighted x, and is as close as the tier to
+    # the recurrence over the same rounded inputs (both ~3e-3 of the largest |y|)
+    x16, B16, C16 = (a.astype(jnp.bfloat16) for a in (x, B, C))
+    exact = jnp.stack([reference_lm.state_space_recurrence(
+        *(a.astype(jnp.float32) for a in (x16[b], dt[b], A, B16[b], C16[b])))
+        + D[:, None] * x16[b].astype(jnp.float32) for b in range(2)]).reshape(2, length, H * P)
+    half = kernel(xBC.astype(jnp.bfloat16), dt).astype(jnp.float32)
+    tier16 = ssd.ssd_scan_jnp(x16, dt, A, B16, C16, D, chunk=chunk).astype(jnp.float32)
+    gap, tier_gap = (np.abs(y.reshape(2, length, H * P) - exact).max() for y in (half, tier16))
+    assert gap <= 1.5 * tier_gap < 1e-2 * np.abs(exact).max(), (gap, tier_gap)
+    dropped = jnp.concatenate([kernel(xBC[:, s:s + chunk], dt[:, s:s + chunk])
+                               for s in range(0, length, chunk)], axis=1)
+    assert not np.allclose(dropped, want, rtol=2e-4, atol=2e-4)
 
 
 def test_causal_conv_reads_no_later_position():
