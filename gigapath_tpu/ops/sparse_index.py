@@ -22,8 +22,11 @@ the scores equal to it, the lowest columns by bisection over the column
 index. :func:`sparse_attention` is a causal flash core that reads the mask a
 tile at a time beside the keys; it visits every key block at or below the
 diagonal (a query's keys are wherever its scores put them), so its cost is a
-dense causal core's, and what the selection saves is what a later kernel that
-gathers the selected keys would save.
+dense causal core's, and what the selection saves is what a kernel that
+visits only the selected keys would save. Such skipping exists for a selection
+by key block (:mod:`gigapath_tpu.ops.block_sparse`, whose ``block_sparse_attn``
+visits the blocks a tile of queries chose and no other); for this per-key
+selection it does not yet.
 """
 
 from __future__ import annotations

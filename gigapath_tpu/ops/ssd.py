@@ -3,8 +3,9 @@ convolution, the chunked state-space-duality (SSD) scan, gated RMSNorm and
 the output projection.
 
 The recurrence, per head with state ``S [P, N]`` (``P`` the head size, ``N``
-the state size; ``B`` and ``C`` are shared by the heads of a group, and there
-is one group)::
+the state size; ``B`` and ``C`` are shared by the heads of a group: Mamba-2
+has one group, and decayed linear attention (:func:`linear_scan`) a group a
+head)::
 
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T        y_t = S_t C_t + D x_t
 
@@ -62,31 +63,38 @@ def _advance(state, decay, chunk_state):
 def _chunk_block(D, state, block):
     """``cb`` chunks at once: ``state [b, H, P, N]`` float32 enters the first.
     ``x [b, cb, Q, H, P]``, ``dt`` and ``acs`` (the running sum of ``dt A``
-    inside each chunk) ``[b, cb, H, Q]`` float32, ``B``, ``C`` ``[b, cb, Q, N]``.
-    The block's ``y`` leaves in ``x``'s type, summed in float32 with the
-    ``D x`` skip before that one rounding."""
+    inside each chunk) ``[b, cb, H, Q]`` float32, ``B``, ``C`` ``[b, cb, Q, N]``
+    (one group) or ``[b, cb, Q, H, N]`` (a group a head). The block's ``y``
+    leaves in ``x``'s type, summed in float32 with the ``D x`` skip before
+    that one rounding."""
     x, dt, acs, B, C = block
     Q = x.shape[2]
+    per_head = B.ndim == 5
     # inside a chunk: y_i = sum_{j <= i} (C_i . B_j) exp(acs_i - acs_j) dt_j x_j
-    scores = jnp.einsum("bcqn,bckn->bcqk", C, B, preferred_element_type=jnp.float32)
+    if per_head:
+        scores = jnp.einsum("bcqhn,bckhn->bchqk", C, B, preferred_element_type=jnp.float32)
+    else:
+        scores = jnp.einsum("bcqn,bckn->bcqk", C, B, preferred_element_type=jnp.float32)
     lower = jnp.tril(jnp.ones((Q, Q), bool))
     # the exponent is masked, not the exponential: above the diagonal the
     # difference is positive and would overflow
     decay = jnp.exp(jnp.where(lower, acs[..., :, None] - acs[..., None, :], -jnp.inf))
-    mixed = (scores[:, :, None] * decay * dt[..., None, :]).astype(x.dtype)
+    mixed = ((scores if per_head else scores[:, :, None]) * decay
+             * dt[..., None, :]).astype(x.dtype)
     y = jnp.einsum("bchqk,bckhp->bcqhp", mixed, x, preferred_element_type=jnp.float32)
     # what each chunk leaves: sum_j exp(acs_last - acs_j) dt_j x_j B_j^T
     left = (jnp.exp(acs[..., -1:] - acs) * dt).transpose(0, 1, 3, 2)[..., None]
     chunk_states = jnp.einsum(
-        "bcqn,bcqhp->bchpn", B, (x.astype(jnp.float32) * left).astype(x.dtype),
-        preferred_element_type=jnp.float32)
+        "bcqhn,bcqhp->bchpn" if per_head else "bcqn,bcqhp->bchpn", B,
+        (x.astype(jnp.float32) * left).astype(x.dtype), preferred_element_type=jnp.float32)
     # the state that enters each chunk, by the recurrence over the block's chunks
     entering = []
     for c in range(x.shape[1]):
         entering.append(state)
         state = _advance(state, jnp.exp(acs[:, c, :, -1]), chunk_states[:, c])
     entering = jnp.stack(entering, axis=1).astype(x.dtype)
-    carried = jnp.einsum("bcqn,bchpn->bcqhp", C, entering, preferred_element_type=jnp.float32)
+    carried = jnp.einsum("bcqhn,bchpn->bcqhp" if per_head else "bcqn,bchpn->bcqhp", C, entering,
+                         preferred_element_type=jnp.float32)
     y = y + carried * jnp.exp(acs).transpose(0, 1, 3, 2)[..., None]
     return state, (y + D[:, None] * x.astype(jnp.float32)).astype(x.dtype)
 
@@ -96,7 +104,8 @@ def ssd_scan_jnp(x, dt, A, B, C, D, *, chunk: int = 256,
     """The ``jnp`` tier of :func:`ssd_scan`, ``y_t = S_t C_t + D x_t``.
 
     ``x [b, L, H, P]``; ``dt [b, L, H]`` float32, after the softplus; ``A [H]``
-    float32, negative; ``B``, ``C`` ``[b, L, N]``; ``D [H]``. Returns ``y [b,
+    float32, negative; ``B``, ``C`` ``[b, L, N]`` (one group) or ``[b, L, H,
+    N]`` (a group a head); ``D [H]``. Returns ``y [b,
     L, H, P]`` in ``x``'s type (a [L, heads x head size] float32 array is
     0.5 GB at 16,384 tokens). ``L`` need be no multiple of ``chunk``: the tail
     is padded with ``dt = 0``, under which a position neither decays the
@@ -147,6 +156,28 @@ def ssd_scan(xBC, dt, A_log, dt_bias, D, *, state_size: int, chunk: int = 256):
     x = x.reshape(b, L, H, P)
     dt, A = _steps(dt, A_log, dt_bias)
     return ssd_scan_jnp(x, dt, A, B, C, D, chunk=chunk).reshape(b, L, inner)
+
+
+def linear_scan(x, B, C, A, *, chunk: int = 128):
+    """Decayed linear attention as the scan with a ``B`` / ``C`` group a head,
+    ``dt = 1`` and ``D = 0``: ``y_t,h = sum_{s<=t} exp(A_h (t - s)) (C_t,h .
+    B_s,h) x_s,h``, so ``S_t = exp(A_h) S_{t-1} + x_t B_t^T``. ``x [b, L, H,
+    P]`` (the values), ``B``, ``C`` ``[b, L, H, N]`` (keys and queries, any
+    scale already on them), ``A [H]`` float32, the log of each head's decay.
+    Returns ``y [b, L, H P]`` in ``x``'s type. The kernel where the device gate
+    says TPU and :func:`gigapath_tpu.ops.pallas_ssd.fits` takes the widths,
+    the ``jnp`` tier elsewhere."""
+    b, L, H, P = x.shape
+    A = A.astype(jnp.float32)
+    if _gate._on_tpu():
+        from gigapath_tpu.ops import pallas_ssd
+
+        if pallas_ssd.fits(H, P, B.shape[-1], chunk, per_head=True):
+            return pallas_ssd.linear_scan_fwd(x.reshape(b, L, -1), B.reshape(b, L, -1),
+                                              C.reshape(b, L, -1), A, chunk=chunk)
+    dt = jnp.ones((b, L, H), jnp.float32)
+    return ssd_scan_jnp(x, dt, A, B, C, jnp.zeros((H,), jnp.float32),
+                        chunk=chunk).reshape(b, L, H * P)
 
 
 class Mamba2Mixer(nn.Module):

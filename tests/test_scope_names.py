@@ -22,6 +22,7 @@ _SLIDE = tables.load("configs", "gigapath_slide_enc12l768d")["tiny"]
 _LM = tables.load("configs", "granite4h_small_ep2")["tiny"]
 _AXK1 = tables.load("configs", "axk1_ep16")["tiny"]
 _BRUMBY = tables.load("configs", "brumby14b_pp5")["tiny"]
+_SALA = tables.load("configs", "minicpm_sala_pp8")["tiny"]
 _N_TOKENS = 40  # + class token = 41: three 16-token and two 32-token segments
 
 
@@ -106,6 +107,18 @@ def _brumby_forward(length=40, **widths):
     return pipeline.lm_forward_fn.__wrapped__(model), (params, ids, rows)
 
 
+def _sala_forward(length=40, **widths):
+    from gigapath_tpu import pipeline
+    from gigapath_tpu.utils.registry import create_model_from_registry
+    import gigapath_tpu.models.minicpm_sala  # noqa: F401
+
+    model = create_model_from_registry(_SALA["arch"], depth=_SALA["depth"], **widths)
+    ids = jax.ShapeDtypeStruct((2, length), jnp.int32)
+    rows = jax.ShapeDtypeStruct((2, 4), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids, rows)["params"]
+    return pipeline.lm_forward_fn.__wrapped__(model), (params, ids, rows)
+
+
 def _on_kernels(monkeypatch_context, build):
     """``build()`` with the device gate answering "TPU" and every
     ``pallas_call`` in interpret mode: the slide encoder then takes the fused
@@ -184,6 +197,14 @@ def _lowered(path: str) -> str:
         if path == "brumby_kernels":  # the kernel takes heads of 128 and chunks of 128
             return _on_kernels(mp, lambda: _text(*_brumby_forward(
                 length=200, head_dim=128, retention_chunk=128)))
+        if path == "sala_jnp":
+            return _text(*_sala_forward())
+        if path == "sala_kernels":  # heads of 128, 8 query heads a KV group, chunks of 128
+            return _on_kernels(mp, lambda: _text(*_sala_forward(
+                length=200, head_dim=128, num_attention_heads=16, lightning_nh=16,
+                lightning_nkv=16, lightning_head_dim=128, lightning_chunk=128,
+                sparse_block_size=16, sparse_window_size=16, sparse_kernel_size=16,
+                sparse_kernel_stride=8)))
         if path == "fused_grad":
             return _text(_grad_of(functools.partial(
                 da.dilated_attention_fused, segment_lengths=[16, 32],
@@ -274,6 +295,12 @@ _NAMES = {
                    "lm_head"],
     "brumby_kernels": ["jit_lm_forward", "rope", "retention", "gate", "kernel_fwd",
                        "power_retention_fwd", "mlp", "lm_head"],
+    "sala_jnp": ["jit_lm_forward", "self_attn", "q_proj", "k_proj", "v_proj", "q_norm", "k_norm",
+                 "block_score", "compress", "score", "block_select", "attn_core", "kernel_fwd",
+                 "out_gate", "o_proj", "rope", "lightning", "out_norm", "mlp", "lm_head"],
+    "sala_kernels": ["jit_lm_forward", "block_score", "compress", "score", "kernel_fwd",
+                     "block_select", "attn_core", "block_sparse_attn", "rope", "lightning",
+                     "ssd_scan_fwd", "out_norm", "out_gate", "mlp", "lm_head"],
     "fused_grad": ["dilated_attn", "branch_r2", "pack", "kernel_fwd", "kernel_dq",
                    "kernel_dkv", "unpack", "merge", "dilated_pack", "dilated_fwd_overlap",
                    "dilated_dq", "dilated_dkv", "dilated_unpack", "dilated_epilogue_fwd",
@@ -439,6 +466,31 @@ def test_power_retention_holds_its_steps_in_order_of_the_path():
                      text)
     assert re.search(r'"[^"]*/layers_1/mlp/', text)
     assert re.search(r'"[^"]*/lm_head/lm_head/', text)
+
+
+def test_block_sparse_attention_holds_its_steps_in_order_of_the_path():
+    """``.../layers_0/self_attn/<projection | norm | block_score | block_select
+    | attn_core | out_gate>/...``: the compressed keys and their scores under
+    ``block_score``, the top-k under ``block_select``, the tiles' lists and the
+    kernel under ``attn_core``; the lightning layers' scan, one jitted function
+    for all three, under ``lightning`` and its norm under ``out_norm``
+    (benchmarks/scopes/sala.json matches on these)."""
+    text = _lowered("sala_kernels")
+    for step in ("q_proj", "k_proj", "v_proj", "q_norm", "k_norm", "out_gate", "o_proj"):
+        assert re.search(rf'"[^"]*/layers_0/self_attn/{step}[/"]', text), step
+    assert re.search(r'"[^"]*/layers_0/self_attn/block_score/compress/', text)
+    assert "/layers_0/self_attn/block_score/score/jit(_score_call)" in text
+    assert re.search(r'"[^"]*/layers_0/self_attn/block_select/', text)
+    assert "/layers_0/self_attn/attn_core/jit(_attn_call)" in text
+    for kernel in ("block_score", "block_sparse_attn", "ssd_scan_fwd"):
+        assert re.search(rf'"kernel_fwd/{kernel}/', text), kernel
+    for layer in (1, 2, 3):
+        assert f"/layers_{layer}/self_attn/lightning/jit(_linear_call)" in text, layer
+        assert re.search(rf'"[^"]*/layers_{layer}/self_attn/out_norm/', text), layer
+        assert re.search(rf'"[^"]*/layers_{layer}/self_attn/rope/', text), layer
+    assert len(re.findall(r"func.func private @_linear_call", text)) == 1
+    assert not re.search(r'"[^"]*/layers_0/self_attn/lightning/', text)
+    assert re.search(r'"[^"]*/layers_1/mlp/', text) and re.search(r'"[^"]*/lm_head/lm_head/', text)
 
 
 # Equation counts of the parent commit (7f80832), every nested jaxpr counted,

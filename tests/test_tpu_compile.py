@@ -524,11 +524,13 @@ def test_granite_layers_at_published_widths_hold_their_kernels(topo, one_chip, m
         assert '"scoped_memory_configs":[]' in call
         # the jnp tier's 2.16 GB of temporaries -> 1.10 GB
         assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
-        # no kernel table of the benchmark takes it: its time stays the scan's
+        # no kernel table of the benchmark takes it but the scan's own by its
+        # name, which only the MiniCPM-SALA cell's lightning_roofline reads:
+        # in this cell its time stays the scan group's
         from benchmarks.lib import tables
 
         for table in tables.names("kernels"):
-            assert not _picked(tables.kernel_table(table), call), table
+            assert _picked(tables.kernel_table(table), call) == (table == "ssd_scan_by_name"), table
     else:
         # rows move by the kernels' own copies: XLA gathers no sorted buffer and
         # builds no [S, k, M] array for the weighted sum
@@ -679,3 +681,53 @@ def test_brumby_retention_at_published_widths_holds_its_kernel(topo, one_chip, m
     assert "[32768,32768]" not in text and "[1,32768,32768]" not in text
     # the layer's temporaries beside 8.40 GB of weights on a 16 GB chip
     assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+
+
+@pytest.mark.parametrize("piece", ["sparse", "lightning"])
+def test_sala_layers_at_the_cells_length_hold_their_kernels(topo, one_chip, monkeypatch, piece):
+    """MiniCPM-SALA's two token mixers at the cell's 65,536 tokens and the
+    published widths, the device gate answering "TPU". The sparse layer is one
+    ``block_score`` call (its float32 scores ``[2, 1,024 blocks, L]``, no
+    unit's score in memory) and one ``block_sparse_attn`` call over 32 query
+    and 2 KV heads of 128; no ``[L, L]`` array and no ``[32, L, L / 16]`` one
+    exists. A lightning layer is one ``ssd_scan_fwd`` call with a ``B`` / ``C``
+    group a head. Each stands under the scope the trace's reduction finds it
+    by (benchmarks/scopes/sala.json) and is taken by its kernel table."""
+    import gigapath_tpu.ops.flash_attention as fa
+    from benchmarks.lib import tables
+    from gigapath_tpu.models.minicpm_sala import LightningAttention, SparseAttention
+    from gigapath_tpu.obs.ledger import custom_calls_of
+    from gigapath_tpu.utils.registry import create_model_from_registry
+
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    cfg = create_model_from_registry("minicpm_sala", depth=4).cfg
+    L = 65536
+    x = jax.ShapeDtypeStruct((1, L, 4096), jnp.bfloat16)
+    if piece == "sparse":
+        layer, shapes = SparseAttention(cfg), (x, jax.ShapeDtypeStruct((1, 16), jnp.int32))
+        kernels = {"block_score": ("block_score/score/jit\\(_score_call\\)/kernel_fwd/block_score/",
+                                   "f32[1,2,1024,65536]", "block_score_by_name"),
+                   "block_sparse_attn": ("attn_core/jit\\(_attn_call\\)/kernel_fwd/block_sparse_attn/",
+                                         "bf16[1,65536,32,128]", "block_sparse_attn_by_name")}
+    else:
+        rot = jax.ShapeDtypeStruct((L, 64), jnp.float32)
+        layer, shapes = LightningAttention(cfg, 1), (x, rot, rot)
+        kernels = {"ssd_scan_fwd": ("lightning/jit\\(_linear_call\\)/kernel_fwd/ssd_scan_fwd/",
+                                    "bf16[1,65536,4096]", "ssd_scan_by_name")}
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), *shapes)
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), (params, *shapes))
+    compiled = jax.jit(layer.apply).lower(*avals).compile()
+    assert custom_calls_of(compiled) == len(kernels)
+    text = compiled.as_text()
+    for kernel, (scope, result, table) in kernels.items():
+        assert re.search(rf'op_name="[^"]*/{scope}', text), kernel
+        calls = re.findall(rf"\n\s*(?:ROOT )?%{kernel}(?:\.\d+)? = ([^\n]*) custom-call\(", text)
+        assert len(calls) == 1 and calls[0].startswith(result), calls
+        call = next(line for line in text.splitlines() if f"%{kernel}" in line and " = " in line)
+        assert _picked(tables.kernel_table(table), call), kernel
+    assert not re.search(r"65536,65536\]", text)
+    assert not re.search(r"\[(1,)?32,65536,409[56]\]", text)
+    # the layer's temporaries beside 3.42 GB of weights on a 16 GB chip
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+
