@@ -1161,10 +1161,19 @@ def _valid_rows(g: int, L: int, s, i, rows: int):
     segment s (inside the segment AND inside the sequence); may be <= 0.
     (``lax`` primitives on int32 scalars here and below, not ``jnp.minimum``
     or the ``*`` / ``-`` / ``//`` / ``<`` operators: inside a kernel each of
-    those is a ``jit`` of its own to trace and lower, and the program
-    holds 240 of these kernels.)"""
+    those is a ``jit`` of its own to trace and lower, and a program holds
+    many of these kernels.)"""
     in_segment = lax.min(np.int32(g), lax.sub(np.int32(L), lax.mul(s, np.int32(g))))
     return lax.sub(in_segment, lax.mul(i, np.int32(rows)))
+
+
+def _zero_past(x, valid):
+    """A dense window with its rows from ``valid`` (traced) on set to exact
+    zeros, by LOGICAL row index: packed K/V MUST be exact zeros at padded
+    slots or p=0 x NaN poisons the PV matmul."""
+    row = lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    real = lax.lt(row, lax.broadcast_in_dim(valid, x.shape, ()))
+    return lax.select(real, x, lax.full_like(x, 0))
 
 
 def _emit_packed(x, valid, o_ref, *, r, hb, Dh, bt):
@@ -1172,15 +1181,11 @@ def _emit_packed(x, valid, o_ref, *, r, hb, Dh, bt):
     tokens of this segment -> all phases' [r, hb, bt, Dh] packed blocks:
     the rows-of-r-tokens -> r*E-lanes re-tile happens in VMEM. Rows past
     the segment's end (the NEXT segment's real tokens when Mp*r > g) or
-    past L (whatever stands there, possibly non-finite) are zeroed by
-    LOGICAL row index first: packed K/V MUST be exact zeros at padded
-    slots or p=0 x NaN poisons the PV matmul. One straight-line body for
-    full, partial and empty windows alike: a second copy of the band
-    extraction under ``pl.when`` doubled what every one of the program's
-    180 pack calls costs to trace and lower."""
-    row = lax.broadcasted_iota(jnp.int32, x.shape, 0)
-    real = lax.lt(row, lax.broadcast_in_dim(valid, x.shape, ()))
-    x = lax.select(real, x, lax.full_like(x, 0))
+    past L (whatever stands there, possibly non-finite) are zeroed first.
+    One straight-line body for full, partial and empty windows alike: a
+    second copy of the band extraction under ``pl.when`` doubled what every
+    pack call costs to trace and lower."""
+    x = _zero_past(x, valid)
     _extract_bands(x.reshape(bt, r * x.shape[-1]), o_ref, r, hb, Dh)
 
 
@@ -1323,7 +1328,6 @@ def _pad_segments(x: jnp.ndarray, g: int, S: int, gp2: int) -> jnp.ndarray:
     return x
 
 
-@jax.named_scope("pack")
 def _pack_phases(x: jnp.ndarray, g: int, S: int, r: int, Mp: int, H: int,
                  interpret: bool) -> jnp.ndarray:
     """[B, L, E] -> packed [B, S, r, hb, Mp, Dh] holding ONLY the diagonal
@@ -1332,7 +1336,7 @@ def _pack_phases(x: jnp.ndarray, g: int, S: int, r: int, Mp: int, H: int,
     tensor; the kernels only ever read the diagonal. One pallas_call,
     reading every dense byte at most once; the dense array is its operand
     as it stands (no XLA pad or relayout) unless :func:`_copy_plan`
-    says ``"padded"``."""
+    says ``"padded"``. Callers open the ``pack`` scope."""
     B, L, E = x.shape
     hb = H // r
     Dh = E // H
@@ -1384,6 +1388,221 @@ def _pack_phases(x: jnp.ndarray, g: int, S: int, r: int, Mp: int, H: int,
         interpret=interpret,
         name="dilated_pack",
     )(x)
+
+
+# The joint pass: one read of a projection writes the packed copy of every
+# branch it can serve. Grid step j holds the dense window of ``rows`` rows
+# at ``j * rows``, zeroed past L, and writes one packed block of bt =
+# rows / r packed rows a member: block i of segment s covers the dense rows
+# ``[s*g + i*rows, +rows)``. Where a segment starts on the window grid that
+# is window ``first_s + i``; where it starts ``off`` rows past it, the block
+# is joined from the last ``rows - off`` rows of the previous window (kept
+# in VMEM from the step before) and the first ``off`` of this one, in the
+# step of the later window. A branch joins where its segments' blocks fall
+# on steps of their own: ``first_s = ceil(s*g / rows)`` and each segment's
+# ``Mp / bt`` blocks end before the next segment's first. The branch with
+# the largest packed copies keeps a call of its own: the attention's peak
+# is at its kernel, with its three copies live, and a pass that wrote the
+# other branches' copies beside them would hold those live there too.
+_JOINT_ROWS = (2048, 1024, 512, 256, 128, 64, 32, 16)
+# what _joint_vmem may count for one grid step: the compiler's scoped
+# limit is 16 MiB by default and no copy kernel raises it
+_JOINT_VMEM_BUDGET = 12 * 2 ** 20
+
+
+class PackPlan(NamedTuple):
+    """Static geometry of the packing of one projection for a branch set.
+    Hashable: the static argument of :func:`_pack_call`."""
+
+    H: int
+    geoms: Tuple[Tuple[int, int, int, int], ...]  # (g, S, r, Mp) a branch
+    rows: int  # dense rows a window of the joint pass (0: no joint pass)
+    members: Tuple[int, ...]  # the branches the joint pass writes
+    interpret: bool = False
+
+
+def _segment_steps(rows: int, geom) -> Optional[Tuple[int, ...]]:
+    """The grid step of each segment's first packed block in a joint pass
+    of ``rows``-row windows, or None where the branch cannot join one."""
+    g, S, r, Mp = geom
+    bt = rows // r
+    firsts = tuple(-(-s * g // rows) for s in range(S))
+    if any(b - a < Mp // bt for a, b in zip(firsts, firsts[1:])):
+        return None  # a segment's blocks would reach the next one's steps
+    return firsts
+
+
+def _joins(rows: int, L: int, geom, tile: int) -> bool:
+    """Whether a joint pass of ``rows``-row windows can write the branch
+    ``geom``: whole packed blocks of the sublane tile, windows joined at
+    row offsets on the tile, segments on steps of their own."""
+    g, S, r, Mp = geom
+    if rows % r or rows > L or (S > 1 and g % tile):
+        return False
+    bt = rows // r
+    return (bt % tile == 0 and Mp % bt == 0
+            and _segment_steps(rows, geom) is not None)
+
+
+def _packed_bytes(geom, H: int, E: int) -> int:
+    """Bytes of one packed copy in HBM, a row padded to whole lane tiles."""
+    g, S, r, Mp = geom
+    return S * H * Mp * _round_up(E // H, LANES)
+
+
+def _joint_vmem(rows: int, E: int, H: int, geoms, itemsize: int) -> int:
+    """Bytes one grid step of the joint pass holds: the double-buffered
+    window and its zeroed copy; a re-tiled copy a member, and for a member
+    whose blocks are joined from two windows the previous window and the
+    joined one besides; each member's double-buffered packed block (a row
+    padded to whole lane tiles)."""
+    window = rows * E * itemsize
+    lanes = _round_up(E // H, LANES)
+    windows, blocks = 3, 0
+    for g, S, r, Mp in geoms:
+        windows += 1 if S == 1 or g % rows == 0 else 3
+        blocks += (H // r) * rows * lanes * itemsize
+    return windows * window + 2 * blocks
+
+
+def plan_pack(L: int, E: int, H: int, geoms, itemsize: int,
+              interpret: bool = False) -> PackPlan:
+    """The window height that lets the most branches share one pass within
+    the VMEM budget (the taller on a tie), decided from shapes alone; the
+    branch with the largest packed copies never joins, and there is no
+    joint pass where fewer than two branches can share one."""
+    tile = _sublane_tile(itemsize)
+    largest = max(range(len(geoms)), key=lambda i: _packed_bytes(geoms[i], H, E))
+    best = (1, 0, ())
+    for rows in _JOINT_ROWS:
+        members = tuple(
+            i for i, geom in enumerate(geoms)
+            if i != largest and _joins(rows, L, geom, tile)
+        )
+        if _joint_vmem(rows, E, H, [geoms[i] for i in members],
+                       itemsize) <= _JOINT_VMEM_BUDGET:
+            best = max(best, (len(members), rows, members))
+    _, rows, members = best
+    return PackPlan(H=H, geoms=tuple(geoms), rows=rows, members=members,
+                    interpret=bool(interpret))
+
+
+def _segment_of(j, firsts):
+    """(segment, block in it) of grid step j (traced) from the segments'
+    first steps."""
+    s = np.int32(0)
+    for first in firsts[1:]:
+        s = lax.add(s, lax.convert_element_type(
+            lax.ge(j, np.int32(first)), jnp.int32))
+    start = np.int32(firsts[0])
+    for k, first in enumerate(firsts[1:], 1):
+        start = lax.select(lax.eq(s, np.int32(k)), np.int32(first), start)
+    return s, lax.sub(j, start)
+
+
+def _joint_pack_kernel(x_ref, *refs, L, members, steps):
+    """One dense [rows, E] window -> the packed block of every member it
+    serves: its rows past L zeroed once, then each member's block (joined
+    from the previous window where its segment starts off the window grid),
+    re-tile and band extraction. A step that holds no block of a member
+    writes nothing to it (its block index stays on the block before, which
+    is copied out once). refs: the members' outputs, then the previous
+    window where a member joins two."""
+    j = pl.program_id(1)
+    rows = x_ref.shape[0]
+    x = _zero_past(x_ref[...], lax.sub(np.int32(L), lax.mul(j, np.int32(rows))))
+    prev_ref = refs[len(members)] if len(refs) > len(members) else None
+    for o_ref, (r, hb, Dh, g, firsts, blocks, offsets) in zip(refs, members):
+        s, i = _segment_of(j, firsts)
+
+        def emit(o_ref=o_ref, r=r, hb=hb, Dh=Dh, g=g, s=s, i=i,
+                 offsets=offsets):
+            if not any(offsets):  # the window is the block, zeroed past L
+                _extract_bands(x.reshape(rows // r, r * x.shape[-1]),
+                               o_ref, r, hb, Dh)
+                return
+            block = x
+            for k, off in enumerate(offsets):
+                if off:
+                    joined = lax.concatenate([prev_ref[off:], x[:off]], 0)
+                    block = lax.select(
+                        lax.broadcast_in_dim(lax.eq(s, np.int32(k)), x.shape, ()),
+                        joined, block)
+            _emit_packed(block, _valid_rows(g, L, s, i, rows), o_ref,
+                         r=r, hb=hb, Dh=Dh, bt=rows // r)
+
+        if firsts == tuple(range(0, steps, blocks)):  # a block every step
+            emit()
+        else:
+            pl.when(lax.lt(i, np.int32(blocks)))(emit)
+    if prev_ref is not None:
+        prev_ref[...] = x
+
+
+def _pack_joint(x: jnp.ndarray, plan: PackPlan):
+    """[B, L, E] -> the members' packed [B, S, r, hb, Mp, Dh] arrays, equal
+    bit for bit to :func:`_pack_phases`' of each: one pallas_call, grid
+    (batch, window), the dense array its operand as it stands."""
+    B, L, E = x.shape
+    H, rows = plan.H, plan.rows
+    Dh = E // H
+    members, out_specs, out_shape, steps = [], [], [], 0
+    for i in plan.members:
+        g, S, r, Mp = plan.geoms[i]
+        hb, bt = H // r, rows // r
+        firsts = _segment_steps(rows, plan.geoms[i])
+        offsets = tuple(s * g % rows for s in range(S))
+        steps = max(steps, firsts[-1] + Mp // bt)
+        members.append((r, hb, Dh, g, firsts, Mp // bt, offsets))
+
+        def block(b, j, firsts=firsts, final=np.int32(Mp // bt - 1)):
+            s, k = _segment_of(j, firsts)
+            return b, s, 0, 0, lax.min(k, final), 0
+
+        out_specs.append(pl.BlockSpec(
+            (1, 1, r, hb, bt, Dh), block, memory_space=pltpu.VMEM,
+        ))
+        out_shape.append(jax.ShapeDtypeStruct((B, S, r, hb, Mp, Dh), x.dtype))
+    # a window wholly past L is clamped to the last one that starts inside
+    # (its rows are all zeroed, and an unchanged block index is not copied
+    # again)
+    last = np.int32((L - 1) // rows)
+    joined = any(any(m[-1]) for m in members)
+    return pl.pallas_call(
+        functools.partial(
+            _joint_pack_kernel, L=L, members=tuple(members), steps=steps,
+        ),
+        grid=(B, steps),
+        in_specs=[pl.BlockSpec(
+            (None, rows, E), lambda b, j: (b, lax.min(j, last), 0),
+            memory_space=pltpu.VMEM,
+        )],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((rows, E), x.dtype)] if joined else [],
+        # in order: a block is joined from the window of the step before,
+        # and a member's block stays in place over the steps that hold none
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 2
+        ),
+        interpret=plan.interpret,
+        name="dilated_pack",
+    )(x)
+
+
+@functools.partial(jax.jit, static_argnames=("plan",))
+def _pack_call(x: jnp.ndarray, *, plan: PackPlan):
+    """One projection [B, L, E] -> the packed copy of every branch of
+    ``plan``, in its order: the joint pass for its members, a call of
+    :func:`_pack_phases` for each other branch. A jitted function of its
+    own, static in the plan, so that q, k, v and the layers of a model
+    share one trace and one lowering."""
+    joint = dict(zip(plan.members, _pack_joint(x, plan))) if plan.members else {}
+    return tuple(
+        joint[i] if i in joint
+        else _pack_phases(x, g, S, r, Mp, plan.H, plan.interpret)
+        for i, (g, S, r, Mp) in enumerate(plan.geoms)
+    )
 
 
 @jax.named_scope("unpack")
@@ -1517,26 +1736,6 @@ def _dilated_branch(q, k, v, vl_dyn, sl, r, H, real_len, causal, interpret,
     return out, lse
 
 
-def _branch_packed_fwd_impl(q, k, v, vl_dyn, sl, r, H, real_len, causal,
-                            interpret, flags, body: Optional[str] = None):
-    """Shared forward core: dense [B, L, E] q/k/v -> PACKED
-    ``(out6 [B, S, r, hb, Mp, Dh], lse5 [B, S, r, Mp, LANES])`` — the
-    kernel-native layout, consumed either by the dense unpack/scatter pair
-    (:func:`_dilated_branch_fwd_impl`) or directly by the streaming fusion
-    epilogue (which never materializes the dense per-branch tensors).
-    ``body``: see :func:`_packed_forward`."""
-    B, L, E = q.shape
-    Dh = E // H
-    g, S, gp, m, Mp, block = _branch_geometry(L, E, sl, r)
-    q6 = _pack_phases(q, g, S, r, Mp, H, interpret)
-    k6 = _pack_phases(k, g, S, r, Mp, H, interpret)
-    v6 = _pack_phases(v, g, S, r, Mp, H, interpret)
-    kvlen = _branch_kvlen(B, S, g, r, m, real_len, vl_dyn)
-    return _packed_forward(
-        q6, k6, v6, kvlen, causal, H // r, Dh, block, interpret, body
-    )
-
-
 def _packed_forward(q6, k6, v6, kvlen, causal, hb, Dh, block, interpret,
                     body: Optional[str] = None):
     """The forward kernel over packed q / k / v: the body
@@ -1559,10 +1758,16 @@ def _packed_forward(q6, k6, v6, kvlen, causal, hb, Dh, block, interpret,
 
 def _dilated_branch_fwd_impl(q, k, v, vl_dyn, sl, r, H, real_len, causal,
                              interpret, flags, body: Optional[str] = None):
+    """One branch's forward: dense [B, L, E] q/k/v -> dense ``(out, lse)``
+    and the kernel-native packed ``(out6, lse5)``. ``body``: see
+    :func:`_packed_forward`."""
     B, L, E = q.shape
     g, S, gp, m, Mp, block = _branch_geometry(L, E, sl, r)
-    out6, lse5 = _branch_packed_fwd_impl(
-        q, k, v, vl_dyn, sl, r, H, real_len, causal, interpret, flags, body
+    with jax.named_scope("pack"):
+        q6, k6, v6 = (_pack_phases(x, g, S, r, Mp, H, interpret) for x in (q, k, v))
+    kvlen = _branch_kvlen(B, S, g, r, m, real_len, vl_dyn)
+    out6, lse5 = _packed_forward(
+        q6, k6, v6, kvlen, causal, H // r, E // H, block, interpret, body
     )
     # off-band lanes come back as exact zeros from the unpack kernel — the
     # branch's cover pattern needs no separate select
@@ -1584,20 +1789,23 @@ def _dilated_branch_fwd(q, k, v, vl_dyn, sl, r, H, real_len, causal,
     return (out, lse), ((q, k, v, vl_dyn) + res, q.shape)
 
 
-def _branch_bwd_core(q, k, v, vl_dyn, do6, out6, lse5, sl, r, H, real_len,
-                     causal, interpret, flags):
-    """Shared backward core: PACKED cotangent ``do6`` (plus the saved
-    packed forward results) -> dense ``(dq, dk, dv, vl_ct)``. Callers:
-    the dense branch VJP (packs its dense ``do`` first) and the packed
-    branch VJP behind the streaming fusion epilogue (whose epilogue
-    backward emits ``do6`` already packed — no dense round-trip)."""
-    B, L, E = q.shape
+def _valid_len_ct(vl_dyn):
+    """The (zero) cotangent of the traced valid lengths."""
+    return (None if vl_dyn is None
+            else np.zeros(vl_dyn.shape, dtype=jax.dtypes.float0))
+
+
+def _branch_bwd_core(q6, k6, v6, vl_dyn, do6, out6, lse5, L, E, sl, r, H,
+                     real_len, causal, interpret, flags):
+    """Shared backward core: PACKED q / k / v and cotangent ``do6`` (plus
+    the saved packed forward results) -> dense ``(dq, dk, dv)``. Callers:
+    the dense branch VJP (packs its dense ``do`` beside q / k / v) and the
+    branch-set VJP behind the merge epilogue (whose epilogue backward
+    emits ``do6`` already packed — no dense round-trip)."""
+    B = q6.shape[0]
     Dh = E // H
     hb = H // r
     g, S, gp, m, Mp, block = _branch_geometry(L, E, sl, r)
-    q6 = _pack_phases(q, g, S, r, Mp, H, interpret)
-    k6 = _pack_phases(k, g, S, r, Mp, H, interpret)
-    v6 = _pack_phases(v, g, S, r, Mp, H, interpret)
     # delta = rowsum(do * out) per (token, head), in the kernel's lse
     # layout [B, S, r, Mp, LANES] — the packed arrays ARE the diagonal
     delta = (do6.astype(jnp.float32) * out6.astype(jnp.float32)).sum(axis=-1)
@@ -1615,17 +1823,11 @@ def _branch_bwd_core(q, k, v, vl_dyn, do6, out6, lse5, sl, r, H, real_len,
             q6, k6, v6, do6, lse5, delta, kvlen, causal, Dh ** -0.5,
             hb, Dh, block, block, interpret,
         )
-
-    def undo(x6):
-        # off-band lanes are exact zeros from the unpack kernel — which IS
-        # the correct gradient there (the branch never reads those slots)
-        return _unpack_phases(x6, L, E, g, S, r, interpret)
-
-    vl_ct = (
-        None if vl_dyn is None
-        else np.zeros(vl_dyn.shape, dtype=jax.dtypes.float0)
+    # off-band lanes are exact zeros from the unpack kernel — which IS the
+    # correct gradient there (the branch never reads those slots)
+    return tuple(
+        _unpack_phases(x6, L, E, g, S, r, interpret) for x6 in (dq6, dk6, dv6)
     )
-    return undo(dq6), undo(dk6), undo(dv6), vl_ct
 
 
 def _dilated_branch_bwd(sl, r, H, real_len, causal, interpret, flags, saved,
@@ -1633,11 +1835,14 @@ def _dilated_branch_bwd(sl, r, H, real_len, causal, interpret, flags, saved,
     (q, k, v, vl_dyn, out6, lse5), (B, L, E) = saved
     do, _dlse = cotangents  # no gradient flows through the lse output
     g, S, gp, m, Mp, block = _branch_geometry(L, E, sl, r)
-    do6 = _pack_phases(do, g, S, r, Mp, H, interpret)
+    with jax.named_scope("pack"):
+        do6, q6, k6, v6 = (
+            _pack_phases(x, g, S, r, Mp, H, interpret) for x in (do, q, k, v)
+        )
     return _branch_bwd_core(
-        q, k, v, vl_dyn, do6, out6, lse5, sl, r, H, real_len, causal,
-        interpret, flags,
-    )
+        q6, k6, v6, vl_dyn, do6, out6, lse5, L, E, sl, r, H, real_len,
+        causal, interpret, flags,
+    ) + (_valid_len_ct(vl_dyn),)
 
 
 _dilated_branch.defvjp(_dilated_branch_fwd, _dilated_branch_bwd)
@@ -1684,76 +1889,82 @@ def dilated_branch_attention(
 
 
 # ---------------------------------------------------------------------------
-# packed-boundary branch op (for the streaming fusion epilogue)
+# the branch set with a packed output boundary (for the merge epilogue)
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
-def _dilated_branch_packed(q, k, v, vl_dyn, sl, r, H, real_len, causal,
-                           interpret, flags):
-    """Branch op with a PACKED output boundary: dense q/k/v in, packed
-    ``(out6, lse5)`` out. Twin of :func:`_dilated_branch` whose backward
-    accepts the cotangent *already in the packed layout* (the epilogue
-    backward emits it there), so neither direction ever materializes the
-    dense per-branch out/lse tensors."""
-    out6, lse5 = _branch_packed_fwd_impl(
-        q, k, v, vl_dyn, sl, r, H, real_len, causal, interpret, flags
-    )
-    return out6, lse5
-
-
-def _dilated_branch_packed_fwd(q, k, v, vl_dyn, sl, r, H, real_len, causal,
-                               interpret, flags):
-    out6, lse5 = _branch_packed_fwd_impl(
-        q, k, v, vl_dyn, sl, r, H, real_len, causal, interpret, flags
-    )
-    # Residuals mirror _dilated_branch_fwd: dense q/k/v (shared across
-    # branches — XLA stores one copy) + this branch's packed results.
-    return (out6, lse5), (q, k, v, vl_dyn, out6, lse5)
-
-
-def _dilated_branch_packed_bwd(sl, r, H, real_len, causal, interpret, flags,
-                               saved, cotangents):
-    q, k, v, vl_dyn, out6, lse5 = saved
-    do6, _dlse5 = cotangents  # no gradient flows through the lse output
-    return _branch_bwd_core(
-        q, k, v, vl_dyn, do6, out6, lse5, sl, r, H, real_len, causal,
-        interpret, flags,
-    )
-
-
-_dilated_branch_packed.defvjp(_dilated_branch_packed_fwd,
-                              _dilated_branch_packed_bwd)
-
-
-def dilated_branch_attention_packed(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    sl: int,
-    r: int,
-    num_heads: int,
-    *,
-    real_len: Optional[int] = None,
-    valid_len_dyn: Optional[jnp.ndarray] = None,
-    is_causal: bool = False,
-    interpret: bool = False,
-    flags: Optional[PipelineFlags] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One dilated branch returning the PACKED phase-major results
-    ``(out6 [B, S, r, hb, Mp, Dh], lse5 [B, S, r, Mp, LANES])`` — the
-    streaming fusion epilogue's input contract. Same eligibility rules
-    and ``flags`` default as :func:`dilated_branch_attention`."""
+def _packed_projections(q, k, v, schedule, H, interpret):
+    """Dense q / k / v -> each branch's ``(q6, k6, v6)``: one
+    :func:`_pack_call` a projection, whose joint pass reads the projection
+    once for every branch that can share it."""
     B, L, E = q.shape
-    assert E % num_heads == 0
-    assert num_heads % r == 0 and E % r == 0, (num_heads, E, r)
-    rl = L if real_len is None else min(int(real_len), L)
-    if flags is None:
-        flags = snapshot_flags()
-    return _dilated_branch_packed(
-        q, k, v, valid_len_dyn, int(sl), int(r), num_heads, rl, is_causal,
-        interpret, flags,
+    geoms = [_branch_geometry(L, E, sl, r) for sl, r in schedule]
+    plan = plan_pack(
+        L, E, H,
+        tuple((g, S, r, Mp) for (g, S, _, _, Mp, _), (_, r) in zip(geoms, schedule)),
+        q.dtype.itemsize, interpret,
     )
+    with jax.named_scope("pack"):
+        packed = [_pack_call(x, plan=plan) for x in (q, k, v)]
+    return list(zip(*packed)), geoms
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _dilated_branches(q, k, v, vl_dyn, schedule, H, real_len, causal,
+                      interpret, flags):
+    """Every branch of ``schedule`` (``(segment, ratio)`` pairs) with a
+    PACKED output boundary: dense q / k / v in, each branch's
+    ``(out6, lse5)`` out. The backward takes the cotangents already in the
+    packed layout (the epilogue backward emits them there), so neither
+    direction materializes a dense per-branch out / lse."""
+    return _dilated_branches_fwd(
+        q, k, v, vl_dyn, schedule, H, real_len, causal, interpret, flags
+    )[0]
+
+
+def _dilated_branches_fwd(q, k, v, vl_dyn, schedule, H, real_len, causal,
+                          interpret, flags):
+    B, L, E = q.shape
+    packed, geoms = _packed_projections(q, k, v, schedule, H, interpret)
+    outs, lses = [], []
+    for (sl, r), (q6, k6, v6), (g, S, gp, m, Mp, block) in zip(
+            schedule, packed, geoms):
+        with jax.named_scope(f"branch_r{r}"):
+            kvlen = _branch_kvlen(B, S, g, r, m, real_len, vl_dyn)
+            o6, l5 = _packed_forward(
+                q6, k6, v6, kvlen, causal, H // r, E // H, block, interpret
+            )
+        outs.append(o6)
+        lses.append(l5)
+    outs, lses = tuple(outs), tuple(lses)
+    # Residuals: the DENSE q / k / v and the branches' packed results; the
+    # packed q / k / v would keep ~3 dense-sized copies alive a branch, and
+    # the backward packs again with the same joint pass
+    return (outs, lses), (q, k, v, vl_dyn, outs, lses)
+
+
+def _dilated_branches_bwd(schedule, H, real_len, causal, interpret, flags,
+                          saved, cotangents):
+    q, k, v, vl_dyn, outs, lses = saved
+    d_outs, _dlses = cotangents  # no gradient flows through the lse outputs
+    B, L, E = q.shape
+    packed, _ = _packed_projections(q, k, v, schedule, H, interpret)
+    grads = None
+    # last branch first: the order in which autodiff sums the cotangents of
+    # a value used by one op a branch, so the sums round as they did then
+    for i in reversed(range(len(schedule))):
+        sl, r = schedule[i]
+        with jax.named_scope(f"branch_r{r}"):
+            dqkv = _branch_bwd_core(
+                *packed[i], vl_dyn, d_outs[i], outs[i], lses[i], L, E, sl, r,
+                H, real_len, causal, interpret, flags,
+            )
+        grads = dqkv if grads is None else tuple(
+            a + b for a, b in zip(grads, dqkv))
+    return grads + (_valid_len_ct(vl_dyn),)
+
+
+_dilated_branches.defvjp(_dilated_branches_fwd, _dilated_branches_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -2198,21 +2409,21 @@ def dilated_attention_stream_fused(
     flags: Optional[PipelineFlags] = None,
 ) -> jnp.ndarray:
     """Multi-branch dilated attention on dense [B, L, E] through the merge
-    epilogue: every branch runs the packed-boundary op and the packed
+    epilogue: the branch set runs with a packed output boundary (q, k and v
+    packed once for all branches, :func:`_pack_call`) and the packed
     results go straight into :func:`_fusion_epilogue`; no dense per-branch
     out / lse exists, forward or backward. ``plan`` is
     :func:`plan_stream_fusion`'s, and carries the interpret mode."""
     if flags is None:
         flags = snapshot_flags()
-    outs, lses = [], []
-    for sl, r in zip(segment_lengths, dilated_ratios):
-        with jax.named_scope(f"branch_r{int(r)}"):
-            o6, l5 = dilated_branch_attention_packed(
-                q, k, v, int(sl), int(r), num_heads,
-                real_len=real_len, valid_len_dyn=valid_len_dyn,
-                is_causal=is_causal, interpret=plan.interpret, flags=flags,
-            )
-        outs.append(o6)
-        lses.append(l5)
+    L = q.shape[1]
+    schedule = tuple(
+        (int(sl), int(r)) for sl, r in zip(segment_lengths, dilated_ratios)
+    )
+    rl = L if real_len is None else min(int(real_len), L)
+    outs, lses = _dilated_branches(
+        q, k, v, valid_len_dyn, schedule, num_heads, rl, is_causal,
+        plan.interpret, flags,
+    )
     with jax.named_scope("merge"):
-        return _fusion_epilogue(tuple(outs), tuple(lses), plan)
+        return _fusion_epilogue(outs, lses, plan)

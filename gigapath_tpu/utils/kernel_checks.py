@@ -341,6 +341,20 @@ def _copy_kernel_checks(geom: Geometry, rng, check) -> None:
               jnp.sum(pack(x) != _jnp_pack(x, g, S, r, Mp, H)), 0, 0.5)
         check(f"copy unpack {tag}: elements that differ",
               jnp.sum(unpack(p6) != _jnp_unpack(p6, L, E, g, S, r)), 0, 0.5)
+    # the pack of a projection for the whole schedule: the joint pass and
+    # the branches' own calls, as the fused op calls them
+    for Dh in dict.fromkeys((geom.head_dim, 64, 96)):
+        E = H * Dh
+        geoms = tuple(
+            (g, S, r, Mp) for (g, S, _, _, Mp, _), (_, r) in zip(
+                (pd._branch_geometry(L, E, sl, r) for sl, r in branches), branches))
+        plan = pd.plan_pack(L, E, H, geoms, 2)
+        x = jnp.asarray(rng.normal(size=(2, L, E)), jnp.bfloat16)
+        packed = jax.jit(lambda a: pd._pack_call(a, plan=plan))(x)
+        differ = sum(jnp.sum(p != _jnp_pack(x, g, S, r, Mp, H))
+                     for p, (g, S, r, Mp) in zip(packed, geoms))
+        check(f"joint pack Dh={Dh} L={L} rows={plan.rows} members={plan.members}: "
+              "elements that differ", differ, 0, 0.5)
 
 
 def _forward_body_checks(geom: Geometry, rng, check) -> None:
