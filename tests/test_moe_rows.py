@@ -37,6 +37,21 @@ def _written(total):
     return -(-int(total) // pr.DISPATCH_ROWS) * pr.DISPATCH_ROWS
 
 
+@jax.jit
+def _ascending_sum(out_rows, position, weights, total):
+    """The combine's formula, a choice at a time in ascending ``j`` in
+    float32: a held choice adds ``row * w``, one that is not held leaves the
+    sum as it is (selecting 0 for it would add an exact 0). Evaluated by XLA,
+    as the kernel's interpreter is: XLA's CPU backend fuses the product into
+    the add (one rounding), which a numpy float32 loop would not, so the
+    kernel is held to this and not to numpy."""
+    acc = jnp.zeros((position.shape[0], out_rows.shape[1]), jnp.float32)
+    for j in range(position.shape[1]):
+        row = out_rows[position[:, j]].astype(jnp.float32)
+        acc = jnp.where((position[:, j] < total)[:, None], acc + row * weights[:, j, None], acc)
+    return acc.astype(out_rows.dtype)
+
+
 @pytest.mark.parametrize("held,offset", [(8, 0), (4, 0), (4, 4), (1, 5)],
                          ids=["all", "half", "other_half", "one"])
 @pytest.mark.parametrize("case", ["spread", "one_expert_gets_none", "one_expert_gets_all"])
@@ -53,6 +68,7 @@ def test_kernels_match_the_jnp_form(case, held, offset):
     want = pr.combine_rows_jnp(out_rows, position, weights, total)
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(got, _ascending_sum(out_rows, position, weights, total))
     if held == E:
         assert int(total) == S * K  # every tile runs: nothing is skipped
 
@@ -76,15 +92,18 @@ def test_total_inside_a_tile_on_an_edge_and_zero(total):
     got = pr.combine_rows_kernel(out_rows, position, weights, total, True)
     np.testing.assert_allclose(got, pr.combine_rows_jnp(out_rows, position, weights, total),
                                rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(got, _ascending_sum(out_rows, position, weights, total))
     if int(total) == 0:
         assert not np.asarray(got).any()
 
 
-@pytest.mark.parametrize("dtype,k", [(jnp.bfloat16, 4), (jnp.bfloat16, 10), (jnp.float32, 1)],
-                         ids=["bf16_top4", "bf16_top10", "f32_top1"])
+@pytest.mark.parametrize("dtype,k", [(jnp.bfloat16, 4), (jnp.bfloat16, 10), (jnp.float32, 1),
+                                     (jnp.bfloat16, 8)],
+                         ids=["bf16_top4", "bf16_top10", "f32_top1", "bf16_top8"])
 def test_kernels_take_other_widths_of_choice_and_type(dtype, k):
     """top-10 pads a token's choices to 16 in the index blocks (64 tokens a
-    combine tile); the sum is float32 whatever the rows' type."""
+    combine tile), top-8 fills its 8 with no pad slot; the sum is float32
+    whatever the rows' type."""
     s, e = 1024, 16
     rng = np.random.default_rng(k)
     x = jnp.asarray(rng.standard_normal((s, M)), dtype)
@@ -102,6 +121,104 @@ def test_kernels_take_other_widths_of_choice_and_type(dtype, k):
     tol = 1e-6 if dtype == jnp.float32 else 2 ** -7
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(
+        _ascending_sum(out_rows, position, weights, total), np.float32))
+
+
+def _held_where(s, k, hold, seed=0):
+    """``position [s, k]``: a permutation of the sorted buffer in which the
+    choices ``hold [s, k]`` (bool) take the first rows. Returns it and ``total``."""
+    rng = np.random.default_rng(seed)
+    flat = hold.reshape(-1)
+    position = np.empty(s * k, np.int32)
+    position[flat] = rng.permutation(int(flat.sum()))
+    position[~flat] = int(flat.sum()) + rng.permutation(int((~flat).sum()))
+    return jnp.asarray(position.reshape(s, k)), jnp.int32(flat.sum())
+
+
+@pytest.mark.parametrize("k", [10, 8], ids=["top10", "top8"])
+def test_tokens_that_hold_every_choice_or_none_and_a_tile_that_holds_none(k):
+    """A token whose ``k`` choices are all held, one with none (its row of
+    the output is zeros), and a whole combine tile whose tokens hold nothing
+    (its copy loop issues no copy, its sum loop writes zeros). The
+    interpreter lands a copy only when a wait covers it and fills what never
+    landed with NaN, so a wait short of the rows issued shows in the sum."""
+    s = 1024
+    tokens = pr.INDEX_BLOCK // pr._padded_k(k)
+    rng = np.random.default_rng(k)
+    hold = rng.random((s, k)) < 0.5
+    hold[0], hold[1], hold[2 * tokens:3 * tokens] = True, False, False
+    position, total = _held_where(s, k, hold, seed=k)
+    weights = jnp.asarray(rng.random((s, k)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((s * k, M)), jnp.bfloat16)
+    out_rows = jnp.where((jnp.arange(s * k) < total)[:, None], x, jnp.nan)
+    on_wait = pltpu.InterpretParams(dma_execution_mode="on_wait")
+    got = np.asarray(pr.combine_rows_kernel(out_rows, position, weights, total, on_wait),
+                     np.float32)
+    np.testing.assert_array_equal(got, np.asarray(
+        _ascending_sum(out_rows, position, weights, total), np.float32))
+    assert got[0].any() and not got[1].any() and not got[2 * tokens:3 * tokens].any()
+
+
+@pytest.mark.parametrize("k", [1, 2, 8, 10])
+@pytest.mark.parametrize("share", [0.0, 0.06, 0.5, 1.0])
+def test_each_tokens_choices_are_ordered_held_first(k, share):
+    """``_held_first``: a token's held choices (``position < total``) first,
+    in ascending ``j``, then ``S * k`` with weight 0 up to ``_padded_k(k)``
+    slots; ``counts`` is the held choices a token."""
+    s, kp = 256, pr._padded_k(k)
+    rng = np.random.default_rng(k)
+    position, total = _held_where(s, k, rng.random((s, k)) < share, seed=k)
+    weights = jnp.asarray(rng.random((s, k)) + 0.5, jnp.float32)
+    got_p, got_w, counts = (np.asarray(a) for a in pr._held_first(position, weights, total, s * k))
+    p, w, t = np.asarray(position), np.asarray(weights), int(total)
+    assert got_p.shape == got_w.shape == (s, kp) and counts.dtype == np.int32
+    np.testing.assert_array_equal(counts, (p < t).sum(1))
+    for i in range(s):
+        held = [j for j in range(k) if p[i, j] < t]
+        n = len(held)
+        assert got_p[i, :n].tolist() == p[i, held].tolist()
+        assert got_w[i, :n].tolist() == w[i, held].tolist()
+        assert (got_p[i, n:] == s * k).all() and (got_w[i, n:] == 0).all()
+
+
+@pytest.mark.parametrize("total", [S * K, S * K + 64], ids=["total_all", "total_past_the_buffer"])
+def test_a_position_outside_the_buffer_is_never_read(total):
+    """The kernel's copies go unchecked by the compiler, so a row index past
+    the sorted buffer or below 0 must never reach one: such a choice is not
+    held and adds 0, whatever ``total`` says. The interpreter raises on a
+    read outside an array."""
+    rng = np.random.default_rng(total)
+    position = np.asarray(rng.permutation(S * K), np.int32).reshape(S, K)
+    position[0, 0], position[1, 1], position[2] = S * K + 3, -2, (-1, S * K)
+    weights = jnp.asarray(rng.random((S, K)), jnp.float32)
+    out_rows = jnp.asarray(rng.standard_normal((S * K, M)), jnp.float32)
+    got = pr.combine_rows_kernel(out_rows, jnp.asarray(position), weights, jnp.int32(total),
+                                 pltpu.InterpretParams())
+    inside = np.where((position >= 0) & (position < S * K), position, S * K)
+    np.testing.assert_array_equal(got, _ascending_sum(out_rows, jnp.asarray(inside), weights,
+                                                      jnp.int32(S * K)))
+    assert not np.asarray(got[2]).any()
+
+
+def test_the_combine_orders_its_choices_without_a_sort():
+    """What the combine traces to at a Granite-like shape: the ordering is
+    compares, selects and sums; no sort, scatter or gather is left to XLA."""
+    s, m, k = 16384, 4096, 10
+    args = (jax.ShapeDtypeStruct((s * k, m), jnp.bfloat16), jax.ShapeDtypeStruct((s, k), jnp.int32),
+            jax.ShapeDtypeStruct((s, k), jnp.float32), jax.ShapeDtypeStruct((), jnp.int32))
+    jaxpr = jax.make_jaxpr(pr.combine_rows_kernel)(*args)
+    names = set()
+
+    def walk(j):
+        for eqn in j.eqns:
+            names.add(eqn.primitive.name)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jaxpr.jaxpr)
+    assert "pallas_call" in names
+    assert not names & {"sort", "scatter", "scatter-add", "scatter_add", "gather", "top_k",
+                        "cumsum", "argsort"}, names
 
 
 @pytest.mark.parametrize("held", [4, 1], ids=["half", "one"])

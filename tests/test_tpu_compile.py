@@ -635,6 +635,28 @@ def test_axk1_layers_at_published_widths_hold_their_kernels(topo, one_chip, monk
         assert pallas_rows._combine_vmem(7168, 8, 2) <= pallas_rows._VMEM_BUDGET
 
 
+@pytest.mark.parametrize("m,k", [(4096, 10), (7168, 8)], ids=["granite", "axk1"])
+def test_the_combine_alone_orders_its_choices_without_a_sort(topo, one_chip, m, k):
+    """``moe_combine`` alone at an expert cell's 16,384 tokens: one custom
+    call under ``combine/kernel_fwd/jit(_combine_call)/``, and no sort,
+    scatter or gather in the compiled text (each token's choices are put held
+    first by compares and selects; an ``argsort``ed list of the held choices
+    once cost more than the kernel saved)."""
+    from gigapath_tpu.ops.moe import pallas_rows
+
+    def combine(*args):
+        with jax.named_scope("combine"):
+            return pallas_rows.combine_rows_kernel(*args)
+
+    s = 16384
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip) for shape, dtype in (
+        ((s * k, m), jnp.bfloat16), ((s, k), jnp.int32), ((s, k), jnp.float32), ((), jnp.int32))]
+    text = jax.jit(combine).lower(*args).compile().as_text()
+    assert len(re.findall(r"\n\s*(?:ROOT )?%moe_combine(?:\.\d+)? = [^\n]* custom-call\(", text)) == 1
+    assert re.search(r'op_name="[^"]*/combine/kernel_fwd/jit\(_combine_call\)/moe_combine/', text)
+    assert not re.search(r"\n\s*(?:ROOT )?%\S+ = [^\n]* (sort|scatter|gather)\(", text)
+
+
 def test_deepseek_v32_attention_at_published_widths_holds_its_kernels(topo, one_chip, monkeypatch):
     """DeepSeek-V3.2's attention at the cell's 16,384 tokens, the device gate
     answering "TPU": one ``index_score`` call over 64 index heads of 128 whose
